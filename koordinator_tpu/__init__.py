@@ -32,48 +32,18 @@ import jax
 # where parity is not required.
 jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: the cycle kernels take 10-20s to compile
-# per shape bucket (16.5s measured for the dense TPU kernel, BENCH_r03), but
-# a scheduler must be ready at informer-sync speed (reference analog:
-# cmd/koord-scheduler/app/server.go:206-220).  With the cache a restarted
-# sidecar reuses the compiled executable and the first cycle runs in well
-# under a second.  Opt out with KOORD_XLA_CACHE=0 or point KOORD_XLA_CACHE
-# at a different directory; daemons re-point it under their --state-dir via
-# configure_compilation_cache (scheduler/server.py).
-
-
-def configure_compilation_cache(path, min_compile_seconds: float = 1.0,
-                                force: bool = False) -> None:
-    """Point JAX's persistent compilation cache at ``path``.
-
-    Must run before the first compile — the cache is initialized lazily on
-    first use and later re-pointing does not move already-initialized
-    state.  ``path=None`` or ``""`` disables the cache.  The
-    ``KOORD_XLA_CACHE`` env var takes precedence over programmatic calls
-    (an operator override must win over a daemon default) — except under
-    ``force=True``, the seam for an EXPLICIT ``--xla-cache`` flag, which
-    outranks the env default exactly because the operator typed it.
-    """
-    env = os.environ.get("KOORD_XLA_CACHE", "")
-    if env and not force:
-        return  # import-time wiring below already honored the override
-    if not path:
-        jax.config.update("jax_compilation_cache_dir", None)
-        return
-    jax.config.update("jax_compilation_cache_dir", path)
-    # cache every compile that costs more than min_compile_seconds; keep
-    # tiny jits out (caching them would churn small files for no win)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", float(min_compile_seconds)
-    )
-
-
-_cache = os.environ.get("KOORD_XLA_CACHE", "")
-if _cache != "0":
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        _cache or os.path.expanduser("~/.cache/koordinator_tpu/xla"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# Persistent XLA compilation cache: the cycle kernels take 10-20 s to
+# compile per shape bucket, and a restarted scheduler must be ready at
+# informer-sync speed (reference analog:
+# cmd/koord-scheduler/app/server.go:206-220).  One rule, applied here and
+# nowhere else: where JAX_COMPILATION_CACHE_DIR is set, JAX reads it and
+# this package sets nothing; otherwise the cache lives at one fixed,
+# gitignored path inside the checkout — never under ~, a temp name or a
+# PID, since a cache directory that moves never hits.
+XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
 
 __version__ = "0.1.0"

@@ -19,10 +19,8 @@ and module-locally (same philosophy as the donation/retrace rules):
 4. ``name = jax.jit(fn)`` call-form assignments are flagged outright —
    the call form cannot carry the decorator; spell it as a decorated def
    or suppress with a reason;
-5. a ``shard_map`` / ``shard_map_compat`` launch outside any jitted def
-   is its own unattributed device launch and is flagged (the
-   version-compat shim in parallel/mesh.py carries the one reasoned
-   suppression: its callers register at their own jit boundary).
+5. a ``shard_map`` launch outside any jitted def is its own
+   unattributed device launch and is flagged.
 
 Modules outside the serving path (tests, harness, obs itself) are out of
 scope: their launches never sit on the Score/Assign path the ledger
@@ -48,7 +46,7 @@ RULE = "unregistered-jit-boundary"
 _SCOPE_PARTS = {"solver", "parallel", "bridge"}
 
 # spellings of the shard-mapped launch entry point the repo uses
-_SHARD_MAP_NAMES = {"shard_map", "shard_map_compat", "_shard_map"}
+_SHARD_MAP_NAMES = {"shard_map"}
 
 
 def _in_scope(path: str) -> bool:

@@ -29,9 +29,7 @@ work asynchronously — a blocking ``device_get``/``block_until_ready``
 inside it stalls every queued launch exactly the way the old single
 lock did, un-pipelining the engine silently.  Nested defs inside a
 launch-section function are exempt: that is precisely where the
-readback closure (the only code allowed to block) lives.  The shard
-path's materialize-inside-the-demotion-guard transfers carry reasoned
-per-line suppressions.
+readback closure (the only code allowed to block) lives.
 """
 
 from __future__ import annotations

@@ -363,7 +363,7 @@ def _shard_map_body_knobs(source: SourceFile) -> List[Violation]:
             name = fn.attr if isinstance(fn, ast.Attribute) else (
                 fn.id if isinstance(fn, ast.Name) else ""
             )
-            if not name.endswith("shard_map") and name != "shard_map_compat":
+            if not name.endswith("shard_map"):
                 continue
             if not node.args or not isinstance(node.args[0], ast.Name):
                 continue

@@ -1179,24 +1179,16 @@ class ScorerServicer:
                     )
                     if ratio <= self._score_incr_max_ratio:
                         incr_cols = len(incr.dirty_nodes)
-                        try:
-                            scores, feasible = self._score_incremental(
-                                snap, incr
-                            )
-                            incr_result = "incr"
-                        except Exception:  # owner failure on the incremental launch: the full rescore below is the documented fallback; the residency was dropped so the torn tensor can never serve
-                            # the kernel may have consumed the donated
-                            # scores buffer mid-failure: the residency
-                            # is poison — drop it and full-rescore;
-                            # the reply this batch serves stays exact
-                            import logging
-
-                            logging.getLogger(__name__).exception(
-                                "incremental rescore failed; falling "
-                                "back to a full score_cycle"
-                            )
-                            self.state.drop_score_residency()
-                            incr_result = "fallback"
+                        # the launch consumes the donated residency:
+                        # unpublish it first, so a failed launch (which
+                        # fails this batch) never leaves a consumed
+                        # buffer for the next Score; success re-stores
+                        # the advanced tensors below
+                        self.state.drop_score_residency()
+                        scores, feasible = self._score_incremental(
+                            snap, incr
+                        )
+                        incr_result = "incr"
                     else:
                         # mostly-dirty: a full rescore is cheaper than
                         # scattering most of the tensor incrementally
@@ -1523,17 +1515,16 @@ class ScorerServicer:
         """Advance the resident score tensors through the accumulated
         dirty columns/rows (solver/incremental.py ``rescore_dirty``) —
         the warm Score engine.  Caller holds the launch lock (commits
-        that add dirt run under it too, so the sets cannot move) and
-        owns the fallback on failure.
+        that add dirt run under it too, so the sets cannot move); a
+        failure raises to the batch's callers.
 
         The resident ``scores`` buffer is DONATED to the rescore: the
-        residency's references are cleared FIRST so a mid-kernel
-        failure can never leave a consumed buffer published — the
-        caller re-stores the advanced tensors on success and drops the
-        residency on failure.  With no dirt at all (a quota-only delta
-        stream, or a wider-k relaunch over an unchanged snapshot) the
-        tensors are already exact and pass through untouched — no
-        kernel launches."""
+        caller unpublishes the residency before the launch, so a
+        mid-kernel failure can never leave a consumed buffer published,
+        and re-stores the advanced tensors on success.  With no dirt at
+        all (a quota-only delta stream, or a wider-k relaunch over an
+        unchanged snapshot) the tensors are already exact and pass
+        through untouched — no kernel launches."""
         from koordinator_tpu.solver.incremental import rescore_dirty
 
         if not res.dirty_nodes and not res.dirty_pods:
@@ -1995,10 +1986,10 @@ class ScorerServicer:
             def _readback():
                 # blocking stacked transfer — OFF the launch lock, so a
                 # coalesced Score batch can launch while it drains.
-                # ``rounds`` rides the same stacked device_get: it may
-                # be a device scalar (single-chip wave path), a host int
-                # (shard path, materialized inside its demotion guard)
-                # or None — device_get passes the last two through.
+                # ``rounds`` rides the same stacked device_get: a
+                # device scalar (single-chip wave path), a host int
+                # (shard path) or None — device_get passes the last
+                # two through.
                 with scope.span("readback"):
                     assignment, status, valid, got_rounds = jax.device_get(
                         (result.assignment, result.status,
@@ -2095,9 +2086,6 @@ class ScorerServicer:
 
         ``spans`` is the RPC's CycleScope (obs/spans.py) — same span
         surface as the recorder, but private to this cycle."""
-        result = None
-        rounds = None
-        eff_wave = self.cfg.wave
         # fused scoring terms (ISSUE 15): the multi-chip wave cycle has
         # no extras seam, so a term-enabled config serves Assign through
         # the single-chip run_cycle below (which folds the term tensors
@@ -2106,85 +2094,26 @@ class ScorerServicer:
         # path either way: the terms live INSIDE score_all.
         if self.mesh is not None and not self._term_names:
             from koordinator_tpu.parallel import greedy_assign_waves
-            from koordinator_tpu.solver import (
-                _demoted,
-                _record_failure,
-                _record_success,
-            )
 
             # the CycleConfig wave knobs thread through to the
             # round-based sharded cycle; wave=1 (the per-pod default)
-            # keeps the multichip path's own proven width
-            wave = self.cfg.wave if self.cfg.wave > 1 else 32
-            top_m = self.cfg.top_m
-            bucket = (
-                "shard",
-                int(snap.nodes.allocatable.shape[0]),
-                int(snap.pods.capacity),
-                self.mesh.size,
-                wave,
-                top_m,
-            )
-            if not _demoted(bucket):
-                try:
-                    # distinct name from the fallback's "dispatch": a
-                    # failed shard attempt followed by the single-chip
-                    # cycle must not leave two same-named spans a
-                    # post-mortem reader would double-count
-                    with spans.span("dispatch_shard"):
-                        result, nwaves = greedy_assign_waves(
-                            snap, self.mesh, self.cfg,
-                            wave=wave, top_m=top_m, spans=spans,
-                        )
-                        # materialize INSIDE the guard: with async
-                        # dispatch a late device fault would otherwise
-                        # surface at the reply assembly, outside this
-                        # fallback (the same hazard run_cycle documents).
-                        # This is the ONE blocking transfer allowed in a
-                        # launch section — the shard path trades a slot
-                        # of pipeline depth for its demotion guard.
-                        import dataclasses
-
-                        result = dataclasses.replace(
-                            result,
-                            assignment=np.asarray(result.assignment),  # koordlint: disable=lock-held-dispatch(shard demotion guard: the fault must surface inside the fallback try, pipeline depth is traded deliberately)
-                            status=np.asarray(result.status),  # koordlint: disable=lock-held-dispatch(shard demotion guard)
-                        )
-                    # device-derived stat, materialized AFTER the device
-                    # program completed — one scalar transfer, no retrace
-                    rounds = int(np.asarray(nwaves))  # koordlint: disable=lock-held-dispatch(shard demotion guard)
-                    eff_wave = wave
-                    _record_success(bucket)
-                except Exception as exc:
-                    # the run_cycle demotion philosophy, shared
-                    # machinery: back off this shape bucket instead
-                    # of re-paying a failed shard compile on every
-                    # RPC; the single-chip cycle is bit-identical
-                    # and path in the reply shows the degradation
-                    _record_failure(bucket)
-                    result = None
-                    # the cycle record must say the shard attempt
-                    # failed, not just show a closed dispatch_shard
-                    # span next to the fallback's dispatch
-                    spans.note("shard_error", f"{exc!r:.200}")
-                    import logging
-
-                    logging.getLogger(__name__).exception(
-                        "sharded assign failed; serving single-chip "
-                        "and backing off bucket %r",
-                        bucket,
-                    )
-        if result is None:
-            eff_wave = self.cfg.wave
-            with spans.span("dispatch"):
-                result = run_cycle(snap, self.cfg, i32_ok=i32_ok)
-            # device-derived wave count: returned UN-materialized (a
-            # device scalar) — blocking on it here would hold the
-            # launch lock for the whole cycle; the pipelined readback
-            # fetches it in the same stacked device_get as
-            # assignment/status, off the lock
-            rounds = result.rounds
-        return result, rounds, eff_wave
+            # keeps the multichip path's own proven width.  A failed
+            # shard cycle raises: the RPC fails, no single-chip
+            # fallback hides it.
+            eff_wave = self.cfg.wave if self.cfg.wave > 1 else 32
+            with spans.span("dispatch_shard"):
+                result, rounds = greedy_assign_waves(
+                    snap, self.mesh, self.cfg,
+                    wave=eff_wave, top_m=self.cfg.top_m, spans=spans,
+                )
+            return result, rounds, eff_wave
+        with spans.span("dispatch"):
+            result = run_cycle(snap, self.cfg, i32_ok=i32_ok)
+        # device-derived wave count: returned UN-materialized (a device
+        # scalar) — blocking on it here would hold the launch lock for
+        # the whole cycle; the pipelined readback fetches it in the
+        # same stacked device_get as assignment/status, off the lock
+        return result, result.rounds, self.cfg.wave
 
 
 def _handler(fn, req_cls):

@@ -29,13 +29,22 @@ _tried = False
 
 
 def _build() -> bool:
+    """``make`` on every load: a no-op when the library is up to date,
+    a rebuild when a source is newer — the loaded binary always matches
+    the sources beside it."""
+    import fcntl
+
     try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
+        # one builder at a time across processes (test workers load
+        # concurrently): the lock rides the Makefile itself
+        with open(os.path.join(_NATIVE_DIR, "Makefile"), "rb") as fh:
+            fcntl.flock(fh, fcntl.LOCK_EX)
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR, "libkoordnative.so"],
+                check=True,
+                capture_output=True,
+                timeout=120,
+            )
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -47,7 +56,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)

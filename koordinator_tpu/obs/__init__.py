@@ -16,7 +16,7 @@ ScorerServicer, one per bridge daemon):
   form).
 * **flight** (obs/flight.py) — a ring buffer of the last K cycles'
   records + config knobs + snapshot ids, dumped as schema-validated
-  JSON under --state-dir on cycle error, kernel demotion, or SIGUSR1.
+  JSON under --state-dir on cycle error or SIGUSR1.
 
 The overhead contract is locked in by tests/test_resident_warm.py: a
 warm delta-Sync/Assign stream with telemetry enabled (it always is on
@@ -63,8 +63,7 @@ def _config_knobs(cfg) -> Dict[str, object]:
 
 class CycleTelemetry:
     """Spans + scorer metrics + flight recorder, wired to the process
-    feeds (jit cache misses via analysis.retrace_guard, kernel
-    demotions via solver.register_demotion_listener)."""
+    feed of jit cache misses (analysis.retrace_guard)."""
 
     def __init__(
         self,
@@ -139,11 +138,6 @@ class CycleTelemetry:
                 "koord_scorer_jit_cache_miss_total will not populate",
                 exc_info=True,
             )
-        from koordinator_tpu import solver
-
-        self._unhooks.append(
-            solver.register_demotion_listener(self.on_demotion)
-        )
 
     def close(self) -> None:
         """Unhook the process-wide feeds (tests; daemons run for life)
@@ -158,22 +152,6 @@ class CycleTelemetry:
             self.exporter.close()
 
     # -- event sinks --
-    def on_demotion(self, bucket, failures) -> None:
-        """Kernel demotions are PROCESS-global (solver module state) and
-        this fires on the demoting thread, which may not be this
-        telemetry's servicer thread — so only thread-safe sinks here:
-        the locked registry and the RLock'd flight recorder.  Never the
-        span recorder (unlocked by design; owned by the RPC thread).
-        The demoted bucket rides the dump itself."""
-        self.metrics.count_demotion()
-        self.flight.dump(
-            "demotion",
-            extra={
-                "bucket": "/".join(map(str, bucket)),
-                "failures": int(failures),
-            },
-        )
-
     def record_sync(self, info, snapshot_id: str, epoch: str,
                     generation: int) -> None:
         self.metrics.record_sync(info)

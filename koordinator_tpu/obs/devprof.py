@@ -94,7 +94,7 @@ LEDGER_FILENAME = "devprof.json"
 # launched, with an abstract (ShapeDtypeStruct) argument spec a future
 # incarnation can replay through fn.lower(...).compile() — pickled
 # because the specs carry real static objects (frozen CycleConfig);
-# same trust domain as the xla-cache executables beside it
+# same trust domain as the persistent compile cache's executables
 PREWARM_FILENAME = "prewarm.pkl"
 
 # flush the on-disk ledger every this many sampled launches (compile
@@ -174,8 +174,8 @@ class _BoundaryStats:
 class LaunchLedger:
     """Process-global registry of jit boundaries + their capture state.
 
-    One instance lives at module scope (like the retrace-guard hook and
-    the kernel demotion listeners); tests get a fresh one via
+    One instance lives at module scope (like the retrace-guard hook);
+    tests get a fresh one via
     :func:`reset`.  All mutation happens under one lock — boundaries
     fire from the bridge worker threads AND the pipelined readback
     threads concurrently.
@@ -630,16 +630,13 @@ def _device_count() -> Optional[int]:
 
 
 def _trace_state_clean() -> bool:
-    """True when no jax trace is live on this thread.  Drift-tolerant:
-    when the probe is unavailable we claim clean and rely on the
-    exception gates (a tracer poisons perf_counter math, not
-    correctness — the wrapper still returns fn's result)."""
-    try:
-        import jax
+    """True when no jax trace is live on this thread (public jax.core
+    probe).  No exception gate: a probe that stops existing must fail
+    loudly, not claim "clean" and time every nested boundary as a
+    launch."""
+    import jax
 
-        return bool(jax.core.trace_state_clean())
-    except Exception:  # koordlint: disable=broad-except(reason: version-drift probe; claiming clean only risks a harmless timing sample, never correctness)
-        return True
+    return not jax.core.unsafe_am_i_under_a_jit_DO_NOT_USE()
 
 
 # -- signatures ------------------------------------------------------

@@ -2,12 +2,12 @@
 
 A ring buffer of committed cycle records (obs/spans.py
 ``CycleSpans.to_record()`` dicts) plus the config knobs and snapshot
-ids that produced them.  On a cycle error, a kernel demotion, or
-SIGUSR1, the whole ring is dumped as ONE schema-validated JSON file
-under the daemon's ``--state-dir`` (``<state-dir>/flight/``) — so a bad
-cycle is diagnosable from the artifact, not from whatever happened to
-be in the log buffer (the BENCH_r05 class: a regression that was only
-caught because a run timed out).
+ids that produced them.  On a cycle error or SIGUSR1, the whole ring
+is dumped as ONE schema-validated JSON file under the daemon's
+``--state-dir`` (``<state-dir>/flight/``) — so a bad cycle is
+diagnosable from the artifact, not from whatever happened to be in the
+log buffer (a regression that is otherwise only caught because a run
+timed out).
 
 The dump schema is enforced by :func:`validate_flight_dump` (stdlib
 only, mirroring bench.py's ``_validate_artifact`` convention): a
@@ -32,7 +32,7 @@ logger = logging.getLogger(__name__)
 
 FLIGHT_DUMP_VERSION = 1
 DEFAULT_CAPACITY = 64
-# dump-file churn guard: misbehaving triggers (a demotion storm) must
+# dump-file churn guard: misbehaving triggers (an error storm) must
 # not fill the state dir; oldest dumps are pruned past this count
 MAX_DUMPS_KEPT = 32
 
@@ -166,7 +166,7 @@ class FlightRecorder:
         self._dump_seq = 0
         self.dropped = 0  # cycles that fell off the ring, for the dump
         # per-reason dump rate limit: a flood of one trigger (a client
-        # looping bad frames, a demotion storm) must not stall serving
+        # looping bad frames, an error storm) must not stall serving
         # on disk I/O per event NOR churn real post-mortem dumps out of
         # the pruned directory.  sigusr1 is exempt — the operator asked.
         self.min_dump_interval_s = 10.0
@@ -210,7 +210,7 @@ class FlightRecorder:
         path, or None when no state dir is configured, the document
         fails its own schema, or the write fails (a diagnostics dump
         must never take the serving path down with it).  ``extra``
-        carries trigger-specific scalars (e.g. the demoted bucket)."""
+        carries trigger-specific scalars."""
         if not self.state_dir:
             return None
         if reason != "sigusr1":

@@ -13,8 +13,9 @@ signatures first) *while the server is already accepting RPCs*:
 * a request whose signature the runner has not reached yet just
   compiles inline, exactly as today — prewarm is an accelerant, never
   a gate, and the breaker/brownout ladder is untouched;
-* each replayed compile lands in the persistent XLA cache under
-  ``--state-dir/xla-cache``, so even the inline-compile fallback pays
+* each replayed compile lands in the persistent XLA cache (the
+  package's one rule: ``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``), so even the inline-compile fallback pays
   trace time only, not backend compile time;
 * replayed signatures land in the compile ledger via
   ``devprof.record_prewarm_compile`` — warm, but NOT attributed

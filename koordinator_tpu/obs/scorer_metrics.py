@@ -18,7 +18,6 @@ koord_scorer_jit_cache_miss_total      counter   kind (trace|compile)
 koord_scorer_snapshot_generation       gauge     —
 koord_scorer_resident_epoch            gauge     epoch (value always 1)
 koord_scorer_resident_warm             gauge     — (last Sync: 1 warm/0 cold)
-koord_scorer_kernel_demotions_total    counter   —
 koord_scorer_uds_frames_total          counter   method
 koord_scorer_uds_malformed_total       counter   reason
 koord_scorer_uds_errors_total          counter   —
@@ -129,7 +128,6 @@ JIT_CACHE_MISS = "koord_scorer_jit_cache_miss_total"
 SNAPSHOT_GENERATION = "koord_scorer_snapshot_generation"
 RESIDENT_EPOCH = "koord_scorer_resident_epoch"
 RESIDENT_WARM = "koord_scorer_resident_warm"
-DEMOTIONS_TOTAL = "koord_scorer_kernel_demotions_total"
 UDS_FRAMES = "koord_scorer_uds_frames_total"
 UDS_MALFORMED = "koord_scorer_uds_malformed_total"
 UDS_ERRORS = "koord_scorer_uds_errors_total"
@@ -220,8 +218,6 @@ _FAMILIES = (
     (RESIDENT_WARM, "gauge",
      "1 when the last Sync landed on the resident device tensors "
      "(warm), 0 when it dropped residency (cold)"),
-    (DEMOTIONS_TOTAL, "counter",
-     "Pallas kernel shape-bucket demotions to a fallback path"),
     (UDS_FRAMES, "counter", "raw-UDS request frames served, by method"),
     (UDS_MALFORMED, "counter",
      "malformed raw-UDS frames (oversized, unknown method, truncated "
@@ -531,9 +527,6 @@ class ScorerMetrics:
     # -- feeds --
     def count_jit_miss(self, kind: str) -> None:
         self.registry.counter_add(JIT_CACHE_MISS, 1, {"kind": kind})
-
-    def count_demotion(self) -> None:
-        self.registry.counter_add(DEMOTIONS_TOTAL, 1)
 
     def count_uds_frame(self, method: str) -> None:
         self.registry.counter_add(UDS_FRAMES, 1, {"method": method})

@@ -7,7 +7,6 @@ from koordinator_tpu.parallel.mesh import (  # noqa: F401
     replicated_sharding,
     score_sharding,
     shard_cluster_snapshot,
-    shard_map_compat,
     shard_snapshot_for_scoring,
     shard_snapshot_for_assign,
     snapshot_partition_specs,

@@ -44,27 +44,6 @@ CLUSTER_AXIS = "nodes"
 POD_AXIS = "pods"
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-compat shard_map: ``jax.shard_map`` (with its ``check_vma``
-    kwarg) graduated from ``jax.experimental.shard_map.shard_map`` (whose
-    equivalent kwarg is ``check_rep``); the installed jax may carry either.
-    Shared by parallel/shard_assign.py and solver/resident.py — the one
-    compat shim."""
-    if hasattr(jax, "shard_map"):
-        # koordlint: disable=unregistered-jit-boundary(reason: version-compat shim, not a launch site — every caller sits inside its own registered devprof.boundary jit boundary)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    # koordlint: disable=unregistered-jit-boundary(reason: version-compat shim, not a launch site — every caller sits inside its own registered devprof.boundary jit boundary)
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def cluster_mesh(devices: Optional[Sequence] = None) -> Mesh:
     """The 1-D resident-snapshot mesh: every device holds one node-axis
     shard of the cluster.  ``devices`` defaults to all visible devices;

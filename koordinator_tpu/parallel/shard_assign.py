@@ -63,9 +63,7 @@ from koordinator_tpu.solver.greedy import (
 # the packed-key encode/decode, the cross-shard top-M merge and the
 # in-wave certification are the ONE shared implementation
 # (solver/wave.py) this path and the single-chip wave_assign both
-# consume — no copy-pasted math; the shard_map version-compat shim is
-# shared with the resident scatter (parallel/mesh.py)
-from koordinator_tpu.parallel.mesh import shard_map_compat as _shard_map
+# consume — no copy-pasted math
 from koordinator_tpu.solver.wave import (
     is_most_allocated,
     merge_topm,
@@ -261,7 +259,7 @@ def _assign_sharded(
         (nreq, nest, quse), chosen_in_order = lax.scan(step, init, order)
         return chosen_in_order, nreq, nest, quse
 
-    chosen_in_order, node_requested, node_estimated, quota_used = _shard_map(
+    chosen_in_order, node_requested, node_estimated, quota_used = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -546,11 +544,9 @@ def _assign_waves(
             # apply the committed prefix to the local shard state
             local = choices - offset
             mine = committed & (local >= 0) & (local < n_loc)
-            onehot = (
-                (local[:, None] == jnp.arange(n_loc)[None, :]) & mine[:, None]
-            ).astype(jnp.int64)
-            nreq = nreq + jnp.einsum("wn,wr->nr", onehot, preq_wave)
-            nest = nest + jnp.einsum("wn,wr->nr", onehot, pest_wave)
+            target = jnp.where(mine, local, n_loc)  # not mine: dropped
+            nreq = nreq.at[target].add(preq_wave, mode="drop")
+            nest = nest.at[target].add(pest_wave, mode="drop")
 
             write = jnp.where(
                 done, choices.astype(jnp.int32), jnp.int32(-1)
@@ -581,7 +577,7 @@ def _assign_waves(
         return chosen_buf[:PCAP], nreq, nest, quse, nwaves
 
     (chosen_in_order, node_requested, node_estimated, quota_used, nwaves) = (
-        _shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=tuple(in_specs),

@@ -37,15 +37,13 @@ from koordinator_tpu.leaderelection import LeaderElector
 from koordinator_tpu.obs.lockwitness import witness_lock
 from koordinator_tpu.scheduler.config_api import load_config
 from koordinator_tpu.scheduler.services import APIService
-from koordinator_tpu.solver import pallas_demotions
 
 
 def default_state_dir() -> str:
     """Per-user daemon state dir (XDG state home).  NOT a fixed /tmp
-    path: the state dir holds the persistent XLA compile cache, whose
-    entries are deserialized executables — a world-writable shared
-    location would let another local user pre-plant cache entries the
-    scheduler then loads."""
+    path: the state dir holds prewarm signatures and the journal, which
+    the daemon loads back — a world-writable shared location would let
+    another local user pre-plant them."""
     base = os.environ.get("XDG_STATE_HOME") or os.path.join(
         os.path.expanduser("~"), ".local", "state"
     )
@@ -106,49 +104,23 @@ class SchedulerServer:
         trace_export: Optional[str] = None,
         shed_fractions: Optional[dict] = None,
         devprof_sample: Optional[int] = None,
-        xla_cache: Optional[str] = None,
         prewarm: bool = False,
     ):
-        # persistent compile cache under the daemon's state dir: a
-        # restarted sidecar skips the multi-second (16.5s on TPU,
-        # BENCH_r03) cycle-kernel compile and is serving warm cycles at
-        # informer-sync speed.  Must happen before the first compile;
-        # KOORD_XLA_CACHE (operator override) wins if set.
         if state_dir is None:
             state_dir = default_state_dir()
         self.state_dir = state_dir
         if state_dir:
-            import koordinator_tpu
-
             try:
                 os.makedirs(state_dir, exist_ok=True)
             except OSError as exc:
-                # the compile cache is an optimization: an unwritable
-                # default state dir (readOnlyRootFilesystem, no HOME)
-                # must cost the restart-compile, not the daemon
+                # dumps, ledger and prewarm file are diagnostics: an
+                # unwritable default (readOnlyRootFilesystem, no HOME)
+                # costs them, not the daemon (each writer logs its miss)
                 import logging
 
                 logging.getLogger(__name__).warning(
-                    "state dir %s unavailable (%s); persistent compile "
-                    "cache disabled for this run",
-                    state_dir,
-                    exc,
+                    "state dir %s unavailable: %s", state_dir, exc
                 )
-            else:
-                koordinator_tpu.configure_compilation_cache(
-                    os.path.join(state_dir, "xla-cache")
-                )
-        if xla_cache is not None:
-            # an EXPLICIT --xla-cache outranks both the state-dir
-            # default above and the KOORD_XLA_CACHE env (force=True):
-            # the operator typed it.  "" / "0" disables the cache.
-            import koordinator_tpu
-
-            koordinator_tpu.configure_compilation_cache(
-                None if xla_cache in ("", "0") else xla_cache,
-                force=True,
-            )
-        self.xla_cache = xla_cache
         cfg = DEFAULT_CYCLE_CONFIG
         self.profiles = []
         if config_path:
@@ -375,7 +347,7 @@ class SchedulerServer:
                 cfg, lambda: self.elector.is_leader, mesh=mesh,
                 mesh_resident=mesh_resident,
                 # flight-recorder dumps (obs/flight.py) land under
-                # <state-dir>/flight on cycle error/demotion/SIGUSR1
+                # <state-dir>/flight on cycle error/SIGUSR1
                 state_dir=state_dir,
                 **servicer_kw,
             )
@@ -405,18 +377,11 @@ class SchedulerServer:
 
             def do_GET(self):
                 if self.path == "/healthz":
-                    # demoted kernel shape-buckets ride health (round-3
-                    # review: demotion must be visible beyond a log line)
-                    demoted = {
-                        "/".join(map(str, k)): v
-                        for k, v in pallas_demotions().items()
-                    }
                     self._reply(
                         200,
                         {
                             "ok": True,
                             "leader": outer.elector.is_leader,
-                            "kernel_demotions": demoted,
                             # warm-cycle visibility: whether the last Sync
                             # landed on the resident device tensors
                             # ("warm") or dropped residency ("cold")
@@ -457,10 +422,6 @@ class SchedulerServer:
                     registry.gauge_set(
                         "koord_scheduler_leader",
                         int(outer.elector.is_leader),
-                    )
-                    registry.gauge_set(
-                        "koord_scheduler_kernel_demotions",
-                        len(pallas_demotions()),
                     )
                     reply_text(self, registry.render())
                     return
@@ -1241,17 +1202,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "KOORD_DEVPROF_SAMPLE)",
     )
     ap.add_argument(
-        "--xla-cache", dest="xla_cache",
-        default=None,
-        help="persistent XLA compile cache directory (docs/KERNEL.md "
-        "\"Cold path\"): an explicit path here outranks both the "
-        "<state-dir>/xla-cache default and the KOORD_XLA_CACHE env; "
-        "'' or '0' disables the cache for this run.  Point every "
-        "replica of a tier (leader, followers, autoscaler spawns) at "
-        "the SAME directory so one replica's compile is every "
-        "replica's warm start (env: KOORD_XLA_CACHE)",
-    )
-    ap.add_argument(
         "--prewarm", action="store_true",
         default=bool(os.environ.get("KOORD_PREWARM")),
         help="AOT signature prewarm (docs/KERNEL.md \"Cold path\"): "
@@ -1268,9 +1218,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     ap.add_argument(
         "--state-dir", default=None,
         help="daemon state directory (default: $XDG_STATE_HOME/"
-        "koord-scheduler, per-user); the persistent XLA compile cache "
-        "lives at <state-dir>/xla-cache so a restarted sidecar skips the "
-        "multi-second cycle-kernel compile (KOORD_XLA_CACHE overrides)",
+        "koord-scheduler, per-user): flight-recorder dumps, the devprof "
+        "ledger, prewarm signatures, the journal.  The persistent XLA "
+        "compile cache is JAX_COMPILATION_CACHE_DIR, else <checkout>/"
+        ".jax_cache",
     )
     return ap
 
@@ -1316,7 +1267,6 @@ def main(argv=None) -> int:
         trace_export=args.trace_export,
         shed_fractions=shed_fractions,
         devprof_sample=args.devprof_sample,
-        xla_cache=args.xla_cache,
         prewarm=args.prewarm,
     ).start()
     try:

@@ -184,15 +184,15 @@ def _build(snapshot, *, cfg):
 def _build_sharded(snapshot, *, cfg, mesh):
     from koordinator_tpu.parallel.mesh import (
         POD_AXIS,
-        shard_map_compat,
         snapshot_pod_partition_specs,
     )
 
-    return shard_map_compat(
+    return jax.shard_map(
         lambda snap: _build_carry(snap, cfg),
         mesh=mesh,
         in_specs=(snapshot_pod_partition_specs(snapshot),),
         out_specs=(P(POD_AXIS, None), P(POD_AXIS)),
+        check_vma=False,
     )(snapshot)
 
 
@@ -232,16 +232,16 @@ def _count_blocks_sharded(snapshot, *, cfg, mesh):
     output lands in global block order because device d's shard IS
     blocks [d*NB/D, (d+1)*NB/D)."""
     from koordinator_tpu.parallel.mesh import (
-        shard_map_compat,
         snapshot_partition_specs,
     )
     from koordinator_tpu.parallel.mesh import CLUSTER_AXIS
 
-    return shard_map_compat(
+    return jax.shard_map(
         lambda snap: _count_carry(snap, cfg),
         mesh=mesh,
         in_specs=(snapshot_partition_specs(snapshot),),
         out_specs=P(CLUSTER_AXIS, None),
+        check_vma=False,
     )(snapshot)
 
 
@@ -386,7 +386,6 @@ def _refresh(snapshot, cand, count, node_idx, pod_idx, *, cfg):
 def _refresh_sharded(snapshot, cand, count, node_idx, pod_idx, *, cfg, mesh):
     from koordinator_tpu.parallel.mesh import (
         POD_AXIS,
-        shard_map_compat,
         snapshot_pod_partition_specs,
     )
 
@@ -403,7 +402,7 @@ def _refresh_sharded(snapshot, cand, count, node_idx, pod_idx, *, cfg, mesh):
         loc = jnp.where((loc >= 0) & (loc < p_local), loc, p_local)
         return _refresh_carry(snap_l, cand_l, count_l, nidx, loc, cfg)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -411,6 +410,7 @@ def _refresh_sharded(snapshot, cand, count, node_idx, pod_idx, *, cfg, mesh):
             cspec, P(POD_AXIS), P(), P(),
         ),
         out_specs=(cspec, P(POD_AXIS)),
+        check_vma=False,
     )(snapshot, cand, count, node_idx, pod_idx)
 
 
@@ -449,16 +449,16 @@ def _score(snapshot, cand, *, cfg):
 def _score_sharded(snapshot, cand, *, cfg, mesh):
     from koordinator_tpu.parallel.mesh import (
         POD_AXIS,
-        shard_map_compat,
         snapshot_pod_partition_specs,
     )
 
     cspec = P(POD_AXIS, None)
-    return shard_map_compat(
+    return jax.shard_map(
         lambda snap, cd: _score_carry(snap, cd, cfg),
         mesh=mesh,
         in_specs=(snapshot_pod_partition_specs(snapshot), cspec),
         out_specs=(cspec, cspec),
+        check_vma=False,
     )(snapshot, cand)
 
 

@@ -10,8 +10,9 @@ committed deltas invalidated:
 
 * **dirty columns** — node rows a delta scattered (or whose derived
   freshness flipped): gather those node rows, run the scoring math for
-  every pod against just them (O(P x d)), scatter the [P, d] result
-  into the resident tensors.
+  every pod against just them (O(P x d)), and select the [P, d] result
+  into the resident tensors through a node-to-column map (a column
+  scatter needs more scoped VMEM than v5e grants).
 * **dirty rows** — pod rows that changed (requests/estimated deltas,
   priority-class flips): gather those pod rows, score them against
   every node (O(d_p x N)), scatter the [d_p, N] result in.
@@ -19,7 +20,7 @@ committed deltas invalidated:
 Exactness contract: every term of the scoring math
 (solver/greedy.py ``score_all`` — the SHARED body, so the engines
 cannot drift) is cellwise in (pod row, node row), so gather-compute-
-scatter produces the very same bits a full ``score_cycle`` would put
+place produces the very same bits a full ``score_cycle`` would put
 in those cells; untouched cells keep the bits the last launch wrote.
 tests/test_score_incremental.py fuzzes randomized warm streams against
 the full-rescore oracle byte-for-byte.
@@ -34,7 +35,7 @@ koordlint retrace-hazard rule rejects that shape statically).
 
 Donation: the resident ``scores`` tensor ([P, N] i64, the big one) is
 donated — the pre-rescore buffer is dead the moment the new tensor
-exists, so the scatter aliases in place.  ``feasible`` is NOT donated:
+exists, so the update aliases in place.  ``feasible`` is NOT donated:
 coalesced Score readbacks ``device_get`` the feasible tensor they
 captured at launch, and a non-donating warm commit (derived-column
 only) does not drain the pipeline — donating feasible could delete a
@@ -118,8 +119,17 @@ def _rescore_body(snapshot, scores, feasible, node_idx, pod_idx, cfg):
     s_cols, f_cols = score_all(
         dataclasses.replace(snapshot, nodes=sub_nodes), cfg
     )
-    scores = scores.at[:, node_idx].set(s_cols, mode="drop")
-    feasible = feasible.at[:, node_idx].set(f_cols, mode="drop")
+    # placed by a select through a column map, not a column scatter:
+    # XLA's TPU scatter of P-long column windows needs more scoped VMEM
+    # than v5e grants (RESOURCE_EXHAUSTED at 16,384 x 2,048 with 64
+    # dirty columns, tests/test_chip_compile.py); the select streams
+    src = jnp.full((n_rows,), -1, jnp.int32).at[node_idx].set(
+        jnp.arange(node_idx.shape[0], dtype=jnp.int32), mode="drop"
+    )
+    hit = (src >= 0)[None, :]
+    col = jnp.maximum(src, 0)
+    scores = jnp.where(hit, jnp.take(s_cols, col, axis=1), scores)
+    feasible = jnp.where(hit, jnp.take(f_cols, col, axis=1), feasible)
     # dirty ROWS: the gathered pod rows vs every node -> [dB_p, N]
     sub_pods = _take_pods(pods, jnp.clip(pod_idx, 0, p_rows - 1))
     s_rows, f_rows = score_all(
@@ -154,7 +164,6 @@ def _rescore_sharded(snapshot, scores, feasible, node_idx, pod_idx, *, cfg, mesh
 
     from koordinator_tpu.parallel.mesh import (
         CLUSTER_AXIS,
-        shard_map_compat,
         snapshot_partition_specs,
     )
 
@@ -168,7 +177,7 @@ def _rescore_sharded(snapshot, scores, feasible, node_idx, pod_idx, *, cfg, mesh
         loc = jnp.where(owned, loc, n_local)  # not-mine/pad -> dropped
         return _rescore_body(snap_local, scores_l, feasible_l, loc, pidx, cfg)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -176,6 +185,7 @@ def _rescore_sharded(snapshot, scores, feasible, node_idx, pod_idx, *, cfg, mesh
             score_spec, score_spec, P(), P(),
         ),
         out_specs=(score_spec, score_spec),
+        check_vma=False,
     )(snapshot, scores, feasible, node_idx, pod_idx)
 
 

@@ -442,10 +442,11 @@ def _wave_cycle_kernel(
 
     def _lane(row, m):
         """Lane m (traced) of a [1, 128] row -> i32 scalar (dynamic lane
-        slicing is costly on the VPU; a masked lane sum is one vector op)."""
-        return jnp.sum(
-            jnp.where(lane_iota == m, row, _i32(0)), dtype=jnp.int32
-        )
+        slicing is costly on the VPU; a masked lane max is one vector op).
+        Scalar reductions here are max, never sum: Mosaic lowers a
+        scalar i32 sum through an i64 accumulator under x64, which v5e
+        refuses ("64-bit types are not supported")."""
+        return jnp.max(jnp.where(lane_iota == m, row, I32_MIN))
 
     def frozen_masked(j):
         """Frozen masked scores [n_rows, 1] for block-row j.  Quota is
@@ -525,10 +526,8 @@ def _wave_cycle_kernel(
             nr, ne, a, usage_row, fresh, sreq, est, recip_c,
             fit_w_row, la_w_row, fit_w_sum, la_w_sum, cfg,
         )
-        feas_s = jnp.sum(
-            jnp.where(feas, _i32(1), _i32(0)), dtype=jnp.int32
-        ) != _i32(0)
-        score = jnp.sum(total, dtype=jnp.int32)
+        feas_s = jnp.max(jnp.where(feas, _i32(1), _i32(0))) != _i32(0)
+        score = jnp.max(total)  # [1, 1]: the one element
         if has_extras:
             xv = _lane(xcomb_ref[pl.ds(c, 1), :], j)
             feas_s = feas_s & (xv != _i32(XCOMB_INFEASIBLE))
@@ -616,13 +615,11 @@ def _wave_cycle_kernel(
                 # non-candidates whose keys moved
                 def consider(w, st2):
                     bs2, bi2 = st2
-                    cw = jnp.sum(
-                        jnp.where(sub_iota_w == w, choices_col, _i32(0)),
-                        dtype=jnp.int32,
+                    cw = jnp.max(
+                        jnp.where(sub_iota_w == w, choices_col, I32_MIN)
                     )
-                    comm = jnp.sum(
-                        jnp.where(sub_iota_w == w, committed_col, _i32(0)),
-                        dtype=jnp.int32,
+                    comm = jnp.max(
+                        jnp.where(sub_iota_w == w, committed_col, _i32(0))
                     ) != _i32(0)
                     live = comm & (w < i_w)
                     cw_eff = jnp.maximum(cw, _i32(0))

@@ -66,7 +66,7 @@ def _scatter_flat_sharded(arr, idx, val, *, mesh):
     (shape, dtype, bucket, mesh), same sticky-bucket economics as
     ``_scatter_flat``.
     """
-    from koordinator_tpu.parallel.mesh import CLUSTER_AXIS, shard_map_compat
+    from koordinator_tpu.parallel.mesh import CLUSTER_AXIS
     from jax.sharding import PartitionSpec as P
 
     spec = P(CLUSTER_AXIS, *([None] * (arr.ndim - 1)))
@@ -82,8 +82,9 @@ def _scatter_flat_sharded(arr, idx, val, *, mesh):
         flat = flat.at[loc].set(val.astype(a.dtype), mode="drop")
         return flat.reshape(a.shape)
 
-    return shard_map_compat(
-        body, mesh=mesh, in_specs=(spec, P(), P()), out_specs=spec
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(spec, P(), P()), out_specs=spec,
+        check_vma=False,
     )(arr, idx, val)
 
 

@@ -122,6 +122,13 @@ def score_feasible(score):
     return score > SENTINEL_SCORE // 2
 
 
+def hit_sum(hit, rows):
+    """``sum_w hit[w, k] * rows[w, r]`` for a bool ``hit`` [W, K] and
+    i64 ``rows`` [W, R] -> [K, R], exact.  A masked sum, not an einsum:
+    the TPU has no i64 dot and XLA's X64 rewrite implements none."""
+    return jnp.sum(jnp.where(hit[:, :, None], rows[:, None, :], 0), axis=0)
+
+
 def flatten_shards(a):
     """Align an ``all_gather``'d per-shard candidate payload by wave
     lane: ``[S, W, M, ...] -> [W, S*M, ...]``.  The flattened axis is
@@ -237,12 +244,8 @@ def resolve_wave(
             hit_u = earlier[:, None] & (
                 choices[:, None] == u_gid[None, :]
             )  # [W, U]
-            dreq_u = jnp.einsum(
-                "wu,wr->ur", hit_u.astype(jnp.int64), preq_wave
-            )
-            dest_u = jnp.einsum(
-                "wu,wr->ur", hit_u.astype(jnp.int64), pest_wave
-            )
+            dreq_u = hit_sum(hit_u, preq_wave)
+            dest_u = hit_sum(hit_u, pest_wave)
             if prod_sensitive:
                 usage_u = jnp.where(
                     pprod_wave[i], universe["uprod"], universe["usage"]
@@ -287,12 +290,8 @@ def resolve_wave(
             hit = earlier[:, None] & (
                 choices[:, None] == c_nodes[None, :]
             )  # [W, M]
-            dreq = jnp.einsum(
-                "wm,wr->mr", hit.astype(jnp.int64), preq_wave
-            )
-            dest = jnp.einsum(
-                "wm,wr->mr", hit.astype(jnp.int64), pest_wave
-            )
+            dreq = hit_sum(hit, preq_wave)
+            dest = hit_sum(hit, pest_wave)
             dirty = jnp.any(hit, axis=0)  # [M]
             # re-key dirtied candidates with the SAME step semantics the
             # scan path and the frozen wave scoring use — the candidate
@@ -552,13 +551,11 @@ def _wave_assign(
             prod_sensitive=prod_sensitive,
         )
 
-        # apply the committed prefix to the carried node state
-        onehot = (
-            (choices[:, None] == jnp.arange(N, dtype=choices.dtype)[None, :])
-            & committed[:, None]
-        ).astype(jnp.int64)
-        nreq = nreq + jnp.einsum("wn,wr->nr", onehot, preq_wave)
-        nest = nest + jnp.einsum("wn,wr->nr", onehot, pest_wave)
+        # apply the committed prefix to the carried node state (an
+        # exact scatter-add; uncommitted rows target N and drop)
+        target = jnp.where(committed, choices, N)
+        nreq = nreq.at[target].add(preq_wave, mode="drop")
+        nest = nest.at[target].add(pest_wave, mode="drop")
 
         write = jnp.where(done, choices.astype(jnp.int32), jnp.int32(-1))
         # positions not committed this round keep their buffer value

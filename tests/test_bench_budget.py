@@ -1,6 +1,6 @@
 """bench.py budget accounting — stdlib only (no jax import).
 
-BENCH_r05 was rc=124 with an EMPTY tail: the driver's axe landed before
+A bench run once ended rc=124 with an EMPTY tail: the driver's axe landed before
 the CPU-fallback artifact printed, because stage windows could overshoot
 the total budget (an unclamped inter-probe sleep, and a 60s floor on the
 CPU window applied even when less than 60s remained).  The invariant
@@ -115,7 +115,7 @@ class TestArtifactDeadline:
     real.  The budget accountant bounds the windows bench GRANTS
     itself, but a stage that hangs past its window — or a driver
     timeout shorter than the budget — used to kill the process with
-    nothing on stdout (BENCH_r05).  The hard deadline replays here
+    nothing on stdout.  The hard deadline replays here
     under an injected clock: a slow stage never returns, the watchdog
     fires, and a schema-valid artifact with ``"truncated": true`` is
     flushed before exit."""
@@ -522,7 +522,7 @@ class TestTraceArtifactFields:
         replay hanging past the budget (a wedged UDS server, a compile
         storm) must still put ONE schema-valid truncated artifact on
         stdout, stamped with the trace stage it died in — the
-        BENCH_r05 rc=124-no-artifact class must not reopen for the new
+        rc=124-no-artifact class must not reopen for the new
         config."""
         emitted, fired = [], []
         now = [0.0]

@@ -1,0 +1,169 @@
+"""AOT compiles of the served path's device programs for a described v5e.
+
+Nothing runs: each program is lowered and compiled for one chip of a
+described, unattached ``v5e:2x2`` topology, which raises what the chip's
+compiler would raise (an unsupported 64-bit type inside a kernel, more
+VMEM than a kernel may use, a program that does not fit HBM).  Shapes
+are the served buckets of BASELINE.json config 4: 10,000 pods in the
+16,384 pod bucket, 2,000 nodes in the 2,048 node bucket.  The dense
+kernel is also compiled at the node-bucket limits run_cycle routes by
+(``solver.DENSE_MAX_NODES``), and refused just past them.
+
+The topology is described inside a module fixture, never at import: only
+the worker that runs this file loads the TPU library.
+"""
+
+import os
+
+import jax
+import pytest
+
+from koordinator_tpu.config import DEFAULT_CYCLE_CONFIG, CycleConfig
+from koordinator_tpu.harness import generators
+
+POD_BUCKET, NODE_BUCKET = 16384, 2048
+# score_cycle's i64 [P, N] intermediates at the served buckets: 3.53e9
+# bytes of temp measured with jax/libtpu 0.9.0 / 0.0.34
+SCORE_TEMP_CEILING = 3.6e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the TPU compiler would otherwise log under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile cannot be read back without the chip:
+    # keep these out of the persistent cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, node_bucket=NODE_BUCKET):
+    snap = generators.quota_colocation_snapshot(
+        pods=10_000, nodes=2_000,
+        node_bucket=node_bucket, pod_bucket=POD_BUCKET,
+    )[0]
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        snap,
+    )
+
+
+def _dense(one_chip, node_bucket, extras):
+    from koordinator_tpu.solver.pallas_dense import _greedy_assign_dense
+
+    spec = _spec(one_chip, node_bucket)
+    cells = (POD_BUCKET, node_bucket)
+    mask = scores = None
+    if extras:
+        mask = jax.ShapeDtypeStruct(cells, bool, sharding=one_chip)
+        scores = jax.ShapeDtypeStruct(cells, "int64", sharding=one_chip)
+    return _greedy_assign_dense.__wrapped__.lower(
+        spec, DEFAULT_CYCLE_CONFIG, False, mask, scores
+    ).compile()
+
+
+def test_dense_kernel_served_buckets(one_chip):
+    compiled = _dense(one_chip, NODE_BUCKET, extras=False)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("extras", [False, True])
+def test_dense_kernel_at_node_limit(one_chip, extras):
+    from koordinator_tpu import solver
+
+    limit = solver.DENSE_MAX_NODES_EXTRAS if extras else solver.DENSE_MAX_NODES
+    compiled = _dense(one_chip, limit, extras)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_kernel_refused_past_node_limit(one_chip):
+    from koordinator_tpu import solver
+
+    with pytest.raises(Exception, match="vmem"):
+        _dense(one_chip, 2 * solver.DENSE_MAX_NODES, extras=False)
+
+
+def test_wide_wave_kernel_served_buckets(one_chip):
+    from koordinator_tpu.solver.pallas_cycle import _greedy_assign_pallas
+
+    compiled = _greedy_assign_pallas.__wrapped__.lower(
+        _spec(one_chip), CycleConfig(wave=32, top_m=4)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_score_cycle_served_buckets(one_chip):
+    from koordinator_tpu.solver.greedy import score_cycle
+
+    compiled = score_cycle.__wrapped__.lower(
+        _spec(one_chip), cfg=DEFAULT_CYCLE_CONFIG
+    ).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < SCORE_TEMP_CEILING, temp
+
+
+def test_incremental_rescore_served_buckets(one_chip):
+    """The warm Score engine at the smoke's dirty buckets (48 usage
+    deltas -> 64 dirty columns, 300 churned pods -> 512 dirty rows):
+    a column scatter here was refused for scoped VMEM on the chip."""
+    import jax.numpy as jnp
+
+    from koordinator_tpu.solver.incremental import _rescore
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cells = (POD_BUCKET, NODE_BUCKET)
+    _rescore.__wrapped__.lower(
+        _spec(one_chip), arg(cells, jnp.int64), arg(cells, bool),
+        arg((64,), jnp.int64), arg((512,), jnp.int64),
+        cfg=DEFAULT_CYCLE_CONFIG,
+    ).compile()
+
+
+def test_shard_wave_cycle_on_four_chips(topo):
+    """The mesh-resident Assign (``--mesh 4``): the round-based wave
+    cycle over a 4-chip cluster mesh, node tables sharded.  Its in-wave
+    deltas were an i64 einsum, which the TPU's X64 rewrite refuses."""
+    from koordinator_tpu.parallel.mesh import (
+        cluster_mesh,
+        snapshot_shardings,
+    )
+    from koordinator_tpu.parallel.shard_assign import _assign_waves
+
+    mesh = cluster_mesh(topo.devices[:4])
+    snap = generators.quota_colocation_snapshot(
+        pods=10_000, nodes=2_000,
+        node_bucket=NODE_BUCKET, pod_bucket=POD_BUCKET,
+    )[0]
+    spec = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        snap, snapshot_shardings(snap, mesh),
+    )
+    compiled = _assign_waves.__wrapped__.lower(
+        spec, None, None, cfg=DEFAULT_CYCLE_CONFIG, mesh=mesh,
+        has_mask=False, has_scores=False, wave=32,
+        top_m=DEFAULT_CYCLE_CONFIG.top_m,
+    ).compile()
+    assert "all-gather" in compiled.as_text()
+

@@ -2444,10 +2444,10 @@ class TestUnregisteredJitBoundary:
 
     def test_shard_map_outside_jit_fires(self):
         vs = self._lint("""
-        from koordinator_tpu.parallel.mesh import shard_map_compat
+        import jax
 
         def helper(mesh, x):
-            return shard_map_compat(
+            return jax.shard_map(
                 lambda a: a, mesh=mesh, in_specs=None, out_specs=None
             )(x)
         """)
@@ -2459,12 +2459,11 @@ class TestUnregisteredJitBoundary:
         from functools import partial
         import jax
         from koordinator_tpu.obs import devprof
-        from koordinator_tpu.parallel.mesh import shard_map_compat
 
         @devprof.boundary("solver.fixture._sharded")
         @partial(jax.jit, static_argnames=("mesh",))
         def _sharded(x, *, mesh):
-            return shard_map_compat(
+            return jax.shard_map(
                 lambda a: a, mesh=mesh, in_specs=None, out_specs=None
             )(x)
         """)

@@ -5,7 +5,7 @@ Covers the subsystem contracts end to end:
 * span recorder mechanics (cycle ids, nesting, bounded buffers, notes);
 * metrics registry histogram rendering + IDEMPOTENT family
   registration (the duplicate # HELP/# TYPE fix);
-* flight recorder ring wraparound, dump-on-error, dump-on-demotion,
+* flight recorder ring wraparound, dump-on-error,
   dump-on-SIGUSR1, and schema validation of every written dump;
 * a REAL cycle through the ScorerServicer populating the scorer
   families, served in valid Prometheus text from the daemon's /metrics;
@@ -285,15 +285,15 @@ class TestFlightRecorder:
         assert len(os.listdir(flight_dir)) == flight_mod.MAX_DUMPS_KEPT
 
     def test_dump_rate_limit_suppresses_floods(self, tmp_path):
-        """A trigger storm (demotion loop, misbehaving client) must not
+        """A trigger storm (error loop, misbehaving client) must not
         stall serving on per-event disk I/O or churn real dumps out of
         the pruned directory; sigusr1 is exempt (the operator asked)."""
         fr = FlightRecorder(state_dir=str(tmp_path))
         fr.record(self._record(0))
-        assert fr.dump("demotion")
-        assert fr.dump("demotion") is None  # inside the interval
+        assert fr.dump("cycle-error")
+        assert fr.dump("cycle-error") is None  # inside the interval
         assert fr.dumps_suppressed == 1
-        assert fr.dump("cycle-error")  # distinct reason: own limiter
+        assert fr.dump("bad-frame")  # distinct reason: own limiter
         assert fr.dump("sigusr1") and fr.dump("sigusr1")  # never limited
 
     def test_failed_write_does_not_close_the_rate_window(self, tmp_path,
@@ -599,27 +599,35 @@ class TestServicerTelemetry:
             "koord_scorer_cycle_errors_total", {"stage": "assign"}
         ) == 1
 
-    def test_demotion_listener_counts_and_dumps(self, tmp_path):
-        from koordinator_tpu import solver
+    def test_kernel_fault_fails_assign_and_dumps(self, tmp_path,
+                                                 monkeypatch):
+        """A fault inside the Pallas kernel itself (the TPU branch of
+        run_cycle, routed on the CPU platform) fails the Assign and
+        dumps the flight ring as a cycle error — never a silent
+        demotion to the scan."""
+        import jax
+
+        from koordinator_tpu.solver import pallas_dense
 
         sv, state, reply = _servicer(str(tmp_path))
-        solver._record_failure(("wide", "fixture-bucket"))
-        try:
-            reg = sv.telemetry.registry
-            assert reg.get("koord_scorer_kernel_demotions_total") == 1
-            flight_dir = os.path.join(tmp_path, "flight")
-            dumps = [f for f in os.listdir(flight_dir) if "demotion" in f]
-            assert dumps
-            with open(os.path.join(flight_dir, dumps[0])) as f:
-                doc = json.load(f)
-            assert validate_flight_dump(doc) == []
-            # the demoted bucket rides the dump's extra block, NOT the
-            # span recorder (demotions fire on the demoting thread,
-            # which may not own this telemetry's spans)
-            assert doc["extra"]["bucket"] == "wide/fixture-bucket"
-            assert doc["extra"]["failures"] == 1
-        finally:
-            solver._record_success(("wide", "fixture-bucket"))
+
+        def boom(*a, **kw):
+            raise RuntimeError("mosaic refused the kernel")
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pallas_dense, "greedy_assign_dense", boom)
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            sv.assign(pb2.AssignRequest(snapshot_id=reply.snapshot_id))
+        flight_dir = os.path.join(tmp_path, "flight")
+        dumps = [f for f in os.listdir(flight_dir) if "cycle-error" in f]
+        assert dumps
+        with open(os.path.join(flight_dir, dumps[0])) as f:
+            doc = json.load(f)
+        assert validate_flight_dump(doc) == []
+        assert "mosaic refused" in doc["cycles"][-1]["error"]
+        assert sv.telemetry.registry.get(
+            "koord_scorer_cycle_errors_total", {"stage": "assign"}
+        ) == 1
 
 
 class TestDaemonMetricsEndpoint:

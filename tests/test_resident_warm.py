@@ -727,7 +727,7 @@ logging.getLogger().setLevel(logging.WARNING)
 logging.getLogger("jax._src.compiler").setLevel(logging.DEBUG)
 import jax
 jax.config.update("jax_platforms", "cpu")
-import koordinator_tpu  # wires the persistent cache from KOORD_XLA_CACHE
+import koordinator_tpu  # JAX_COMPILATION_CACHE_DIR set: the package sets none
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 import numpy as np
 from koordinator_tpu.model import encode_snapshot
@@ -754,7 +754,7 @@ class TestCompileCacheSmoke:
         cache = str(tmp_path / "xla-cache")
         env = dict(
             os.environ,
-            KOORD_XLA_CACHE=cache,
+            JAX_COMPILATION_CACHE_DIR=cache,
             JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=1",
         )
@@ -781,15 +781,24 @@ class TestCompileCacheSmoke:
         assert [f for f in files2 if f not in files1] == []
         assert p2.stdout.splitlines()[-1] == p1.stdout.splitlines()[-1]
 
-    def test_configure_compilation_cache_env_override_wins(self, monkeypatch):
-        import jax
-
+    @pytest.mark.parametrize("env_dir", [None, "outside"])
+    def test_cache_dir_rule(self, tmp_path, env_dir):
+        """One rule: JAX_COMPILATION_CACHE_DIR, when set, is where the
+        cache lives and the package sets nothing; unset, the cache goes
+        to the fixed in-checkout path."""
         import koordinator_tpu
 
-        before = jax.config.jax_compilation_cache_dir
-        monkeypatch.setenv("KOORD_XLA_CACHE", "/elsewhere")
-        koordinator_tpu.configure_compilation_cache("/tmp/should-not-win")
-        assert jax.config.jax_compilation_cache_dir == before
-        monkeypatch.delenv("KOORD_XLA_CACHE")
-        koordinator_tpu.configure_compilation_cache(before)
-        assert jax.config.jax_compilation_cache_dir == before
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        want = koordinator_tpu.XLA_CACHE_DIR
+        if env_dir is not None:
+            want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import jax, koordinator_tpu; "
+             "print(jax.config.jax_compilation_cache_dir)"],
+            capture_output=True, text=True, timeout=120, env=env,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == want

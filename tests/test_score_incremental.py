@@ -364,11 +364,15 @@ class TestScoreMemoSeam:
         assert self._memo_hits(sv) == hits + 1
         assert _incr_count(sv, "incr") == incr_launches  # no new launch
 
-    def test_owner_failure_falls_back_without_poisoning(self, monkeypatch):
-        """An incremental launch that raises must (a) still answer its
-        batch exactly via the full rescore, (b) count result=fallback,
-        and (c) leave a residency the NEXT launch can trust — never a
-        half-scattered tensor."""
+    def test_owner_failure_fails_the_score_without_poisoning(
+        self, monkeypatch
+    ):
+        """An incremental launch that raises must (a) fail its batch —
+        no fallback hides the device fault — and (b) leave no residency
+        behind, never a half-scattered tensor: the next Score rescores
+        in full, exactly, and the engine then serves incrementally."""
+        import pytest
+
         from koordinator_tpu.solver import incremental as incr_mod
 
         sv, oracle, state = self._pair()
@@ -382,13 +386,11 @@ class TestScoreMemoSeam:
             raise RuntimeError("injected owner failure")
 
         monkeypatch.setattr(incr_mod, "rescore_dirty", boom)
-        assert _flat(sv, 4) == _flat(oracle, 4)  # exact despite failure
-        assert calls and _incr_count(sv, "fallback") == 1
-        res = sv.state.score_residency()
-        assert res is not None and res.dirty_nodes == set()
-        # engine recovers: the refreshed tensors serve the next delta
-        # incrementally and exactly
+        with pytest.raises(RuntimeError, match="injected owner failure"):
+            _flat(sv, 4)
+        assert calls and sv.state.score_residency() is None
         monkeypatch.setattr(incr_mod, "rescore_dirty", real)
+        assert _flat(sv, 4) == _flat(oracle, 4)  # full rescore
         self._sync_both(sv, oracle, state)
         assert _flat(sv, 4) == _flat(oracle, 4)
         assert _incr_count(sv, "incr") == 1

@@ -972,31 +972,58 @@ class TestHealthzSloBlock:
             s.stop()
 
 
-class TestKernelDemotionSurfacing:
-    def test_healthz_and_metrics_expose_demotions(self, tmp_path):
-        import urllib.request
+class TestKernelFaultSurfacing:
+    def test_kernel_fault_fails_the_assign_rpc(self, tmp_path,
+                                               monkeypatch):
+        """A Pallas kernel fault on the served path reaches the client
+        as a failed Assign RPC and the error counter; /healthz carries
+        no demotion state (there is none)."""
+        import grpc
+        import jax
+        import numpy as np
 
-        from koordinator_tpu import solver
+        from koordinator_tpu.bridge.client import ScorerClient
         from koordinator_tpu.scheduler.server import SchedulerServer
+        from koordinator_tpu.solver import pallas_dense
 
         s = SchedulerServer(
             lease_path=str(tmp_path / "l.lease"),
             uds_path=str(tmp_path / "scorer.sock"),
-            enable_grpc=False,
+            state_dir=str(tmp_path / "state"),
         ).start()
-        bucket = ("dense", "tpu", 2000, 10000, False)
+        client = ScorerClient(f"unix://{tmp_path / 'scorer.sock'}")
         try:
-            solver._record_failure(bucket)
+            deadline = time.monotonic() + 10
+            while not s.elector.is_leader and time.monotonic() < deadline:
+                time.sleep(0.05)
+            assert s.elector.is_leader
+            alloc = np.full((4, 13), 64000, np.int64)
+            client.sync(
+                node_allocatable=alloc,
+                node_requested=np.zeros_like(alloc),
+                node_usage=np.zeros_like(alloc),
+                pod_requests=np.full((6, 13), 1000, np.int64),
+            )
+
+            def boom(*a, **kw):
+                raise RuntimeError("mosaic refused the kernel")
+
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            monkeypatch.setattr(pallas_dense, "greedy_assign_dense", boom)
+            with pytest.raises(grpc.RpcError) as err:
+                client.assign()
+            assert "mosaic refused" in err.value.details()
             with urllib.request.urlopen(
                 f"http://127.0.0.1:{s.http_port}/healthz", timeout=5
             ) as r:
                 doc = json.loads(r.read())
-            assert "dense/tpu/2000/10000/False" in doc["kernel_demotions"]
+            assert "kernel_demotions" not in doc
             with urllib.request.urlopen(
                 f"http://127.0.0.1:{s.http_port}/metrics", timeout=5
             ) as r:
                 text = r.read().decode()
-            assert "koord_scheduler_kernel_demotions 1" in text
+            assert 'koord_scorer_cycle_errors_total{stage="assign"} 1' in text
+            assert "demotion" not in text
         finally:
-            solver._record_success(bucket)
+            client.close()
             s.stop()

@@ -241,41 +241,76 @@ def test_quota_table_round_trip_feasible():
     assert int((np.asarray(result.assignment) >= 0).sum()) > 0
 
 
-class TestPallasDemotionBackoff:
-    """run_cycle's kernel-failure demotion must retry with backoff, not
-    demote a shape bucket for the process lifetime (round-3 review)."""
+class TestPrelaunchKernelChoice:
+    """run_cycle picks its kernel before launch from what the input
+    shows (node bucket vs the kernels' VMEM limits, i32 bounds, extras
+    magnitude).  Nothing catches a kernel failure: it raises."""
 
-    def test_retry_window_reopens(self):
+    @staticmethod
+    def _on_tpu(monkeypatch, **kernels):
+        """Route run_cycle down its TPU branch on the CPU test platform,
+        with the named Pallas entry points replaced."""
+        import jax
+
+        from koordinator_tpu.solver import pallas_cycle, pallas_dense
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mods = {"dense": (pallas_dense, "greedy_assign_dense"),
+                "wide": (pallas_cycle, "greedy_assign_pallas")}
+        for name, fn in kernels.items():
+            mod, attr = mods[name]
+            monkeypatch.setattr(mod, attr, fn)
+
+    def test_kernel_fault_raises(self, monkeypatch):
+        import pytest
+
+        from koordinator_tpu.solver import run_cycle
+
+        def boom(*a, **k):
+            raise RuntimeError("kernel refused")
+
+        self._on_tpu(monkeypatch, dense=boom, wide=boom)
+        nodes, pods, gangs, quotas = generators.loadaware_joint(
+            seed=2, pods=16, nodes=8
+        )
+        snap = encode_snapshot(nodes, pods, gangs, quotas)
+        for cfg in (CycleConfig(), CycleConfig(wave=8, top_m=2)):
+            with pytest.raises(RuntimeError, match="kernel refused"):
+                run_cycle(snap, cfg)
+
+    def test_over_limit_bucket_takes_scan(self, monkeypatch):
         from koordinator_tpu import solver
 
-        bucket = ("dense", "tpu", 2000, 10000, False)
-        try:
-            solver._record_failure(bucket)
-            fails, wait = solver.pallas_demotions()[bucket]
-            assert fails == 1 and wait == 4
-            # 4 demoted cycles ride the scan path...
-            assert all(solver._demoted(bucket) for _ in range(4))
-            # ...then the retry window opens
-            assert not solver._demoted(bucket)
-            # a second failure backs off exponentially
-            solver._record_failure(bucket)
-            _, wait2 = solver.pallas_demotions()[bucket]
-            assert wait2 == 16
-            # success clears the state entirely
-            solver._record_success(bucket)
-            assert bucket not in solver.pallas_demotions()
-            assert not solver._demoted(bucket)
-        finally:
-            solver._record_success(bucket)
+        def never(*a, **k):
+            raise AssertionError("an over-limit bucket reached a kernel")
 
-    def test_backoff_is_capped(self):
-        from koordinator_tpu import solver
+        self._on_tpu(monkeypatch, dense=never, wide=never)
+        nodes, pods, gangs, quotas = generators.loadaware_joint(
+            seed=3, pods=24, nodes=12
+        )
+        snap = encode_snapshot(nodes, pods, gangs, quotas,
+                               node_bucket=256)
+        monkeypatch.setattr(solver, "DENSE_MAX_NODES", 128)
+        monkeypatch.setattr(solver, "WIDE_MAX_NODES", 128)
+        got = solver.run_cycle(snap)
+        assert got.path == "scan"
+        np.testing.assert_array_equal(
+            np.asarray(got.assignment),
+            np.asarray(greedy_assign(snap).assignment),
+        )
 
-        bucket = ("wide", "tpu", 16, 64, True)
-        try:
-            for _ in range(10):
-                solver._record_failure(bucket)
-            _, wait = solver.pallas_demotions()[bucket]
-            assert wait == solver._RETRY_CAP
-        finally:
-            solver._record_success(bucket)
+    def test_variant_limits(self):
+        from koordinator_tpu.solver import (
+            DENSE_MAX_NODES,
+            DENSE_MAX_NODES_EXTRAS,
+            WIDE_MAX_NODES,
+            pallas_variant,
+        )
+
+        assert pallas_variant(2000, False, 1) == "dense"
+        assert pallas_variant(2000, False, 32) == "wide"
+        assert pallas_variant(WIDE_MAX_NODES + 1, False, 32) == "dense"
+        assert pallas_variant(DENSE_MAX_NODES, False, 1) == "dense"
+        assert pallas_variant(DENSE_MAX_NODES + 1, False, 1) is None
+        assert pallas_variant(DENSE_MAX_NODES_EXTRAS, True, 1) == "dense"
+        assert pallas_variant(DENSE_MAX_NODES_EXTRAS + 1, True, 1) is None
