@@ -401,11 +401,11 @@ def _validate_artifact(line: Optional[str]) -> list:
             "'trace_overhead_p99_pct' must be null or a finite "
             "number >= -100"
         )
-    # device-time truth fields (ISSUE 19): the launch ledger's
-    # compile-vs-device split — compile wall paid at the jit
-    # boundaries, sampled per-launch device time of the Score path,
-    # the dominant kernel's XLA-estimated flops, and the backend the
-    # ledger attributed them to.  Malformed ones must not be archived.
+    # compile truth fields (ISSUE 19): compile wall paid at the jit
+    # boundaries, the dominant kernel's XLA-estimated flops, and the
+    # backend the ledger attributed them to; devprof_device_score_us
+    # is null since the ledger stopped sampling device time.
+    # Malformed ones must not be archived.
     _finite_nonneg("devprof_compile_ms_total")
     _finite_nonneg("devprof_device_score_us")
     _finite_nonneg("devprof_flops_per_launch")
@@ -778,11 +778,12 @@ def child(platform: str) -> None:
         except Exception as exc:  # noqa: BLE001
             phase("cpu_native_mt_failed", error=str(exc)[:200])
 
-    # device-time truth (ISSUE 19): a short ledger-on leg at probe
-    # scale.  The headline timings above ran with the ledger OFF (its
-    # default) so they stay comparable across rounds; this leg pays its
-    # own AOT captures on a small snapshot and publishes the
-    # compile-vs-device split the daemon's /metrics families carry.
+    # compile truth (ISSUE 19): a short ledger-on leg at probe scale.
+    # The headline timings above ran with the ledger OFF (its default)
+    # so they stay comparable across rounds; this leg pays its own AOT
+    # captures on a small snapshot and publishes the compile costs the
+    # daemon's /metrics families carry.  The ledger samples no device
+    # time (a profiler trace does), so device_score_us stays null.
     # Best-effort: a devprof failure publishes nulls, never kills the
     # artifact.
     devprof_backend = devprof_compiles = None
@@ -795,13 +796,13 @@ def child(platform: str) -> None:
 
         t0 = time.perf_counter()
         devprof.reset()
-        devprof.configure(sample=1)  # probe leg: sample every launch
+        devprof.configure(attribute=True)
         dsnap = encode_snapshot(
             nodes[:16], pods[:64], [], qdicts, node_bucket=16, pod_bucket=64
         )
         np.asarray(_dp_score(dsnap)[0])  # cold: AOT compile capture
         np.asarray(_dp_assign(dsnap).assignment)
-        for _ in range(4):  # warm: sampled device time
+        for _ in range(4):  # warm: no further capture
             np.asarray(_dp_score(dsnap)[0])
         summ = devprof.summary()
         devprof_backend = summ["backend"]
@@ -810,11 +811,6 @@ def child(platform: str) -> None:
         ]
         devprof_compiles = len(ents)
         devprof_compile_ms_total = sum(e["compile_ms"] for e in ents)
-        st = summ["boundaries"].get("solver.greedy.score_cycle")
-        if st and st["sampled"]:
-            devprof_device_score_us = (
-                st["device_us_total"] / st["sampled"]
-            )
         flops = [e["flops"] for e in summ["entries"] if e.get("flops")]
         if flops:
             devprof_flops_per_launch = max(flops)
@@ -837,7 +833,7 @@ def child(platform: str) -> None:
         # anything else in this child touches the serving path
         try:
             from koordinator_tpu.obs import devprof
-            devprof.configure(sample=0)
+            devprof.configure(attribute=False)
             devprof.reset()
         except Exception:  # koordlint: disable=broad-except(reason: best-effort ledger teardown in the finally arm — the probe already published or phase()d its failure, and the artifact must still print)
             pass
@@ -2898,13 +2894,13 @@ def child_config(platform: str, config: str) -> None:
                     if coal_server is not None:
                         coal_server.stop()
 
-                # device-time truth probe (ISSUE 19): the same
-                # pipelined storm with the launch ledger sampling
-                # 1-in-16 — replies must stay byte-identical with the
-                # ledger-off storm, the client-observed p99 must hold
-                # within the overhead bound, and the ledger's own
-                # summary publishes the compile-vs-device split the
-                # artifact carries.
+                # compile truth probe (ISSUE 19): the same pipelined
+                # storm with the launch ledger attributing compiles —
+                # replies must stay byte-identical with the ledger-off
+                # storm, the client-observed p99 must hold within the
+                # overhead bound, and the ledger's own summary
+                # publishes the compile costs the artifact carries
+                # (device time: null, the ledger samples none).
                 devprof_backend = devprof_compiles = None
                 devprof_compile_ms_total = None
                 devprof_device_score_us = None
@@ -2915,7 +2911,7 @@ def child_config(platform: str, config: str) -> None:
                 try:
                     devprof.reset()
                     devprof.configure(
-                        sample=16,
+                        attribute=True,
                         metrics=server.servicer.telemetry.metrics,
                         state_dir=tmp,
                     )
@@ -2932,7 +2928,7 @@ def child_config(platform: str, config: str) -> None:
                                        int(round(0.99 * (len(lat) - 1))))]
 
                     # interleaved min-of-k (the ISSUE-14 trace-overhead
-                    # idiom): alternate sampling on/off so scheduler
+                    # idiom): alternate attribution on/off so scheduler
                     # noise hits both modes, keep each mode's
                     # least-perturbed p99.  per_client=1: the overhead
                     # delta needs matched storms, not a long soak.
@@ -2948,20 +2944,20 @@ def child_config(platform: str, config: str) -> None:
                         )
                         assert not errs, f"devprof-on storm errors: {errs}"
                         p99_on_runs.append(_p99(lat_on))
-                        devprof.configure(sample=0)
+                        devprof.configure(attribute=False)
                         try:
                             _, lat_off, dig_off, errs = _score_storm(
                                 sock_path, sync.snapshot_id, conc, 1
                             )
                         finally:
-                            devprof.configure(sample=16)
+                            devprof.configure(attribute=True)
                         assert not errs, f"devprof-off storm errors: {errs}"
                         p99_off_runs.append(_p99(lat_off))
                         assert dig_off == dig_coal, (
                             "ledger-off storm replies diverged"
                         )
-                    # reply-byte parity: the ledger may time and count,
-                    # never touch a reply
+                    # reply-byte parity: the ledger may count, never
+                    # touch a reply
                     assert dig_warm == dig_on == dig_coal, (
                         "devprof-on replies diverged from the "
                         "ledger-off storm"
@@ -2979,16 +2975,6 @@ def child_config(platform: str, config: str) -> None:
                     devprof_compile_ms_total = sum(
                         e["compile_ms"] for e in ents
                     )
-                    sampled = sum(
-                        st["sampled"]
-                        for st in summ["boundaries"].values()
-                    )
-                    dev_us = sum(
-                        st["device_us_total"]
-                        for st in summ["boundaries"].values()
-                    )
-                    if sampled:
-                        devprof_device_score_us = dev_us / sampled
                     flops = [e["flops"] for e in summ["entries"]
                              if e.get("flops")]
                     if flops:
@@ -3028,7 +3014,7 @@ def child_config(platform: str, config: str) -> None:
                 finally:
                     # process-global ledger: back to bit-inert before
                     # anything else touches the serving path
-                    devprof.configure(sample=0)
+                    devprof.configure(attribute=False)
                     devprof.reset()
             finally:
                 conn.close()
@@ -4870,10 +4856,8 @@ def failover_leader(platform: str, sock: str, repl: str,
 
     # compile attribution for the warm-restart split (ISSUE 20): the
     # ledger's compile capture fires on every boundary's FIRST launch
-    # regardless of the sampling rate, so a huge rate buys the
-    # restart_compile_ms attribution without per-launch sync overhead
-    # polluting warm_restart_ms itself
-    devprof.configure(sample=1_000_000)
+    # and adds no per-launch sync, so warm_restart_ms stays clean
+    devprof.configure(attribute=True)
     sv = ScorerServicer(score_memo=False, score_incr=False)
     os.makedirs(state_dir, exist_ok=True)
     journal = FrameJournal(os.path.join(state_dir, "journal.krj"))

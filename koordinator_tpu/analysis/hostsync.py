@@ -15,7 +15,8 @@ obvious jnp/jax expression — ``int(shape[0])`` and enum coercions are
 host-side constants and stay legal.
 
 The obs span API (koordinator_tpu/obs/spans.py) is covered too: a
-``begin_span``/``end_span``/``.span()``/``.note()`` inside jitted code
+``begin_span``/``end_span``/``.span()``/``.note()``, or a
+``profiled()``/``tag_cycle()`` profiler annotation, inside jitted code
 would record trace-time wall clock ONCE and then never run again (the
 bare-print trap), and a note of a live tracer value would force a
 concretization.  Telemetry instruments around device programs, never
@@ -41,6 +42,8 @@ _NP_SYNC_FUNCS = ("asarray", "array", "copy")
 _OBS_METHODS = ("begin_span", "end_span")
 _OBS_RECEIVERS = ("obs", "spans", "telemetry", "recorder", "span_recorder")
 _OBS_RECEIVER_METHODS = ("span", "note", "commit", "commit_cycle")
+# obs profiler annotations, called bare or off the module
+_OBS_FUNCS = ("profiled", "tag_cycle")
 
 
 def _root_module(node: ast.AST) -> str:
@@ -131,22 +134,26 @@ def check(source: SourceFile) -> List[Violation]:
                 )
             # obs span/telemetry API: trace-time-only wall clock (and a
             # tracer note forces a host sync); instrument OUTSIDE jit
-            elif isinstance(fn, ast.Attribute) and (
-                fn.attr in _OBS_METHODS
-                or (
-                    fn.attr in _OBS_RECEIVER_METHODS
-                    and any(
-                        seg in _OBS_RECEIVERS for seg in _attr_chain(fn)[:-1]
+            elif (isinstance(fn, ast.Name) and fn.id in _OBS_FUNCS) or (
+                isinstance(fn, ast.Attribute) and (
+                    fn.attr in _OBS_METHODS + _OBS_FUNCS
+                    or (
+                        fn.attr in _OBS_RECEIVER_METHODS
+                        and any(
+                            seg in _OBS_RECEIVERS
+                            for seg in _attr_chain(fn)[:-1]
+                        )
                     )
                 )
             ):
+                called = fn.id if isinstance(fn, ast.Name) else fn.attr
                 out.append(
                     Violation(
                         rule=RULE,
                         path=source.path,
                         line=node.lineno,
                         message=(
-                            f"obs span API .{fn.attr}() inside jitted "
+                            f"obs span API .{called}() inside jitted "
                             f"{spec.name}() records trace-time wall "
                             "clock once (and concretizes tracer "
                             "arguments); instrument around the jitted "

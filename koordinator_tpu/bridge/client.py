@@ -23,7 +23,7 @@ from koordinator_tpu.bridge.codegen import method_path, pb2
 from koordinator_tpu.bridge.state import numpy_to_tensor
 from koordinator_tpu.obs.export import SpanExporter, resolve_export_dir
 from koordinator_tpu.obs.lockwitness import witness_lock, witness_rlock
-from koordinator_tpu.obs.spans import ClientTraceOp
+from koordinator_tpu.obs.spans import ClientTraceOp, profiled
 from koordinator_tpu.replication.retry import BackoffPolicy
 
 # channel-level failures: the RPC may or may not have reached the
@@ -529,10 +529,13 @@ class ScorerClient:
         """One logical Sync (delta-encoded against the acked baseline;
         see :meth:`_sync_op` for the keyword surface).  Traced as ONE
         op: the delta attempt, any failover probes and the full-resend
-        fallback are sibling attempt spans of the same trace."""
-        return self._with_op(
-            "sync", lambda op: self._sync_op(op, **kwargs)
-        )
+        fallback are sibling attempt spans of the same trace.  On the
+        profiler's clock the call is ``koord.client.sync`` and each
+        request build ``koord.client.sync_encode`` (obs/spans.py)."""
+        with profiled("client.sync"):
+            return self._with_op(
+                "sync", lambda op: self._sync_op(op, **kwargs)
+            )
 
     def _sync_op(
         self,
@@ -603,6 +606,10 @@ class ScorerClient:
         staged_scalars: Dict[str, tuple] = {}
 
         def build(baseline: Dict[str, np.ndarray], full: bool):
+            with profiled("client.sync_encode"):
+                return _build(baseline, full)
+
+        def _build(baseline: Dict[str, np.ndarray], full: bool):
             staged.clear()
             staged_scalars.clear()
 

@@ -80,9 +80,9 @@ from koordinator_tpu.bridge.state import ResidentState
 from koordinator_tpu.config import CycleConfig, DEFAULT_CYCLE_CONFIG
 from koordinator_tpu.model.snapshot import pad_bucket
 from koordinator_tpu.obs import CycleTelemetry
-from koordinator_tpu.obs import devprof
 from koordinator_tpu.obs import lockwitness
 from koordinator_tpu.obs.lockwitness import witness_lock
+from koordinator_tpu.obs.spans import profiled, tag_cycle
 from koordinator_tpu.replication.admission import (
     AdmissionGate,
     BreakerOpen,
@@ -101,29 +101,6 @@ from koordinator_tpu.solver import (
     sparse_top_k,
 )
 from koordinator_tpu.solver.candidates import check_candidate_overflow
-
-
-def _devprof_span_attrs(span, notes) -> None:
-    """Attach the launch ledger's notes (obs/devprof.py, drained on the
-    thread that ran the jit boundaries) to a launch/RPC span: sampled
-    device time, whether any boundary compiled (and its wall cost), and
-    the launch's XLA-estimated flops — the host/device split the
-    assemble waterfall renders.  No notes (devprof off, or an unsampled
-    launch) = no attrs, so traces stay byte-identical to today."""
-    if not notes:
-        return
-    dev = [n["device_us"] for n in notes if n.get("device_us") is not None]
-    if dev:
-        span.set_attr("device_us", round(sum(dev), 1))
-    if any(n.get("compiled") for n in notes):
-        span.set_attr("compiled", True)
-        cms = [n["compile_ms"] for n in notes
-               if n.get("compile_ms") is not None]
-        if cms:
-            span.set_attr("compile_ms", round(sum(cms), 2))
-    fl = [n["flops"] for n in notes if n.get("flops") is not None]
-    if fl:
-        span.set_attr("flops", float(sum(fl)))
 
 
 class _AssignMemo:
@@ -173,7 +150,6 @@ class ScorerServicer:
         brownout_max_lag: Optional[int] = None,
         trace_export: Optional[str] = None,
         shed_fractions=None,
-        devprof_sample: Optional[int] = None,
     ):
         """``mesh``: a ``jax.sharding.Mesh`` turns the ASSIGN RPC into
         the round-based multi-chip cycle (parallel/shard_assign.py
@@ -328,6 +304,8 @@ class ScorerServicer:
         # Assign result memo: (snapshot id, CycleConfig) -> _AssignMemo,
         # cleared atomically with every generation bump
         self._assign_memo = {}
+        # per-thread: an Assign's open koord.assign_queue annotation
+        self._rpc_local = threading.local()
         # Score top-k prefix memo (same invalidation; None = disabled)
         self._score_memo = ScoreMemo() if score_memo else None
         # incremental score engine (ISSUE 9): resident [P, N] tensors
@@ -429,17 +407,6 @@ class ScorerServicer:
         self.dispatch.launch_outcome_hook = self._launch_outcome
         self.telemetry.metrics.set_breaker_state(self.breaker.state())
         self.telemetry.metrics.set_candidate_width(self.cfg.candidate_width)
-        # device-time truth (ISSUE 19): configure the process-global
-        # launch ledger.  None = leave the ledger as-is (library
-        # embedders/tests own it); the daemon forwards its
-        # --devprof-sample.  The metrics sink is a weakref inside
-        # devprof, so this servicer's lifetime is never extended.
-        if devprof_sample is not None:
-            devprof.configure(
-                sample=devprof_sample,
-                metrics=self.telemetry.metrics,
-                state_dir=state_dir,
-            )
 
     # -- degradation ladder seams (ISSUE 13) --
     def _breaker_transition(self, to: str) -> None:
@@ -588,10 +555,14 @@ class ScorerServicer:
         # validation runs while the device may still be scattering the
         # PREVIOUS sync's deltas (async dispatch) and while coalesced
         # Scores launch — the old single lock serialized all of that.
-        with self._sync_lock:
+        # The profiler annotations (obs/spans.py): koord.sync over the
+        # body, its cycle stamped once the commit has chosen it;
+        # koord.sync_decode before any cycle exists to name
+        with profiled("sync") as ann, self._sync_lock:
             t0 = time.perf_counter()
             try:
-                staged = self.state.stage_sync(req)
+                with profiled("sync_decode"):
+                    staged = self.state.stage_sync(req)
             except Exception as exc:
                 # ValueError = a frame validation REJECTED (bad delta
                 # shape/index, missing first-sync tensors): the
@@ -615,15 +586,20 @@ class ScorerServicer:
             # under launch -> state: the donation must not invalidate
             # buffers an in-flight batch captured but has not read
             # back, and the mirrors/generation/telemetry move together.
+            # the cycle this Sync's stages land on, for the annotations
+            cycle = [None]
+
             def commit() -> "pb2.SyncReply":
                 with self._state_lock:
                     self.telemetry.flush_backlog()
                     spans = self.telemetry.spans
                     spans.add_measured("sync_decode", decode_s)
+                    cycle[0] = spans.current()
                     try:
-                        info = self.state.commit_sync(
-                            staged, spans=spans, plan=plan_cell[0]
-                        )
+                        with profiled("sync_apply", cycle[0]):
+                            info = self.state.commit_sync(
+                                staged, spans=spans, plan=plan_cell[0]
+                            )
                     except Exception as exc:
                         self.telemetry.abort_cycle("sync", exc)
                         raise
@@ -670,6 +646,7 @@ class ScorerServicer:
                 return self.state.commit_donates(staged, plan=plan_cell[0])
 
             reply = self.dispatch.run_exclusive(commit, drain=_decide_drain)
+            tag_cycle(ann, cycle[0])
             # replication (ISSUE 8): stream the committed frame to the
             # follower tier — still under _sync_lock, so publishes are
             # strictly generation-ordered; the publisher's per-follower
@@ -682,28 +659,29 @@ class ScorerServicer:
             # has the decoded message (gRPC) passes None and the
             # publisher re-serializes, which is byte-identical (same
             # runtime both ends).
-            jhook = self.journal_hook
-            if jhook is not None:
-                try:
-                    jhook(req, reply.snapshot_id, wire_bytes)
-                except Exception:  # the Sync IS committed in memory — a full disk must degrade durability, not fail the acked write; the journal logs and counts the miss
-                    import logging
+            with profiled("sync_publish", cycle[0]):
+                jhook = self.journal_hook
+                if jhook is not None:
+                    try:
+                        jhook(req, reply.snapshot_id, wire_bytes)
+                    except Exception:  # the Sync IS committed in memory — a full disk must degrade durability, not fail the acked write; the journal logs and counts the miss
+                        import logging
 
-                    logging.getLogger(__name__).exception(
-                        "journal append failed for %s",
-                        reply.snapshot_id,
-                    )
-            hook = self.replication_hook
-            if hook is not None:
-                try:
-                    hook(req, reply.snapshot_id, wire_bytes)
-                except Exception:  # the Sync IS committed — a publisher fault must not fail the client's acked write; followers detect the gap and resync
-                    import logging
+                        logging.getLogger(__name__).exception(
+                            "journal append failed for %s",
+                            reply.snapshot_id,
+                        )
+                hook = self.replication_hook
+                if hook is not None:
+                    try:
+                        hook(req, reply.snapshot_id, wire_bytes)
+                    except Exception:  # the Sync IS committed — a publisher fault must not fail the client's acked write; followers detect the gap and resync
+                        import logging
 
-                    logging.getLogger(__name__).exception(
-                        "replication publish failed for %s",
-                        reply.snapshot_id,
-                    )
+                        logging.getLogger(__name__).exception(
+                            "replication publish failed for %s",
+                            reply.snapshot_id,
+                        )
             return reply
 
     # -- replication seam (ISSUE 8; koordinator_tpu/replication/) --
@@ -1151,82 +1129,79 @@ class ScorerServicer:
             # keeps the serialized daemon's semantics (device dispatch +
             # readback + assembly, no queue wait — queue wait has its
             # own koord_scorer_coalesce_queue_delay_ms family)
-            t_exec = time.perf_counter()
-            devprof.drain_notes()  # discard notes a prior stage left on this thread
-            N = snap.nodes.capacity
-            P = snap.pods.capacity
-            ks = [
-                min(int(e.req.top_k) or N, N) for e in accepted
-            ]
-            # ONE launch serves every caller: top_k runs at the batch
-            # max, padded to the sticky power-of-two bucket so varying
-            # batch composition cannot mint new compiled shapes (zero
-            # jit cache misses on the warm path); each caller's k is a
-            # prefix of the padded result (lax.top_k sorts descending
-            # with index tie-breaks, so prefixes are exact)
-            k_launch = min(pad_bucket(max(ks)), N)
-            # engine ordering (ISSUE 9): memo (handled above) ->
-            # incremental column/row rescore of the resident tensors ->
-            # full score_cycle.  All three hand bit-identical tensors
-            # to the SAME masked top_k below.
-            scores = feasible = None
-            incr_result = None  # telemetry: incr | full | fallback
-            incr_cols = 0
-            if self._score_incr:
-                if incr is not None:
-                    ratio = (
-                        len(incr.dirty_nodes) / N + len(incr.dirty_pods) / P
-                    )
-                    if ratio <= self._score_incr_max_ratio:
-                        incr_cols = len(incr.dirty_nodes)
-                        # the launch consumes the donated residency:
-                        # unpublish it first, so a failed launch (which
-                        # fails this batch) never leaves a consumed
-                        # buffer for the next Score; success re-stores
-                        # the advanced tensors below
-                        self.state.drop_score_residency()
-                        scores, feasible = self._score_incremental(
-                            snap, incr
+            with profiled("score_dispatch"):
+                t_exec = time.perf_counter()
+                N = snap.nodes.capacity
+                P = snap.pods.capacity
+                ks = [
+                    min(int(e.req.top_k) or N, N) for e in accepted
+                ]
+                # ONE launch serves every caller: top_k runs at the batch
+                # max, padded to the sticky power-of-two bucket so varying
+                # batch composition cannot mint new compiled shapes (zero
+                # jit cache misses on the warm path); each caller's k is a
+                # prefix of the padded result (lax.top_k sorts descending
+                # with index tie-breaks, so prefixes are exact)
+                k_launch = min(pad_bucket(max(ks)), N)
+                # engine ordering (ISSUE 9): memo (handled above) ->
+                # incremental column/row rescore of the resident tensors ->
+                # full score_cycle.  All three hand bit-identical tensors
+                # to the SAME masked top_k below.
+                scores = feasible = None
+                incr_result = None  # telemetry: incr | full | fallback
+                incr_cols = 0
+                if self._score_incr:
+                    if incr is not None:
+                        ratio = (
+                            len(incr.dirty_nodes) / N + len(incr.dirty_pods) / P
                         )
-                        incr_result = "incr"
+                        if ratio <= self._score_incr_max_ratio:
+                            incr_cols = len(incr.dirty_nodes)
+                            # the launch consumes the donated residency:
+                            # unpublish it first, so a failed launch (which
+                            # fails this batch) never leaves a consumed
+                            # buffer for the next Score; success re-stores
+                            # the advanced tensors below
+                            self.state.drop_score_residency()
+                            scores, feasible = self._score_incremental(
+                                snap, incr
+                            )
+                            incr_result = "incr"
+                        else:
+                            # mostly-dirty: a full rescore is cheaper than
+                            # scattering most of the tensor incrementally
+                            incr_result = "fallback"
                     else:
-                        # mostly-dirty: a full rescore is cheaper than
-                        # scattering most of the tensor incrementally
-                        incr_result = "fallback"
-                else:
-                    incr_result = "full"  # nothing resident to advance
-            if scores is None:
-                scores, feasible = score_cycle(snap, self.cfg)
-            if self._score_incr:
-                # the tensors this launch certifies become (or refresh)
-                # the residency; accumulated dirt clears with the store
-                self.state.store_score_result(self.cfg, scores, feasible)
-            # masked top-k via the packed-f64 fast path (solver/topk.py):
-            # bit-identical to lax.top_k on the masked i64 tensor, ~30x
-            # cheaper on CPU — the shared tail both engines pay, so the
-            # incremental saving is not buried under an integer sort
-            top_scores, top_idx = masked_top_k(
-                scores, feasible, k=k_launch,
-                hi=score_upper_bound(self.cfg),
-            )
-            # brownout full cache (ROADMAP 6(a)): a defensive device
-            # COPY under the cell gate — the stored residency tensor is
-            # DONATED by a subsequent pipelined incremental launch (the
-            # very reason feasible is never donated), so the buffer
-            # this readback will device_get must be its own
-            cache_full = P * N <= self._brownout_full_cells
-            scores_cache = None
-            if cache_full:
-                import jax.numpy as jnp
+                        incr_result = "full"  # nothing resident to advance
+                if scores is None:
+                    scores, feasible = score_cycle(snap, self.cfg)
+                if self._score_incr:
+                    # the tensors this launch certifies become (or refresh)
+                    # the residency; accumulated dirt clears with the store
+                    self.state.store_score_result(self.cfg, scores, feasible)
+                # masked top-k via the packed-f64 fast path (solver/topk.py):
+                # bit-identical to lax.top_k on the masked i64 tensor, ~30x
+                # cheaper on CPU — the shared tail both engines pay, so the
+                # incremental saving is not buried under an integer sort
+                top_scores, top_idx = masked_top_k(
+                    scores, feasible, k=k_launch,
+                    hi=score_upper_bound(self.cfg),
+                )
+                # brownout full cache (ROADMAP 6(a)): a defensive device
+                # COPY under the cell gate — the stored residency tensor is
+                # DONATED by a subsequent pipelined incremental launch (the
+                # very reason feasible is never donated), so the buffer
+                # this readback will device_get must be its own
+                cache_full = P * N <= self._brownout_full_cells
+                scores_cache = None
+                if cache_full:
+                    import jax.numpy as jnp
 
-                scores_cache = jnp.copy(scores)
-            # launch phase ends with the program ENQUEUED (async
-            # dispatch); everything below blocks, so it lives in the
-            # readback closure the dispatcher runs off the launch lock
-            dispatch_s = time.perf_counter() - t_exec
-            # this thread ran the registered jit boundaries above: the
-            # ledger's launch notes attach to the span in the readback
-            devprof_notes = devprof.drain_notes()
+                    scores_cache = jnp.copy(scores)
+                # launch phase ends with the program ENQUEUED (async
+                # dispatch); everything below blocks, so it lives in the
+                # readback closure the dispatcher runs off the launch lock
+                dispatch_s = time.perf_counter() - t_exec
         except Exception as exc:
             if launch_span is not None:
                 launch_span.abort(exc)
@@ -1247,10 +1222,11 @@ class ScorerServicer:
                 # refused; past the cell gate the extra transfer is
                 # skipped — the hot path must not pay O(P x N)
                 # readback at headline scale.
-                ts, ti, feasible_np, valid_np, scores_np = jax.device_get(
-                    (top_scores, top_idx, feasible, snap.pods.valid,
-                     scores_cache)
-                )
+                with profiled("score_readback"):
+                    ts, ti, feasible_np, valid_np, scores_np = (
+                        jax.device_get((top_scores, top_idx, feasible,
+                                        snap.pods.valid, scores_cache))
+                    )
                 readback_s = time.perf_counter() - t0
                 # device work is done: the launch span closes HERE (off
                 # the launch lock), covering async dispatch + the
@@ -1258,7 +1234,6 @@ class ScorerServicer:
                 # the individual RPC spans' errors, not the launch's
                 if launch_span is not None:
                     launch_span.set_attr("k_bucket", k_launch)
-                    _devprof_span_attrs(launch_span, devprof_notes)
                     launch_span.end()
                 ti = ti.astype(np.int32)
                 valid = valid_np[:P].astype(bool)
@@ -1362,57 +1337,56 @@ class ScorerServicer:
         readback raises :class:`CandidateOverflow` — the engine
         refuses rather than silently degrade to a truncated list."""
         try:
-            t_exec = time.perf_counter()
-            devprof.drain_notes()  # discard notes a prior stage left on this thread
-            N = snap.nodes.capacity
-            P = snap.pods.capacity
-            C = int(self.cfg.candidate_width)
-            # a pod holds at most min(C, N) real candidates, so every
-            # caller's k (and the memoized "N") clamps there — the
-            # same derivation the dense path runs with N.  Both are
-            # powers of two, so the k bucket stays within C and the
-            # top-k shape never crosses a jit boundary traced.
-            k_cap = min(C, N)
-            ks = [min(int(e.req.top_k) or k_cap, k_cap) for e in accepted]
-            k_launch = min(pad_bucket(max(ks)), k_cap)
-            refresh_reason = None
-            merges = 0
-            if cres is None:
-                # cold: the pipelined build (ISSUE 20) engages past the
-                # block threshold — the node mesh, when configured,
-                # shards its counts pass over the block axis
-                cand, count = build_candidates(
-                    snap, self.cfg, node_mesh=self.mesh
-                )
-                refresh_reason = "cold"
-            elif cres.dirty_nodes or cres.dirty_pods:
-                if cres.merges >= self.cfg.candidate_max_stale:
-                    # merge-chain bound hit: one full rebuild resets it
+            with profiled("score_dispatch"):
+                t_exec = time.perf_counter()
+                N = snap.nodes.capacity
+                P = snap.pods.capacity
+                C = int(self.cfg.candidate_width)
+                # a pod holds at most min(C, N) real candidates, so every
+                # caller's k (and the memoized "N") clamps there — the
+                # same derivation the dense path runs with N.  Both are
+                # powers of two, so the k bucket stays within C and the
+                # top-k shape never crosses a jit boundary traced.
+                k_cap = min(C, N)
+                ks = [min(int(e.req.top_k) or k_cap, k_cap) for e in accepted]
+                k_launch = min(pad_bucket(max(ks)), k_cap)
+                refresh_reason = None
+                merges = 0
+                if cres is None:
+                    # cold: the pipelined build (ISSUE 20) engages past the
+                    # block threshold — the node mesh, when configured,
+                    # shards its counts pass over the block axis
                     cand, count = build_candidates(
                         snap, self.cfg, node_mesh=self.mesh
                     )
-                    refresh_reason = "stale"
+                    refresh_reason = "cold"
+                elif cres.dirty_nodes or cres.dirty_pods:
+                    if cres.merges >= self.cfg.candidate_max_stale:
+                        # merge-chain bound hit: one full rebuild resets it
+                        cand, count = build_candidates(
+                            snap, self.cfg, node_mesh=self.mesh
+                        )
+                        refresh_reason = "stale"
+                    else:
+                        cand, count = refresh_candidates(
+                            snap, cres.idx, cres.count,
+                            sorted(cres.dirty_nodes), sorted(cres.dirty_pods),
+                            self.cfg,
+                        )
+                        refresh_reason = "dirty"
+                        merges = cres.merges + 1
                 else:
-                    cand, count = refresh_candidates(
-                        snap, cres.idx, cres.count,
-                        sorted(cres.dirty_nodes), sorted(cres.dirty_pods),
-                        self.cfg,
-                    )
-                    refresh_reason = "dirty"
-                    merges = cres.merges + 1
-            else:
-                cand, count = cres.idx, cres.count
-            if refresh_reason is not None:
-                # the lists this launch certifies become the residency;
-                # accumulated dirt clears with the store
-                self.state.store_candidates(self.cfg, cand, count, merges)
-            scores, feasible = score_candidates(snap, cand, self.cfg)
-            top_scores, top_idx, top_ok = sparse_top_k(
-                scores, feasible, cand, k=k_launch,
-                hi=score_upper_bound(self.cfg),
-            )
-            dispatch_s = time.perf_counter() - t_exec
-            devprof_notes = devprof.drain_notes()
+                    cand, count = cres.idx, cres.count
+                if refresh_reason is not None:
+                    # the lists this launch certifies become the residency;
+                    # accumulated dirt clears with the store
+                    self.state.store_candidates(self.cfg, cand, count, merges)
+                scores, feasible = score_candidates(snap, cand, self.cfg)
+                top_scores, top_idx, top_ok = sparse_top_k(
+                    scores, feasible, cand, k=k_launch,
+                    hi=score_upper_bound(self.cfg),
+                )
+                dispatch_s = time.perf_counter() - t_exec
         except Exception as exc:
             if launch_span is not None:
                 launch_span.abort(exc)
@@ -1426,9 +1400,11 @@ class ScorerServicer:
                 # one stacked device->host transfer, like the dense
                 # readback; the exact per-pod feasible counts ride
                 # along for the overflow check
-                ts, ti, ok_np, count_np, valid_np = jax.device_get(
-                    (top_scores, top_idx, top_ok, count, snap.pods.valid)
-                )
+                with profiled("score_readback"):
+                    ts, ti, ok_np, count_np, valid_np = jax.device_get(
+                        (top_scores, top_idx, top_ok, count,
+                         snap.pods.valid)
+                    )
                 readback_s = time.perf_counter() - t0
                 try:
                     check_candidate_overflow(count_np, C)
@@ -1442,7 +1418,6 @@ class ScorerServicer:
                 if launch_span is not None:
                     launch_span.set_attr("k_bucket", k_launch)
                     launch_span.set_attr("candidate_width", C)
-                    _devprof_span_attrs(launch_span, devprof_notes)
                     launch_span.end()
                 ti = ti.astype(np.int32)
                 ok_np = ok_np.astype(bool)
@@ -1735,7 +1710,31 @@ class ScorerServicer:
                 )
 
     def assign(self, req: "pb2.AssignRequest", ctx=None) -> "pb2.AssignReply":
-        """Tracing shell over :meth:`_assign_impl` (see :meth:`sync`)."""
+        """Tracing shell over :meth:`_assign_impl` (see :meth:`sync`).
+        The profiler annotations (obs/spans.py): koord.assign over the
+        body, and koord.assign_queue from here to the device launch —
+        closed by ``launch()``, by a memo serve, or here at the latest."""
+        with profiled("assign") as ann:
+            queue = profiled("assign_queue")
+            self._rpc_local.assign_queue = queue.__enter__()
+            try:
+                reply = self._assign_traced(req, ctx)
+            finally:
+                self._end_assign_queue()
+            tag_cycle(ann, reply)
+        return reply
+
+    def _end_assign_queue(self, cycle=None) -> None:
+        """Close this thread's open koord.assign_queue annotation (once),
+        stamping ``cycle`` (a CycleScope) when given."""
+        ann = getattr(self._rpc_local, "assign_queue", None)
+        if ann is not None:
+            self._rpc_local.assign_queue = None
+            if cycle is not None:
+                tag_cycle(ann, cycle)
+            ann.__exit__(None, None, None)
+
+    def _assign_traced(self, req, ctx) -> "pb2.AssignReply":
         tspan = self._start_rpc_span(
             "assign", req, band=getattr(req, "band", "") or "",
         )
@@ -1913,7 +1912,8 @@ class ScorerServicer:
             entry.result
         )
         wait_ms = (time.perf_counter() - t_rpc) * 1000.0
-        with self._state_lock:
+        self._end_assign_queue(scope)
+        with profiled("assign_reply", scope), self._state_lock:
             reply = pb2.AssignReply(
                 # the cycle that certified this assignment cost
                 # ``cycle_ms`` on the device — that is what the field
@@ -1951,7 +1951,6 @@ class ScorerServicer:
         # queued behind other launches (the coalesce families carry
         # queueing)
         t0 = [0.0]
-        devprof_notes: list = []
 
         @launch_section
         def launch():
@@ -1967,21 +1966,22 @@ class ScorerServicer:
             # in-flight slot keeps a donating Sync OUT (run_exclusive
             # drains) until the readback below completes.
             t0[0] = time.perf_counter()
-            devprof.drain_notes()  # discard notes a prior stage left on this thread
+            self._end_assign_queue(scope)
             # gather-stage deadline check (ISSUE 13): the budget may
             # have drained while this RPC waited for pipeline headroom
             # and the launch lock — an expired Assign must fail HERE,
             # before the full device cycle it can no longer use
             if deadline_at is not None and t0[0] >= deadline_at:
                 raise DeadlineExpired("assign", "gather", budget_ms)
-            with self._state_lock:
-                self._check_generation(req, None)
-                snap = self.state.snapshot()
-                i32_ok = self.state.i32_fits()
-            result, rounds, eff_wave = self._assign_cycle(
-                snap, scope, i32_ok
-            )
-            devprof_notes.extend(devprof.drain_notes())
+            # the dispatch span: snapshot capture and the launch
+            with scope.span("dispatch"):
+                with self._state_lock:
+                    self._check_generation(req, None)
+                    snap = self.state.snapshot()
+                    i32_ok = self.state.i32_fits()
+                result, rounds, eff_wave = self._assign_cycle(
+                    snap, scope, i32_ok
+                )
 
             def _readback():
                 # blocking stacked transfer — OFF the launch lock, so a
@@ -2043,11 +2043,7 @@ class ScorerServicer:
                 self.telemetry.abort_scope(scope, "assign", exc)
             raise
         ms = (time.perf_counter() - t0[0]) * 1000.0
-        if tspan is not None:
-            # device-time truth on the assign RPC span: the ledger's
-            # notes for the cycle this RPC's thread launched
-            _devprof_span_attrs(tspan, devprof_notes)
-        with self._state_lock:
+        with profiled("assign_reply", scope), self._state_lock:
             reply = pb2.AssignReply(
                 cycle_ms=ms,
                 path=result.path or "",
@@ -2107,8 +2103,7 @@ class ScorerServicer:
                     wave=eff_wave, top_m=self.cfg.top_m, spans=spans,
                 )
             return result, rounds, eff_wave
-        with spans.span("dispatch"):
-            result = run_cycle(snap, self.cfg, i32_ok=i32_ok)
+        result = run_cycle(snap, self.cfg, i32_ok=i32_ok)
         # device-derived wave count: returned UN-materialized (a device
         # scalar) — blocking on it here would hold the launch lock for
         # the whole cycle; the pipelined readback fetches it in the
@@ -2116,10 +2111,17 @@ class ScorerServicer:
         return result, result.rounds, self.cfg.wave
 
 
-def _handler(fn, req_cls):
+def _handler(fn, req_cls, method: str, metrics):
+    """One unary method; its deserializer counts the request's bytes
+    on ``koord_scorer_rpc_request_bytes_total{method}``."""
+
+    def deserialize(data: bytes):
+        metrics.count_request_bytes(method, len(data))
+        return req_cls.FromString(data)
+
     return grpc.unary_unary_rpc_method_handler(
         lambda req, ctx: fn(req, ctx),
-        request_deserializer=req_cls.FromString,
+        request_deserializer=deserialize,
         response_serializer=lambda msg: msg.SerializeToString(),
     )
 
@@ -2148,10 +2150,13 @@ def make_server(
             ("grpc.max_send_message_length", -1),
         ),
     )
+    metrics = servicer.telemetry.metrics
     handlers = {
-        "Sync": _handler(servicer.sync, pb2.SyncRequest),
-        "Score": _handler(servicer.score, pb2.ScoreRequest),
-        "Assign": _handler(servicer.assign, pb2.AssignRequest),
+        "Sync": _handler(servicer.sync, pb2.SyncRequest, "sync", metrics),
+        "Score": _handler(servicer.score, pb2.ScoreRequest, "score", metrics),
+        "Assign": _handler(
+            servicer.assign, pb2.AssignRequest, "assign", metrics
+        ),
     }
     server.add_generic_rpc_handlers(
         (grpc.method_handlers_generic_handler(SERVICE, handlers),)

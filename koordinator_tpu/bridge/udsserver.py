@@ -259,6 +259,9 @@ class RawUdsServer:
                 metrics = self._metrics()
                 if metrics is not None:
                     metrics.count_uds_frame(_METHOD_NAMES[method])
+                    metrics.count_request_bytes(
+                        _METHOD_NAMES[method], length
+                    )
                 req_cls, fn = entry
                 try:
                     req = req_cls.FromString(payload)

@@ -203,15 +203,7 @@ def render_waterfall(trace: AssembledTrace, assembly: Optional[Assembly]
     """Plain-text waterfall of one trace: indentation is the tree, the
     bar is wall-clock placement relative to the trace's first span.
     Fan-in links render as ``~> <span-id>`` annotations (the linked
-    span may live in another trace — the coalesced-batch shape).
-
-    Device-time truth (ISSUE 19): spans carrying the launch ledger's
-    attrs (``device_us``/``compiled``/``flops``, attached by the
-    servicer from drained launch notes) split their bar — ``#`` is
-    host wall, ``=`` is the sampled device share — and annotate
-    ``dev=…us`` (plus ``compile=…ms`` on a first-compile launch), so
-    one rendering answers where a slow request's time actually went:
-    Python, XLA compile, or the device program itself."""
+    span may live in another trace — the coalesced-batch shape)."""
     if not trace.spans:
         return f"trace {trace.trace_id}: no spans"
     starts = [
@@ -222,23 +214,9 @@ def render_waterfall(trace: AssembledTrace, assembly: Optional[Assembly]
     ]
     t0, t1 = min(starts), max(ends)
     total_ns = max(1, t1 - t0)
-    dev_spans = [
-        s for s in trace.spans.values()
-        if (s.get("attributes") or {}).get("device_us") is not None
-    ]
-    dev_note = ""
-    if dev_spans:
-        dev_total_us = sum(
-            float(s["attributes"]["device_us"]) for s in dev_spans
-        )
-        dev_note = (
-            f", device {dev_total_us / 1e3:.3f} ms sampled across "
-            f"{len(dev_spans)} span(s)"
-        )
     lines = [
         f"trace {trace.trace_id}"
         f"  ({len(trace.spans)} spans, {total_ns / 1e6:.3f} ms"
-        f"{dev_note}"
         f"{', INCOMPLETE' if not trace.complete else ''})"
     ]
 
@@ -248,24 +226,7 @@ def render_waterfall(trace: AssembledTrace, assembly: Optional[Assembly]
         dur_ns = int(dur_ms * 1e6)
         left = int(width * start / total_ns)
         bar_w = max(1, int(width * dur_ns / total_ns))
-        attrs = span.get("attributes") or {}
-        dev_us = attrs.get("device_us")
-        body = "#" * bar_w
-        dev = ""
-        if dev_us is not None:
-            dev_ms = float(dev_us) / 1e3
-            if dur_ms > 0:
-                # right-align the device share inside the span's own
-                # bar: sampled device time is a total, not an interval,
-                # so the split is proportional, not positional
-                dev_w = min(
-                    bar_w, max(1, round(bar_w * dev_ms / dur_ms))
-                )
-                body = "#" * (bar_w - dev_w) + "=" * dev_w
-            dev = f" dev={float(dev_us):.1f}us"
-        if attrs.get("compiled"):
-            dev += f" compile={float(attrs.get('compile_ms') or 0.0):.2f}ms"
-        bar = " " * left + body[: max(0, width - left)]
+        bar = " " * left + ("#" * bar_w)[: max(0, width - left)]
         status = span.get("status") or {}
         err = " !" if status.get("code") == "ERROR" else ""
         links = "".join(
@@ -274,7 +235,7 @@ def render_waterfall(trace: AssembledTrace, assembly: Optional[Assembly]
         )
         label = f"{'  ' * depth}{span.get('name')} [{span.get('kind')}]"
         lines.append(
-            f"  {bar:<{width}} {dur_ms:9.3f} ms  {label}{err}{dev}{links}"
+            f"  {bar:<{width}} {dur_ms:9.3f} ms  {label}{err}{links}"
         )
         for child in trace.children(str(span["spanId"])):
             emit(child, depth + 1)

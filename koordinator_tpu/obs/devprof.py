@@ -1,55 +1,44 @@
-"""Device-time truth: the XLA launch ledger (ISSUE 19).
+"""Compile truth: the XLA launch ledger (ISSUE 19).
 
-Every latency the system publishes elsewhere is host wall-clock —
-``time.perf_counter`` around dispatch/readback in ``bridge/server.py``.
-This module makes the DEVICE side first-class: every jit boundary in
-the serving path registers here with :func:`boundary`, and the ledger
-captures, per (boundary, static shape signature):
+Every jit boundary in the serving path registers here with
+:func:`boundary`.  With attribution on (``configure(attribute=True)``)
+the ledger captures, per (boundary, static shape signature), at
+first-compile time via the AOT path (``fn.lower(*args).compile()``):
+compile wall-time, XLA ``cost_analysis()`` (flops, bytes accessed) and
+``memory_analysis()`` (temp/argument/output bytes), labeled by backend
+platform.  A retrace is therefore not just a counter bump
+(``koord_scorer_jit_cache_miss_total``) but an **attributed event**
+naming the boundary and the shape signature that minted it.
 
-* **compile truth** — at first-compile time via the AOT path
-  (``fn.lower(*args).compile()``): compile wall-time, XLA
-  ``cost_analysis()`` (flops, bytes accessed) and
-  ``memory_analysis()`` (temp/argument/output bytes), labeled by
-  backend platform.  A retrace is therefore no longer just a counter
-  bump (``koord_scorer_jit_cache_miss_total``) but an **attributed
-  event** naming the boundary and the shape signature that minted it.
-* **execution truth** — per-launch device time, sampled at a bounded
-  rate (``--devprof-sample N`` = time 1 launch in N;  0 = off) by
-  blocking on the launch's own outputs, so the sample is the real
-  dispatch→ready wall for exactly that program.
+Device time is not sampled here: a profiler trace (the benchmark's
+``--trace 1`` run, or the daemon's admin ``profile`` capture,
+:func:`capture_profile`) shows every device operation beside the
+``koord.*`` host stages that obs/spans.py annotates.
 
-The ledger feeds four consumers: new ``koord_scorer_devprof_*``
-metric families on /metrics, ``device_us``/``compiled``/``flops``
-attributes on the ``score_launch``/assign spans (the
-``obs/assemble.py`` waterfall renders the host/device split), the
-/healthz ``device`` block, and the report CLI::
+The ledger feeds the ``koord_scorer_devprof_*`` compile families on
+/metrics, the /healthz ``device`` block, the prewarm replay set
+(capture mode, ISSUE 20) and the report CLI::
 
     python -m koordinator_tpu.obs.devprof <state-dir>
-
-which prints the compile ledger and a top-N-by-device-time table with
-flops/bytes — the roofline-style per-backend constant factors ROADMAP
-item 4's flag-sweep campaign consumes.
 
 The hard contract, inherited from the warm path's compile economics
 (docs/ANALYSIS.md "instrumentation never enters jitted code"):
 
-* ``sample == 0`` (the default; oracles pin it) is **bit-inert**: the
+* attribution and capture off (the default) is **bit-inert**: the
   wrapper short-circuits to ``fn(*args, **kwargs)`` before touching
-  anything — no signature hashing, no notes, no AOT, zero retraces.
+  anything — no signature hashing, no AOT, zero retraces.
 * A boundary invoked while a jax trace is live (nested jits: the
   Pallas cycle calling ``score_cycle``, term extras fused inside
   ``score_all``) bypasses ALL instrumentation — only outermost,
-  host-invoked launches are measured.
+  host-invoked launches are attributed.
 * Capture is exception-gated everywhere: ``cost_analysis`` /
   ``memory_analysis`` availability drifts across jax versions and
   backends, and a telemetry failure must degrade, never break a
   launch.
 
-Costs, stated honestly: with sampling ON, a cold signature compiles
+Cost, stated honestly: with attribution on, a cold signature compiles
 twice (once for the AOT capture, once through jit's own cache) — the
-warm path never pays this; a sampled warm launch pays one
-``block_until_ready`` (it serializes that one launch against the
-pipeline, which is exactly why sampling is bounded-rate).
+warm path never pays this.
 """
 
 from __future__ import annotations
@@ -70,7 +59,6 @@ __all__ = [
     "reset",
     "enabled",
     "capture_enabled",
-    "drain_notes",
     "summary",
     "health_block",
     "dump",
@@ -79,14 +67,9 @@ __all__ = [
     "replay_records",
     "record_prewarm_compile",
     "capture_profile",
-    "DEFAULT_SAMPLE",
     "LEDGER_FILENAME",
     "PREWARM_FILENAME",
 ]
-
-# the recommended sampling rate when the operator turns devprof on
-# without choosing one: time 1 launch in 16
-DEFAULT_SAMPLE = 16
 
 LEDGER_FILENAME = "devprof.json"
 
@@ -96,10 +79,6 @@ LEDGER_FILENAME = "devprof.json"
 # because the specs carry real static objects (frozen CycleConfig);
 # same trust domain as the persistent compile cache's executables
 PREWARM_FILENAME = "prewarm.pkl"
-
-# flush the on-disk ledger every this many sampled launches (compile
-# events always flush immediately — they are rare and load-bearing)
-_FLUSH_EVERY = 32
 
 # flush prewarm launch-count hotness every this many captured launches
 # (a NEW signature always flushes immediately — losing one would leave
@@ -152,23 +131,16 @@ class _Entry:
 
 
 class _BoundaryStats:
-    """Cumulative per-boundary launch/device-time accounting."""
+    """Cumulative per-boundary launch/compile accounting."""
 
-    __slots__ = ("launches", "sampled", "device_us_total", "compiles")
+    __slots__ = ("launches", "compiles")
 
     def __init__(self):
         self.launches = 0
-        self.sampled = 0
-        self.device_us_total = 0.0
         self.compiles = 0
 
     def to_dict(self) -> dict:
-        return {
-            "launches": self.launches,
-            "sampled": self.sampled,
-            "device_us_total": self.device_us_total,
-            "compiles": self.compiles,
-        }
+        return {"launches": self.launches, "compiles": self.compiles}
 
 
 class LaunchLedger:
@@ -186,17 +158,14 @@ class LaunchLedger:
         self._boundaries: Dict[str, _BoundaryStats] = {}
         self._entries: Dict[tuple, _Entry] = {}  # (boundary, sig) -> row
         self._retraces: List[dict] = []  # attributed retrace events
-        self.sample = 0
+        self.attribute = False  # compile attribution at cold launches
         self.capture = False  # prewarm replay-spec capture (ISSUE 20)
         self._metrics_ref: Optional[Callable[[], Any]] = None
         self.state_dir: Optional[str] = None
-        self._counter = 0  # global launch counter driving 1-in-N
-        self._unflushed = 0
         # (boundary, sig) -> replay record: per-sig launch hotness plus
         # the pickled abstract argument spec (None = non-replayable)
         self._replays: Dict[tuple, dict] = {}
         self._replay_unflushed = 0
-        self._tls = threading.local()
 
     # -- registration ------------------------------------------------
     def register(self, name: str) -> None:
@@ -208,14 +177,14 @@ class LaunchLedger:
             return sorted(self._boundaries)
 
     # -- configuration -----------------------------------------------
-    def configure(self, sample: Optional[int] = None, metrics=None,
+    def configure(self, attribute: Optional[bool] = None, metrics=None,
                   state_dir: Optional[str] = None,
                   capture: Optional[bool] = None) -> None:
         import weakref
 
         with self._lock:
-            if sample is not None:
-                self.sample = max(0, int(sample))
+            if attribute is not None:
+                self.attribute = bool(attribute)
             if capture is not None:
                 self.capture = bool(capture)
             if metrics is not None:
@@ -233,13 +202,6 @@ class LaunchLedger:
         return ref()
 
     # -- the wrapper's accounting primitives -------------------------
-    def should_sample(self) -> bool:
-        """1-in-N gate over the global launch counter (all boundaries
-        share one counter so a quiet boundary still gets samples)."""
-        with self._lock:
-            self._counter += 1
-            return self.sample > 0 and self._counter % self.sample == 0
-
     def note_launch(self, name: str) -> None:
         with self._lock:
             st = self._boundaries.setdefault(name, _BoundaryStats())
@@ -287,22 +249,6 @@ class LaunchLedger:
             except Exception:  # koordlint: disable=broad-except(reason: telemetry sink drift must not break a launch; the ledger itself already recorded the compile)
                 pass
         self._flush(force=True)
-
-    def record_device_time(self, name: str, device_us: float) -> None:
-        with self._lock:
-            st = self._boundaries.setdefault(name, _BoundaryStats())
-            st.sampled += 1
-            st.device_us_total += device_us
-            self._unflushed += 1
-            flush = self._unflushed >= _FLUSH_EVERY
-        m = self._metrics()
-        if m is not None:
-            try:
-                m.devprof_device_us(name, device_us)
-            except Exception:  # koordlint: disable=broad-except(reason: telemetry sink drift must not break a launch; the ledger itself already recorded the sample)
-                pass
-        if flush:
-            self._flush(force=True)
 
     # -- prewarm replay capture (ISSUE 20) ---------------------------
     def note_replay(self, name: str, sig: str, args: tuple,
@@ -399,78 +345,45 @@ class LaunchLedger:
             return None
         return path
 
-    # -- per-thread launch notes (span attribution seam) -------------
-    def push_note(self, note: dict) -> None:
-        notes = getattr(self._tls, "notes", None)
-        if notes is None:
-            notes = self._tls.notes = []
-        notes.append(note)
-
-    def drain_notes(self) -> List[dict]:
-        notes = getattr(self._tls, "notes", None)
-        if not notes:
-            return []
-        out = list(notes)
-        notes.clear()
-        return out
-
     # -- views -------------------------------------------------------
     def summary(self) -> dict:
-        """The bench/report view: compile ledger + per-boundary
-        cumulative device time + attributed retraces."""
+        """The bench/report view: compile ledger + per-boundary launch
+        and compile counts + attributed retraces."""
         with self._lock:
             entries = [e.to_dict() for e in self._entries.values()]
             bounds = {
                 n: st.to_dict() for n, st in self._boundaries.items()
             }
             retraces = list(self._retraces)
-            sample = self.sample
+            attribute = self.attribute
         entries.sort(key=lambda d: (d["boundary"], d["sig"]))
         return {
-            "sample": sample,
+            "attribute": attribute,
             "backend": _backend_platform(),
             "boundaries": bounds,
             "entries": entries,
             "retraces": retraces,
         }
 
-    def health_block(self, top: int = 3) -> dict:
-        """The /healthz ``device`` block: platform, device count, the
-        compile ledger summary, and the top boundaries by cumulative
-        device time."""
+    def health_block(self) -> dict:
+        """The /healthz ``device`` block: platform, device count and
+        the compile ledger summary."""
         with self._lock:
             compiles = sum(st.compiles for st in self._boundaries.values())
             compile_ms = sum(
                 e.compile_ms or 0.0 for e in self._entries.values()
             )
-            ranked = sorted(
-                (
-                    (n, st) for n, st in self._boundaries.items()
-                    if st.device_us_total > 0
-                ),
-                key=lambda kv: kv[1].device_us_total,
-                reverse=True,
-            )[:top]
             retraces = len(self._retraces)
-            sample = self.sample
+            attribute = self.attribute
             registered = len(self._boundaries)
         return {
             "platform": _backend_platform(),
             "device_count": _device_count(),
-            "sample": sample,
+            "attribute": attribute,
             "registered_boundaries": registered,
             "compiles": compiles,
             "compile_ms_total": round(compile_ms, 3),
             "retraces": retraces,
-            "top": [
-                {
-                    "boundary": n,
-                    "device_us_total": round(st.device_us_total, 1),
-                    "sampled": st.sampled,
-                    "launches": st.launches,
-                }
-                for n, st in ranked
-            ],
         }
 
     # -- persistence -------------------------------------------------
@@ -490,8 +403,6 @@ class LaunchLedger:
             os.replace(tmp, path)
         except OSError:
             return None
-        with self._lock:
-            self._unflushed = 0
         return path
 
     def _flush(self, force: bool = False) -> None:
@@ -522,15 +433,16 @@ def reset() -> None:
     _LEDGER = LaunchLedger()
 
 
-def configure(sample: Optional[int] = None, metrics=None,
+def configure(attribute: Optional[bool] = None, metrics=None,
               state_dir: Optional[str] = None,
               capture: Optional[bool] = None) -> None:
-    _LEDGER.configure(sample=sample, metrics=metrics, state_dir=state_dir,
-                      capture=capture)
+    _LEDGER.configure(attribute=attribute, metrics=metrics,
+                      state_dir=state_dir, capture=capture)
 
 
 def enabled() -> bool:
-    return _LEDGER.sample > 0
+    """Whether compile attribution is on."""
+    return _LEDGER.attribute
 
 
 def capture_enabled() -> bool:
@@ -591,18 +503,12 @@ def load_replays(records: List[dict]) -> None:
     _LEDGER.load_replays(records)
 
 
-def drain_notes() -> List[dict]:
-    """Pop this thread's launch notes (bridge span attribution).  Cheap
-    no-op when devprof is off — the wrapper never pushes then."""
-    return _LEDGER.drain_notes()
-
-
 def summary() -> dict:
     return _LEDGER.summary()
 
 
-def health_block(top: int = 3) -> dict:
-    return _LEDGER.health_block(top=top)
+def health_block() -> dict:
+    return _LEDGER.health_block()
 
 
 def dump(state_dir: Optional[str] = None) -> Optional[str]:
@@ -765,15 +671,16 @@ def boundary(name: str):
         @partial(jax.jit, static_argnames=("cfg",))
         def score_cycle(snapshot, *, cfg): ...
 
-    Off (``sample == 0`` and prewarm capture off): one comparison then
+    Off (attribution and prewarm capture off): one comparison then
     tail-call — the warm stream is bit-identical with zero retraces
     (the tier-1 retrace-guard oracles run this path).  Inside a live
     jax trace the wrapper also steps aside: nested boundaries
     (``score_cycle`` under the Pallas cycle, term extras inside
-    ``score_all``) measure at their outermost host callsite only.
-    With prewarm capture ON (``--prewarm``, ISSUE 20) every outermost
-    launch additionally records its (boundary, signature) and an
-    abstract replay spec for the next incarnation's prewarm thread.
+    ``score_all``) are attributed at their outermost host callsite
+    only.  With prewarm capture ON (``--prewarm``, ISSUE 20) every
+    outermost launch additionally records its (boundary, signature)
+    and an abstract replay spec for the next incarnation's prewarm
+    thread.
     """
 
     def deco(fn):
@@ -783,7 +690,7 @@ def boundary(name: str):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             led = _LEDGER
-            if led.sample <= 0 and not led.capture:
+            if not led.attribute and not led.capture:
                 return fn(*args, **kwargs)  # bit-inert fast path
             if not _trace_state_clean():
                 return fn(*args, **kwargs)  # nested under another jit
@@ -794,44 +701,16 @@ def boundary(name: str):
                     )
                 except Exception:  # koordlint: disable=broad-except(reason: replay capture is an accelerant — an exotic pytree costs the prewarm record, never the launch)
                     pass
-                if led.sample <= 0:
+                if not led.attribute:
                     return fn(*args, **kwargs)
             led.note_launch(name)
-            compile_ms = None
             try:
                 sig = shape_signature(args, kwargs)
                 cold = not led.seen_sig(name, sig)
             except Exception:  # koordlint: disable=broad-except(reason: an unhashable/exotic pytree must cost attribution, never the launch — fall through to the plain call)
                 return fn(*args, **kwargs)
             if cold:
-                # AOT capture; this signature's launch is NOT
-                # device-sampled — the jit-cache compile it pays next
-                # would contaminate the sample
-                compile_ms = _aot_capture(led, name, sig, fn, args, kwargs)
-                out = fn(*args, **kwargs)
-                led.push_note({
-                    "boundary": name, "sig": sig, "compiled": True,
-                    "compile_ms": compile_ms, "device_us": None,
-                    "flops": _entry_flops(led, name, sig),
-                })
-                return out
-            if led.should_sample():
-                import jax
-
-                t0 = _now()
-                out = fn(*args, **kwargs)
-                try:
-                    jax.block_until_ready(out)
-                except Exception:  # koordlint: disable=broad-except(reason: non-array outputs or backend drift make the barrier best-effort; the sample degrades to dispatch time, the result is returned untouched)
-                    pass
-                device_us = (_now() - t0) * 1e6
-                led.record_device_time(name, device_us)
-                led.push_note({
-                    "boundary": name, "sig": sig, "compiled": False,
-                    "compile_ms": None, "device_us": device_us,
-                    "flops": _entry_flops(led, name, sig),
-                })
-                return out
+                _aot_capture(led, name, sig, fn, args, kwargs)
             return fn(*args, **kwargs)
 
         wrapper.__wrapped__ = fn
@@ -841,19 +720,15 @@ def boundary(name: str):
     return deco
 
 
-def _entry_flops(led: LaunchLedger, name: str, sig: str) -> Optional[float]:
-    with led._lock:
-        e = led._entries.get((name, sig))
-        return e.flops if e is not None else None
-
-
 # -- on-demand profiler capture (admin plane) ------------------------
 
 def capture_profile(state_dir: str, window_ms: int = 1000) -> str:
     """Start a ``jax.profiler`` trace window under ``state_dir`` and
     stop it after ``window_ms`` on a background thread — the admin-RPC
     seam (udsserver METHOD_PROFILE) returns the capture directory
-    immediately; XLA-level inspection happens offline."""
+    immediately; inspection happens offline.  The trace holds the
+    device operations and the ``koord.*`` host stages (obs/spans.py)
+    on one clock."""
     import jax
 
     out_dir = os.path.join(
@@ -881,14 +756,15 @@ def _fmt_num(v, scale=1.0, suffix="") -> str:
     return f"{v / scale:,.1f}{suffix}"
 
 
-def format_report(doc: dict, top: int = 10) -> str:
+def format_report(doc: dict) -> str:
     """Render a dumped ledger: the compile ledger (one row per
     boundary+signature with compile ms / flops / bytes) and the
-    top-N-by-cumulative-device-time table."""
+    attributed retraces."""
     lines = []
     backend = doc.get("backend") or "unknown"
     lines.append(
-        f"devprof ledger — backend={backend} sample={doc.get('sample')}"
+        f"devprof ledger — backend={backend} "
+        f"attribute={doc.get('attribute')}"
     )
     lines.append("")
     lines.append("compile ledger:")
@@ -907,30 +783,6 @@ def format_report(doc: dict, top: int = 10) -> str:
         )
     if not doc.get("entries"):
         lines.append("  (no compiles captured)")
-    lines.append("")
-    lines.append(f"top boundaries by cumulative device time (top {top}):")
-    lines.append(
-        f"  {'boundary':<44} {'device_ms':>10} {'sampled':>8} "
-        f"{'launches':>9} {'compiles':>9}"
-    )
-    ranked = sorted(
-        doc.get("boundaries", {}).items(),
-        key=lambda kv: kv[1].get("device_us_total", 0.0),
-        reverse=True,
-    )
-    shown = 0
-    for name, st in ranked:
-        if shown >= top:
-            break
-        lines.append(
-            f"  {name:<44} "
-            f"{st.get('device_us_total', 0.0) / 1e3:>10,.2f} "
-            f"{st.get('sampled', 0):>8} {st.get('launches', 0):>9} "
-            f"{st.get('compiles', 0):>9}"
-        )
-        shown += 1
-    if not ranked:
-        lines.append("  (no launches recorded)")
     retraces = doc.get("retraces", [])
     if retraces:
         lines.append("")
@@ -949,25 +801,22 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m koordinator_tpu.obs.devprof",
         description="Print the XLA launch ledger captured under a "
-        "daemon's --state-dir (compile costs + top boundaries by "
-        "cumulative device time).",
+        "daemon's --state-dir (compile costs and attributed retraces).",
     )
     ap.add_argument("state_dir", help="daemon --state-dir (or any "
                     f"directory holding {LEDGER_FILENAME})")
-    ap.add_argument("--top", type=int, default=10,
-                    help="rows in the device-time table (default 10)")
     args = ap.parse_args(argv)
     path = args.state_dir
     if os.path.isdir(path):
         path = os.path.join(path, LEDGER_FILENAME)
     if not os.path.exists(path):
-        print(f"devprof: no ledger at {path} (run a daemon with "
-              "--devprof-sample > 0, or call devprof.dump())",
-              file=sys.stderr)
+        print(f"devprof: no ledger at {path} (turn attribution on with "
+              "devprof.configure(attribute=True), or call "
+              "devprof.dump())", file=sys.stderr)
         return 2
     with open(path) as fh:
         doc = json.load(fh)
-    print(format_report(doc, top=args.top))
+    print(format_report(doc))
     return 0
 
 

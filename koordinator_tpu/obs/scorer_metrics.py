@@ -21,6 +21,7 @@ koord_scorer_resident_warm             gauge     — (last Sync: 1 warm/0 cold)
 koord_scorer_uds_frames_total          counter   method
 koord_scorer_uds_malformed_total       counter   reason
 koord_scorer_uds_errors_total          counter   —
+koord_scorer_rpc_request_bytes_total   counter   method (sync|score|assign)
 koord_scorer_coalesce_queue_delay_ms   histogram —
 koord_scorer_coalesce_batch_occupancy  histogram —
 koord_scorer_coalesce_batches_total    counter   —
@@ -66,7 +67,6 @@ koord_scorer_autoscale_events_total    counter   action (scale_up|scale_down)
 koord_scorer_autoscale_replicas        gauge     — (autoscaler's target size)
 koord_scorer_devprof_compiles_total    counter   boundary, backend
 koord_scorer_devprof_compile_ms_total  counter   boundary, backend
-koord_scorer_devprof_device_us         histogram boundary
 koord_scorer_devprof_retrace_total     counter   boundary
 koord_scorer_prewarm_signatures_total  counter   result (compiled|skipped|failed)
 koord_scorer_prewarm_compile_ms_total  counter   —
@@ -131,6 +131,7 @@ RESIDENT_WARM = "koord_scorer_resident_warm"
 UDS_FRAMES = "koord_scorer_uds_frames_total"
 UDS_MALFORMED = "koord_scorer_uds_malformed_total"
 UDS_ERRORS = "koord_scorer_uds_errors_total"
+RPC_REQUEST_BYTES = "koord_scorer_rpc_request_bytes_total"
 COALESCE_QUEUE_DELAY = "koord_scorer_coalesce_queue_delay_ms"
 COALESCE_OCCUPANCY = "koord_scorer_coalesce_batch_occupancy"
 COALESCE_BATCHES = "koord_scorer_coalesce_batches_total"
@@ -176,7 +177,6 @@ AUTOSCALE_EVENTS = "koord_scorer_autoscale_events_total"
 AUTOSCALE_REPLICAS = "koord_scorer_autoscale_replicas"
 DEVPROF_COMPILES = "koord_scorer_devprof_compiles_total"
 DEVPROF_COMPILE_MS = "koord_scorer_devprof_compile_ms_total"
-DEVPROF_DEVICE_US = "koord_scorer_devprof_device_us"
 DEVPROF_RETRACE = "koord_scorer_devprof_retrace_total"
 PREWARM_SIGNATURES = "koord_scorer_prewarm_signatures_total"
 PREWARM_COMPILE_MS = "koord_scorer_prewarm_compile_ms_total"
@@ -223,6 +223,9 @@ _FAMILIES = (
      "malformed raw-UDS frames (oversized, unknown method, truncated "
      "mid-frame), by reason"),
     (UDS_ERRORS, "counter", "raw-UDS requests answered with an error frame"),
+    (RPC_REQUEST_BYTES, "counter",
+     "serialized request bytes the server deserialized (gRPC and "
+     "raw-UDS), by method: the Sync path's bandwidth"),
     (COALESCE_QUEUE_DELAY, "histogram",
      "time a Score request waited in the coalescer's gather queue "
      "before its batch launched"),
@@ -401,11 +404,6 @@ _FAMILIES = (
      "cumulative XLA compile wall-time the ledger attributed, by "
      "boundary and backend; divide by devprof_compiles_total for the "
      "mean compile cost of that boundary's programs"),
-    (DEVPROF_DEVICE_US, "histogram",
-     "sampled per-launch device execution time (dispatch to "
-     "block_until_ready on the launch's own outputs), by boundary; "
-     "sampling is 1-in-N (--devprof-sample), so multiply counts by N "
-     "to estimate launch totals"),
     (DEVPROF_RETRACE, "counter",
      "attributed retraces: a registered boundary minted a NEW program "
      "for a shape signature after its first — the per-boundary "
@@ -436,14 +434,6 @@ _JOURNAL_APPEND_BUCKETS = (
     float("inf"),
 )
 
-# sampled device launches span ~100 us (a warm delta scatter) to
-# multiple seconds (a cold-start dense cycle on CPU): wider-than-journal
-# microsecond buckets
-_DEVPROF_US_BUCKETS = (
-    100.0, 1_000.0, 5_000.0, 20_000.0, 100_000.0, 500_000.0,
-    2_000_000.0, 10_000_000.0, float("inf"),
-)
-
 # per-family bucket overrides (histograms default to DEFAULT_BUCKETS_MS)
 _BUCKET_OVERRIDES = {
     COALESCE_OCCUPANCY: _OCCUPANCY_BUCKETS,
@@ -451,7 +441,6 @@ _BUCKET_OVERRIDES = {
     JOURNAL_APPEND_US: _JOURNAL_APPEND_BUCKETS,
     # frames-per-wakeup is a count, like coalesce occupancy
     SEND_BATCH_FRAMES: _OCCUPANCY_BUCKETS,
-    DEVPROF_DEVICE_US: _DEVPROF_US_BUCKETS,
 }
 
 
@@ -536,6 +525,9 @@ class ScorerMetrics:
 
     def count_uds_error(self) -> None:
         self.registry.counter_add(UDS_ERRORS, 1)
+
+    def count_request_bytes(self, method: str, n: int) -> None:
+        self.registry.counter_add(RPC_REQUEST_BYTES, n, {"method": method})
 
     def record_coalesce(self, batch_size: int, queue_delays_ms) -> None:
         """One coalesced launch: how many requests shared it and how
@@ -711,11 +703,6 @@ class ScorerMetrics:
         self.registry.counter_add(DEVPROF_COMPILES, 1, labels)
         self.registry.counter_add(
             DEVPROF_COMPILE_MS, float(compile_ms), labels
-        )
-
-    def devprof_device_us(self, boundary: str, us: float) -> None:
-        self.registry.histogram_observe(
-            DEVPROF_DEVICE_US, float(us), {"boundary": boundary}
         )
 
     def devprof_retrace(self, boundary: str) -> None:

@@ -12,7 +12,8 @@ Design constraints (the acceptance criteria of ISSUE 4):
 * **No host syncs, no retraces.**  The recorder only ever touches
   host-side Python scalars: ``begin_span``/``end_span`` read a
   monotonic clock and append to a list; ``note()`` stores values the
-  caller already materialized.  Nothing here imports jax, and calling
+  caller already materialized.  Nothing here imports jax at load (the
+  profiler annotation below imports it on first use), and calling
   the span API inside jitted code is rejected statically by koordlint's
   ``host-sync-in-jit`` rule (a span inside a traced function would
   record trace time once and then never run again — the same trap as a
@@ -34,6 +35,14 @@ Design constraints (the acceptance criteria of ISSUE 4):
   public API with a small RLock (host-side, ~100ns — invisible next to
   a device launch); :class:`CycleSpans` itself stays lock-free and is
   only reached through the recorder.
+* **On the profiler's clock.**  Every ``span()`` also enters a
+  ``jax.profiler.TraceAnnotation`` (TraceMe) named ``koord.<span>``
+  carrying ``cycle=<cycle_id>``, so a device trace places the host
+  stages against device gaps.  Stages with no recorder (the client) or
+  attached after the fact with ``add_measured`` are wrapped where they
+  run with :func:`profiled`.  With no profiler session active an
+  annotation costs one ``is_enabled`` check; jax is imported on first
+  use only.
 """
 
 from __future__ import annotations
@@ -48,6 +57,62 @@ from koordinator_tpu.obs.lockwitness import witness_rlock
 # hard cap on spans buffered per cycle: a runaway instrumentation loop
 # must cost a counter bump, not memory
 MAX_SPANS_PER_CYCLE = 256
+
+# profiler annotation names are "koord.<stage>"
+PROFILER_PREFIX = "koord."
+
+_TRACE_ME = None
+
+
+def _trace_me():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    ``obs`` stays importable without jax."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+def profiled(name: str, cycle=None):
+    """``with profiled("stage") as ann:`` — a profiler annotation named
+    ``koord.<name>`` over the block, for stages no recorder span covers.
+    ``cycle`` (a SpanRecorder or CycleScope) stamps ``cycle=<its
+    cycle_id>``, read only while a profiler session is active.  Returns
+    a null annotation outside a session."""
+    tm = _trace_me()
+    if not tm.is_enabled():
+        return _NULL_ANNOTATION
+    if cycle is None:
+        return tm(PROFILER_PREFIX + name)
+    return tm(PROFILER_PREFIX + name, cycle=cycle.cycle_id)
+
+
+def tag_cycle(ann, cycle) -> None:
+    """Stamp ``cycle=<cycle.cycle_id>`` on an open ``profiled()``
+    annotation whose cycle became known inside it (a no-op, reading
+    nothing, outside a profiler session)."""
+    if ann is not _NULL_ANNOTATION:
+        ann.set_metadata(cycle=cycle.cycle_id)
+
+
+class _NullAnnotation:
+    """``profiled()`` outside a profiler session."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullAnnotation":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **_kw) -> None:
+        pass
+
+
+_NULL_ANNOTATION = _NullAnnotation()
 
 # the one cross-trace link type (ISSUE 14): the many RPC spans of a
 # coalesced batch — and every memo/brownout serve — reference the ONE
@@ -322,21 +387,26 @@ class CycleSpans:
 
 
 class _SpanContext:
-    """Tiny re-usable with-block over begin/end.  Not @contextmanager:
-    a generator frame per span is measurable overhead on the warm path."""
+    """Tiny re-usable with-block over begin/end, plus the span's
+    profiler annotation (``koord.<name>``, ``cycle=<cycle_id>``) while
+    a profiler session runs.  Not @contextmanager: a generator frame
+    per span is measurable overhead on the warm path."""
 
-    __slots__ = ("_recorder", "_name", "_handle")
+    __slots__ = ("_recorder", "_name", "_handle", "_ann")
 
     def __init__(self, recorder: "SpanRecorder", name: str):
         self._recorder = recorder
         self._name = name
         self._handle = -1
+        self._ann = _NULL_ANNOTATION
 
     def __enter__(self) -> "_SpanContext":
         self._handle = self._recorder.begin_span(self._name)
+        self._ann = profiled(self._name, self._recorder).__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(None, None, None)
         self._recorder.end_span(self._handle)
         return False
 
@@ -432,6 +502,13 @@ class SpanRecorder:
         (e.g. a delta-Sync waiting for the Assign that correlates it)."""
         with self._lock:
             return self._cycle is not None
+
+    @property
+    def cycle_id(self) -> str:
+        """The open cycle's id (created on first touch, as by
+        ``current()``) — the ``cycle`` a span's annotation carries."""
+        with self._lock:
+            return self.current().cycle_id
 
     # -- distributed-trace spans (ISSUE 14) --
     def mint_span_id(self) -> str:

@@ -103,7 +103,6 @@ class SchedulerServer:
         brownout_max_lag: Optional[int] = None,
         trace_export: Optional[str] = None,
         shed_fractions: Optional[dict] = None,
-        devprof_sample: Optional[int] = None,
         prewarm: bool = False,
     ):
         if state_dir is None:
@@ -222,13 +221,6 @@ class SchedulerServer:
             servicer_kw["trace_export"] = trace_export
         if shed_fractions is not None:
             servicer_kw["shed_fractions"] = shed_fractions
-        # device-time truth (ISSUE 19): --devprof-sample wires the XLA
-        # launch ledger — compile/cost attribution at every registered
-        # jit boundary plus 1-in-N device-time sampling.  Default off:
-        # the serving path stays bit-inert (reply-byte parity, zero jit
-        # cache misses) unless the operator opts in.
-        if devprof_sample is not None:
-            servicer_kw["devprof_sample"] = int(devprof_sample)
         # cold-path kill (ISSUE 20, docs/KERNEL.md "Cold path"):
         # --prewarm turns on the launch ledger's CAPTURE mode — every
         # boundary launch records its abstract signature into
@@ -590,11 +582,9 @@ class SchedulerServer:
 
     def device_health(self) -> dict:
         """The /healthz ``device`` block (ISSUE 19): backend platform
-        and device count, the launch ledger's compile summary (compiles,
-        cumulative compile wall-time, attributed retraces), and the top
-        boundaries by cumulative sampled device time.  With the ledger
-        off (``--devprof-sample`` unset/0) the block still reports the
-        platform so operators can tell CPU-leg from TPU-leg daemons."""
+        and device count, and the launch ledger's compile summary
+        (compiles, cumulative compile wall-time, attributed retraces)
+        — zeros while attribution is off."""
         from koordinator_tpu.obs import devprof
 
         return devprof.health_block()
@@ -738,7 +728,9 @@ class SchedulerServer:
             # optional ASCII window in milliseconds; the reply is the
             # capture directory under --state-dir.  jax.profiler stops
             # on a background thread so the admin RPC returns
-            # immediately — the operator polls the directory.
+            # immediately — the operator polls the directory.  The
+            # trace holds the koord.* host stages (obs/spans.py) beside
+            # the device operations.
             from koordinator_tpu.obs import devprof
 
             window_ms = 1000
@@ -1186,22 +1178,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
             f"KOORD_SHED_FRACTION_{suffix})",
         )
     ap.add_argument(
-        "--devprof-sample", type=int,
-        default=(
-            int(os.environ["KOORD_DEVPROF_SAMPLE"])
-            if os.environ.get("KOORD_DEVPROF_SAMPLE") else None
-        ),
-        help="device-time truth (docs/OBSERVABILITY.md \"Device-time "
-        "truth\"): sample 1-in-N serving launches for device wall-time "
-        "through the XLA launch ledger, and capture compile time + XLA "
-        "cost/memory analysis at every jit boundary's first compile; "
-        "16 is the recommended rate; 0/unset = off — the serving path "
-        "stays bit-inert (reply-byte parity, zero retraces).  Ledger "
-        "persists to <state-dir>/devprof.json; read it with `python -m "
-        "koordinator_tpu.obs.devprof <state-dir>` (env: "
-        "KOORD_DEVPROF_SAMPLE)",
-    )
-    ap.add_argument(
         "--prewarm", action="store_true",
         default=bool(os.environ.get("KOORD_PREWARM")),
         help="AOT signature prewarm (docs/KERNEL.md \"Cold path\"): "
@@ -1266,7 +1242,6 @@ def main(argv=None) -> int:
         brownout_max_lag=args.brownout_max_lag,
         trace_export=args.trace_export,
         shed_fractions=shed_fractions,
-        devprof_sample=args.devprof_sample,
         prewarm=args.prewarm,
     ).start()
     try:

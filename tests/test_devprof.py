@@ -1,16 +1,11 @@
-"""Device-time truth (ISSUE 19): the XLA launch ledger.
+"""Compile truth (ISSUE 19): the XLA launch ledger.
 
 Unit surfaces: boundary registration completeness across the serving
-path, the sample==0 bit-inert contract (zero retraces, no ledger
+path, the attribution-off bit-inert contract (zero retraces, no ledger
 mutation, identical results), first-compile AOT capture with
 cost/memory attribution, attributed retrace events naming boundary +
-shape signature, per-thread launch notes (the span attribution seam),
-the /healthz ``device`` block end-to-end over HTTP, and the report
-CLI's golden shape.
-
-The reply-byte parity and p99-overhead acceptance runs live in
-``bench.py --config bridge`` (the devprof storm probe); this file owns
-everything assertable in-process.
+shape signature, the /healthz ``device`` block end-to-end over HTTP,
+the report CLI's golden shape and the admin profile capture.
 """
 
 import json
@@ -110,7 +105,7 @@ class TestRegistrationCompleteness:
 class TestBitInertOff:
     def test_sample_zero_is_the_default_and_off(self):
         assert not devprof.enabled()
-        assert devprof.summary()["sample"] == 0
+        assert devprof.summary()["attribute"] is False
 
     def test_off_path_zero_retraces_and_no_ledger_state(self):
         fn = _make_boundary("test.off")
@@ -122,7 +117,7 @@ class TestBitInertOff:
         summ = devprof.summary()
         assert summ["boundaries"]["test.off"]["launches"] == 0
         assert summ["entries"] == [] and summ["retraces"] == []
-        assert devprof.drain_notes() == []
+        assert summ["boundaries"]["test.off"]["compiles"] == 0
 
     def test_off_result_identical_to_unwrapped(self):
         fn = _make_boundary("test.parity")
@@ -134,7 +129,7 @@ class TestBitInertOff:
 
 class TestSampledCapture:
     def test_cold_launch_captures_compile_truth(self):
-        devprof.configure(sample=1)
+        devprof.configure(attribute=True)
         fn = _make_boundary("test.cold")
         np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))
         summ = devprof.summary()
@@ -150,43 +145,36 @@ class TestSampledCapture:
             v = entry[key]
             assert v is None or (np.isfinite(v) and v >= 0)
         assert summ["boundaries"]["test.cold"]["compiles"] == 1
-        # the cold launch is never device-sampled (its timing would
-        # include the jit-cache compile, not the program)
-        assert summ["boundaries"]["test.cold"]["sampled"] == 0
+        assert summ["boundaries"]["test.cold"]["launches"] == 1
 
-    def test_warm_sampled_time_is_finite_positive_and_monotone(self):
-        devprof.configure(sample=1)
-        fn = _make_boundary("test.warm")
-        x = jnp.arange(32, dtype=jnp.float32)
-        np.asarray(fn(x))  # cold: AOT capture
-        totals = []
-        for _ in range(3):
-            np.asarray(fn(x))
-            st = devprof.summary()["boundaries"]["test.warm"]
-            assert np.isfinite(st["device_us_total"])
-            assert st["device_us_total"] > 0
-            totals.append(st["device_us_total"])
-        assert totals == sorted(totals)  # cumulative: monotone
-        st = devprof.summary()["boundaries"]["test.warm"]
-        assert st["sampled"] == 3
-        assert st["launches"] == 4
 
-    def test_one_in_n_sampling_rate(self):
-        devprof.configure(sample=4)
-        fn = _make_boundary("test.rate")
+    def test_warm_launches_count_without_recapture(self):
+        devprof.configure(attribute=True)
+        fn = _make_boundary("test.warmcount")
         x = jnp.arange(8, dtype=jnp.float32)
-        np.asarray(fn(x))  # cold
-        for _ in range(16):
+        for _ in range(4):
             np.asarray(fn(x))
-        st = devprof.summary()["boundaries"]["test.rate"]
-        assert st["launches"] == 17
-        # 1-in-4 over a shared counter: ~4 of the 16 warm launches
-        assert 2 <= st["sampled"] <= 6
+        st = devprof.summary()["boundaries"]["test.warmcount"]
+        assert st == {"launches": 4, "compiles": 1}
+        assert len(devprof.summary()["entries"]) == 1
+
+    def test_capture_alone_attributes_no_compile(self):
+        # prewarm capture records replay specs; compile attribution is
+        # its own switch
+        devprof.configure(capture=True)
+        fn = _make_boundary("test.captureonly")
+        np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))
+        summ = devprof.summary()
+        assert summ["entries"] == []
+        assert summ["boundaries"]["test.captureonly"]["compiles"] == 0
+        assert [r["boundary"] for r in devprof.replay_records()] == [
+            "test.captureonly"
+        ]
 
 
 class TestRetraceAttribution:
     def test_new_shape_is_an_attributed_event(self):
-        devprof.configure(sample=1)
+        devprof.configure(attribute=True)
         fn = _make_boundary("test.retrace")
         np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))
         assert devprof.summary()["retraces"] == []  # first compile
@@ -198,7 +186,7 @@ class TestRetraceAttribution:
         assert ev["compile_ms"] is not None and ev["compile_ms"] > 0
 
     def test_warm_shape_never_retraces(self):
-        devprof.configure(sample=1)
+        devprof.configure(attribute=True)
         fn = _make_boundary("test.stable")
         x = jnp.arange(8, dtype=jnp.float32)
         for _ in range(4):
@@ -206,26 +194,9 @@ class TestRetraceAttribution:
         assert devprof.summary()["retraces"] == []
 
 
-class TestLaunchNotes:
-    def test_cold_and_warm_notes_then_drain_empties(self):
-        devprof.configure(sample=1)
-        fn = _make_boundary("test.notes")
-        x = jnp.arange(8, dtype=jnp.float32)
-        np.asarray(fn(x))
-        (cold,) = devprof.drain_notes()
-        assert cold["boundary"] == "test.notes"
-        assert cold["compiled"] is True
-        assert cold["device_us"] is None
-        np.asarray(fn(x))
-        (warm,) = devprof.drain_notes()
-        assert warm["compiled"] is False
-        assert warm["device_us"] is not None and warm["device_us"] > 0
-        assert devprof.drain_notes() == []
-
-
 class TestHealthBlock:
     def test_shape_and_ranking(self):
-        devprof.configure(sample=1)
+        devprof.configure(attribute=True)
         fn = _make_boundary("test.health")
         x = jnp.arange(8, dtype=jnp.float32)
         np.asarray(fn(x))
@@ -233,15 +204,16 @@ class TestHealthBlock:
         blk = devprof.health_block()
         assert blk["platform"] == "cpu"
         assert blk["device_count"] >= 1
-        assert blk["sample"] == 1
+        assert blk["attribute"] is True
         assert blk["registered_boundaries"] >= 1
         assert blk["compiles"] == 1
         assert blk["compile_ms_total"] > 0
         assert blk["retraces"] == 0
-        (top,) = blk["top"]
-        assert top["boundary"] == "test.health"
-        assert top["device_us_total"] > 0
-        assert top["sampled"] == 1 and top["launches"] == 2
+        assert set(blk) == {
+            "platform", "device_count", "attribute",
+            "registered_boundaries", "compiles", "compile_ms_total",
+            "retraces",
+        }
 
     def test_healthz_serves_device_block(self, tmp_path):
         """The daemon end-to-end: /healthz carries the ``device`` block
@@ -250,11 +222,11 @@ class TestHealthBlock:
 
         from koordinator_tpu.scheduler.server import SchedulerServer
 
+        devprof.configure(attribute=True)
         s = SchedulerServer(
             lease_path=str(tmp_path / "l.lease"),
             uds_path=str(tmp_path / "scorer.sock"),
             enable_grpc=False,
-            devprof_sample=1,
         ).start()
         try:
             with urllib.request.urlopen(
@@ -263,41 +235,41 @@ class TestHealthBlock:
                 doc = json.loads(r.read())
             dev = doc["device"]
             assert dev["platform"] == "cpu"
-            assert dev["sample"] == 1
+            assert dev["attribute"] is True
             for key in ("device_count", "registered_boundaries",
-                        "compiles", "compile_ms_total", "retraces", "top"):
+                        "compiles", "compile_ms_total", "retraces"):
                 assert key in dev
-            assert isinstance(dev["top"], list)
+            assert "top" not in dev
         finally:
             s.stop()
 
 
 class TestDumpAndReportCli:
     def test_dump_writes_ledger_json(self, tmp_path):
-        devprof.configure(sample=1, state_dir=str(tmp_path))
+        devprof.configure(attribute=True, state_dir=str(tmp_path))
         fn = _make_boundary("test.dump")
         np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))
         path = devprof.dump()
         assert path == str(tmp_path / devprof.LEDGER_FILENAME)
         with open(path) as fh:
             doc = json.load(fh)
-        assert doc["sample"] == 1
+        assert doc["attribute"] is True
         assert doc["entries"][0]["boundary"] == "test.dump"
 
     def test_report_cli_golden(self, tmp_path, capsys):
-        devprof.configure(sample=1, state_dir=str(tmp_path))
+        devprof.configure(attribute=True, state_dir=str(tmp_path))
         fn = _make_boundary("test.report")
         np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))
-        np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))  # warm sample
+        np.asarray(fn(jnp.arange(8, dtype=jnp.float32)))  # warm
         np.asarray(fn(jnp.arange(9, dtype=jnp.float32)))  # retrace
         devprof.dump()
         assert devprof.main([str(tmp_path)]) == 0
         out = capsys.readouterr().out
-        assert "devprof ledger — backend=cpu sample=1" in out
+        assert "devprof ledger — backend=cpu attribute=True" in out
         assert "compile ledger:" in out
         assert "test.report" in out
         assert "float32[8]" in out and "float32[9]" in out
-        assert "top boundaries by cumulative device time" in out
+        assert "device time" not in out
         assert "attributed retraces (1):" in out
 
     def test_report_cli_missing_ledger_exits_2(self, tmp_path, capsys):
@@ -307,7 +279,7 @@ class TestDumpAndReportCli:
 
 class TestNestedTraceBypass:
     def test_boundary_under_a_live_trace_is_unmeasured(self):
-        devprof.configure(sample=1)
+        devprof.configure(attribute=True)
         inner = _make_boundary("test.inner")
 
         @jax.jit
@@ -323,73 +295,6 @@ class TestNestedTraceBypass:
         )["launches"] == 0
         assert all(
             e["boundary"] != "test.inner" for e in summ["entries"]
-        )
-
-
-class TestWaterfallEndToEnd:
-    def test_traced_tier_renders_host_device_split(self, tmp_path):
-        """The acceptance rendering: a traced serving tier with the
-        ledger sampling every launch exports spans whose assembled
-        waterfall carries host/device attribution on >= 1 request
-        tree (cold launch -> compile= attr, warm launch -> dev=)."""
-        from koordinator_tpu.bridge.client import ScorerClient
-        from koordinator_tpu.bridge.server import (
-            ScorerServicer,
-            make_server,
-        )
-        from koordinator_tpu.obs import assemble as assemble_mod
-        import numpy as np
-
-        traces = str(tmp_path / "traces")
-        sock = os.path.join(str(tmp_path), "s.sock")
-        sv = ScorerServicer(
-            trace_export=traces, devprof_sample=1,
-            score_memo=False, score_incr=False,
-        )
-        server = make_server(servicer=sv)
-        server.add_insecure_port(f"unix://{sock}")
-        server.start()
-        client = ScorerClient(f"unix://{sock}", trace_export=traces)
-        from koordinator_tpu.harness.trace import (
-            ClusterModel,
-            TraceConfig,
-            _build_init,
-        )
-
-        rng = np.random.default_rng(7)
-        cfg = TraceConfig(
-            nodes=8, pod_slots=24, gangs=2, gang_min_member=2
-        )
-        model = ClusterModel(_build_init(cfg, rng))
-        try:
-            client.sync(
-                node_allocatable=model.nalloc,
-                node_requested=model.nreq,
-                node_usage=model.nuse,
-                metric_fresh=list(model.fresh),
-                pod_requests=model.preq,
-                pod_estimated=model.pest,
-                priority=list(model.priority),
-                gang_id=list(model.gang_id),
-                quota_id=list(model.quota_id),
-                gang_min_member=list(model.gang_min),
-                quota_runtime=model.qrt,
-                quota_used=model.quse,
-                quota_limited=model.qlim,
-            )
-            client.score_flat(top_k=4)  # cold: compile attribution
-            client.score_flat(top_k=4)  # warm: sampled device time
-        finally:
-            client.close()
-            sv.telemetry.close()
-            server.stop(0)
-        asm = assemble_mod.assemble([traces])
-        rendered = [
-            assemble_mod.render_waterfall(t, asm)
-            for t in asm.traces.values()
-        ]
-        assert any(
-            "dev=" in text or "compile=" in text for text in rendered
         )
 
 

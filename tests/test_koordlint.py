@@ -956,6 +956,24 @@ class TestHostSyncObsAPI:
         """)
         assert [(v.rule, v.line) for v in got] == [("host-sync-in-jit", 6)]
 
+    def test_profiler_annotation_inside_jit_caught(self):
+        got = lint("""
+        import jax
+        from koordinator_tpu.obs import spans
+
+        @jax.jit
+        def cycle(x):
+            with spans.profiled("dispatch"):
+                y = x + 1
+            with profiled("readback") as ann:
+                tag_cycle(ann, x)
+            return y
+        """)
+        assert [(v.rule, v.line) for v in got] == [
+            ("host-sync-in-jit", 7), ("host-sync-in-jit", 9),
+            ("host-sync-in-jit", 10),
+        ]
+
     def test_obs_outside_jit_is_clean(self):
         assert lint("""
         def serve(recorder, snap):

@@ -588,6 +588,42 @@ class TestRetraceBudget:
             sv.telemetry.flight.document("test")
         ) == []
 
+    def test_warm_stream_under_a_profiler_is_retrace_free(self, tmp_path):
+        """The koord.* profiler annotations (obs/spans.py) stay outside
+        the traced programs: a warm stream recorded under a profiler
+        session holds zero jit cache misses, and its replies carry the
+        same bytes as the same stream without one (timing and cycle-id
+        fields aside)."""
+        import jax
+
+        from koordinator_tpu.analysis import retrace_guard
+
+        def stream(profile: bool):
+            rng = np.random.RandomState(31)
+            state = _random_state(rng, n_nodes=5, n_pods=12,
+                                  with_quota=False)
+            sv = ScorerServicer()
+            sv.sync(_full_sync_request(state))
+            sv.state.snapshot()
+            self._warm_step(sv, state)  # warm-up compiles
+            replies = []
+            if profile:
+                jax.profiler.start_trace(str(tmp_path))
+            try:
+                with retrace_guard(budget=0) as counter:
+                    for _ in range(3):
+                        replies.append(self._warm_step(sv, state))
+            finally:
+                if profile:
+                    jax.profiler.stop_trace()
+            assert counter.traces == 0 and counter.compiles == 0
+            for r in replies:
+                r.cycle_ms = 0.0
+                r.cycle_id = ""
+            return [r.SerializeToString() for r in replies]
+
+        assert stream(profile=True) == stream(profile=False)
+
     def test_warm_mesh_sharded_stream_is_retrace_free(self):
         """ISSUE 7 acceptance: the MESH-SHARDED resident snapshot keeps
         the zero-retrace invariant per shard.  A warm delta-Sync /

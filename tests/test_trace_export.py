@@ -286,40 +286,9 @@ class TestAssembler:
         assert "assign.attempt [client]" in text
         assert "INCOMPLETE" not in text
 
-    def test_waterfall_device_split_renders(self, tmp_path):
-        # device-time truth (ISSUE 19): a launch span carrying the
-        # ledger's drained-note attrs splits its bar (# host, = device)
-        # and annotates dev= / compile=; the header totals the sampled
-        # device time across the trace
-        t = "g" * 32
-        launch = _span(t, "launch", "root", name="score_launch",
-                       kind="internal")
-        launch["durMs"] = 10.0
-        launch["endTimeUnixNano"] = int(10.0 * 1e6)
-        launch["attributes"] = {
-            "device_us": 4000.0, "compiled": True,
-            "compile_ms": 312.5, "flops": 1.5e9,
-        }
-        root = _span(t, "root", name="score")
-        root["durMs"] = 12.0
-        root["endTimeUnixNano"] = int(12.0 * 1e6)
-        _write_jsonl(tmp_path / "p.jsonl", [root, launch])
-        asm = assemble_mod.assemble([str(tmp_path)])
-        text = assemble_mod.render_waterfall(asm.traces[t])
-        assert "dev=4000.0us" in text
-        assert "compile=312.50ms" in text
-        # ~40% of the launch bar is the device share
-        assert "=" in text and "#" in text
-        launch_line = next(
-            ln for ln in text.splitlines() if "score_launch" in ln
-        )
-        assert "=" in launch_line
-        assert launch_line.index("#") < launch_line.index("=")
-        assert "device 4.000 ms sampled across 1 span(s)" in text
-
     def test_waterfall_without_device_attrs_unchanged(self, tmp_path):
-        # no ledger notes -> no device annotations anywhere (the
-        # sample=0 rendering is byte-stable vs pre-ISSUE-19 traces)
+        # the waterfall renders host spans only: no device annotations
+        # anywhere (byte-stable vs pre-ISSUE-19 traces)
         t = "h" * 32
         _write_jsonl(tmp_path / "p.jsonl", [
             _span(t, "root", name="score", kind="client"),
