@@ -51,8 +51,10 @@ from koordinator_tpu.model.snapshot import (
     GangTable,
     NodeBatch,
     PodBatch,
+    PriorityClass,
     QuotaTable,
     pad_bucket,
+    priority_class_column,
 )
 
 R = res.NUM_RESOURCES
@@ -160,14 +162,11 @@ def _pad_rows_to(a, rows):
 
 
 def _pc_column(explicit, priority, P, pb):
-    from koordinator_tpu.model.snapshot import PriorityClass
-
     col = np.full(pb, int(PriorityClass.NONE), np.int32)
     if explicit is not None:
         col[: len(explicit)] = explicit[:pb]
     else:
-        for i in range(P):
-            col[i] = int(PriorityClass.from_priority_value(int(priority[i])))
+        col[:P] = priority_class_column(priority[:P])
     return col
 
 
@@ -1017,9 +1016,7 @@ class ResidentState:
             )
         # gate on the plan's derived set: _warm_plan already diffed the
         # scalar columns, so an unchanged list riding the frame costs
-        # nothing here (the effective-class derivation below is an O(P)
-        # Python loop — it must not run on every priority-carrying Sync
-        # while the launch lock holds back Score/Assign)
+        # nothing here while the launch lock holds back Score/Assign
         new_fresh = staged.get("node_fresh")
         if "node_fresh" in derived and new_fresh is not None:
             new_fresh = np.asarray(new_fresh, bool)

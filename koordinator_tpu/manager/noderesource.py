@@ -25,6 +25,7 @@ from koordinator_tpu.manager.sloconfig import (
     ColocationStrategy,
 )
 from koordinator_tpu.model import resources as res
+from koordinator_tpu.model.snapshot import PriorityClass
 
 # dense axis for this module: [cpu (milli), memory (MiB)]
 CPU, MEM = 0, 1
@@ -34,12 +35,11 @@ PRIORITY_MID = "koord-mid"
 PRIORITY_BATCH = "koord-batch"
 PRIORITY_FREE = "koord-free"
 
-# Priority value bands (reference apis/extension/priority.go:38-49).
-PRIORITY_BANDS = {
-    PRIORITY_PROD: (9000, 9999),
-    PRIORITY_MID: (7000, 7999),
-    PRIORITY_BATCH: (5000, 5999),
-    PRIORITY_FREE: (3000, 3999),
+_CLASS_NAMES = {
+    PriorityClass.PROD: PRIORITY_PROD,
+    PriorityClass.MID: PRIORITY_MID,
+    PriorityClass.BATCH: PRIORITY_BATCH,
+    PriorityClass.FREE: PRIORITY_FREE,
 }
 
 
@@ -48,13 +48,13 @@ def priority_class_of(pod: Mapping) -> str:
     priority-class label wins, else derive from the numeric priority band,
     else prod (HP) by default."""
     pc = pod.get("priority_class", "")
-    if pc in PRIORITY_BANDS:
+    if pc in _CLASS_NAMES.values():
         return pc
     prio = pod.get("priority")
     if prio is not None:
-        for name, (lo, hi) in PRIORITY_BANDS.items():
-            if lo <= int(prio) <= hi:
-                return name
+        return _CLASS_NAMES.get(
+            PriorityClass.from_priority_value(int(prio)), PRIORITY_PROD
+        )
     return PRIORITY_PROD
 
 

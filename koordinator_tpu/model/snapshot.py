@@ -71,18 +71,45 @@ class PriorityClass(enum.IntEnum):
 
     @classmethod
     def from_priority_value(cls, priority: Optional[int]) -> "PriorityClass":
-        # reference apis/extension/priority.go:84-101
         if priority is None:
             return cls.NONE
-        if 9000 <= priority <= 9999:
-            return cls.PROD
-        if 7000 <= priority <= 7999:
-            return cls.MID
-        if 5000 <= priority <= 5999:
-            return cls.BATCH
-        if 3000 <= priority <= 3999:
-            return cls.FREE
+        for lo, hi, pc in PRIORITY_BANDS:
+            if lo <= priority <= hi:
+                return pc
         return cls.NONE
+
+
+# reference apis/extension/priority.go:84-101: inclusive priority-value
+# bands; a value in none of them is NONE.  The one table both the scalar
+# and the column derivation read.
+PRIORITY_BANDS = (
+    (9000, 9999, PriorityClass.PROD),
+    (7000, 7999, PriorityClass.MID),
+    (5000, 5999, PriorityClass.BATCH),
+    (3000, 3999, PriorityClass.FREE),
+)
+
+
+def _band_lookup() -> Tuple[np.ndarray, np.ndarray]:
+    """Sorted band edges and the class of each interval between them
+    (NONE outside every band), for ``np.searchsorted``."""
+    edges, classes = [], [int(PriorityClass.NONE)]
+    for lo, hi, pc in sorted(PRIORITY_BANDS):
+        edges += [lo, hi + 1]
+        classes += [int(pc), int(PriorityClass.NONE)]
+    return np.asarray(edges, np.int64), np.asarray(classes, np.int32)
+
+
+_BAND_EDGES, _BAND_CLASSES = _band_lookup()
+
+
+def priority_class_column(priority) -> np.ndarray:
+    """``PriorityClass.from_priority_value`` over a whole column of int64
+    priority values (array or list): one band lookup, int32 out."""
+    idx = np.searchsorted(
+        _BAND_EDGES, np.asarray(priority, np.int64), side="right"
+    )
+    return _BAND_CLASSES[idx]
 
 
 class QoSClass(enum.IntEnum):

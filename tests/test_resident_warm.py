@@ -24,7 +24,12 @@ import pytest
 
 from koordinator_tpu.bridge.codegen import pb2
 from koordinator_tpu.bridge.server import ScorerServicer
-from koordinator_tpu.bridge.state import ResidentState, numpy_to_tensor
+from koordinator_tpu.bridge.state import (
+    ResidentState,
+    _pc_column,
+    numpy_to_tensor,
+)
+from koordinator_tpu.model.snapshot import PriorityClass
 
 R = 13
 
@@ -184,6 +189,7 @@ def _results(sv: ScorerServicer):
         np.asarray(cycle.quota_used),
         np.asarray(scores)[:P, :N],
         np.asarray(feasible)[:P, :N],
+        np.asarray(snap.pods.priority_class)[:P],
     )
 
 
@@ -210,11 +216,18 @@ class TestWarmParityFuzz:
             want = _results(_cold_oracle(state))
             for g, w, name in zip(
                 got, want, ("assignment", "status", "quota_used",
-                            "scores", "feasible")
+                            "scores", "feasible", "priority_class")
             ):
                 np.testing.assert_array_equal(
                     g, w, err_msg=f"seed={seed} cycle={cycle} {name}"
                 )
+            # the resident class column after the priority churn equals
+            # the per-pod scalar derivation too, not only the cold build
+            prio = state["pod_priority"]
+            np.testing.assert_array_equal(
+                got[-1], _scalar_pc_column(None, prio, len(prio), len(prio)),
+                err_msg=f"seed={seed} cycle={cycle} scalar priority_class",
+            )
         assert warm_seen, "fuzz never exercised the warm device path"
 
     @pytest.mark.parametrize("seed", (0, 1))
@@ -400,6 +413,76 @@ class TestResidentMechanics:
         sv.sync(warm)  # no bucket fields on the wire
         assert sv.state.node_bucket == 5
         assert sv.state.last_sync_path == "warm"
+
+
+# every band edge of apis/extension/priority.go:84-101 and its class
+_EDGE_CLASSES = {
+    2999: PriorityClass.NONE, 3000: PriorityClass.FREE,
+    3999: PriorityClass.FREE, 4000: PriorityClass.NONE,
+    4999: PriorityClass.NONE, 5000: PriorityClass.BATCH,
+    5999: PriorityClass.BATCH, 6000: PriorityClass.NONE,
+    6999: PriorityClass.NONE, 7000: PriorityClass.MID,
+    7999: PriorityClass.MID, 8000: PriorityClass.NONE,
+    8999: PriorityClass.NONE, 9000: PriorityClass.PROD,
+    9999: PriorityClass.PROD, 10000: PriorityClass.NONE,
+}
+
+
+def _scalar_pc_column(explicit, priority, P, pb):
+    """The per-pod derivation ``_pc_column`` must equal, cell for cell."""
+    col = np.full(pb, int(PriorityClass.NONE), np.int32)
+    if explicit is not None:
+        col[: len(explicit)] = explicit[:pb]
+    else:
+        for i in range(P):
+            col[i] = int(PriorityClass.from_priority_value(int(priority[i])))
+    return col
+
+
+def _pc_case(name):
+    """(explicit, priority, P, pb) for one parity case."""
+    edges = np.asarray(list(_EDGE_CLASSES), np.int64)
+    if name == "band-edges":
+        return None, edges, len(edges), len(edges)
+    if name == "zero-and-negative":
+        prio = np.asarray([0, -1, -3000, -5500, -9999, -(2**63)], np.int64)
+        return None, prio, len(prio), len(prio)
+    if name == "past-int32":
+        prio = np.asarray(
+            [2**31 - 1, 2**31, 2**31 + 9500, 2**32 + 3000, -(2**31) - 1,
+             2**40, 2**63 - 1], np.int64,
+        )
+        return None, prio, len(prio), len(prio)
+    if name == "random-10k":
+        prio = np.random.RandomState(24).randint(-2000, 12000, 10000)
+        return None, prio.astype(np.int64), 10000, 16384
+    if name == "padding":
+        return None, edges[:5], 5, 8
+    if name == "explicit":
+        return np.asarray([0, 1, 2, 3, 4], np.int32), edges[:5], 5, 8
+    raise ValueError(name)
+
+
+class TestPriorityClassColumn:
+    def test_bands_match_koordinator(self):
+        for value, want in _EDGE_CLASSES.items():
+            assert PriorityClass.from_priority_value(value) is want, value
+        assert PriorityClass.from_priority_value(None) is PriorityClass.NONE
+
+    @pytest.mark.parametrize("container", ("ndarray", "list"))
+    @pytest.mark.parametrize("case", (
+        "band-edges", "zero-and-negative", "past-int32", "random-10k",
+        "padding", "explicit",
+    ))
+    def test_column_matches_scalar_derivation(self, case, container):
+        explicit, prio, P, pb = _pc_case(case)
+        if container == "list":
+            prio = [int(v) for v in prio]
+        got = _pc_column(explicit, prio, P, pb)
+        want = _scalar_pc_column(explicit, prio, P, pb)
+        assert got.dtype == np.int32 and got.shape == (pb,)
+        np.testing.assert_array_equal(got, want)
+        assert (got[P:] == int(PriorityClass.NONE)).all()
 
 
 class TestEpochContinuity:

@@ -39,6 +39,7 @@ from koordinator_tpu.bridge.codegen import pb2
 from koordinator_tpu.bridge.server import ScorerServicer
 from koordinator_tpu.bridge.state import numpy_to_tensor
 from koordinator_tpu.config import CycleConfig
+from koordinator_tpu.model.snapshot import PriorityClass
 from koordinator_tpu.parallel import cluster_mesh
 
 from test_resident_warm import (
@@ -240,21 +241,39 @@ class TestDirtyMechanics:
     def test_priority_churn_dirties_pods_whose_class_moved(self):
         """Raw priority feeds scoring only through the effective
         priority CLASS: a priority change inside the same band dirties
-        nothing, a band crossing dirties that pod's row."""
-        sv, state, _rng = self._servicer()
+        nothing, a band crossing dirties that pod's row.  Every pod but
+        pod 1 crosses into the next band; the dirty rows and the
+        resident class column equal the scalar derivation and the cold
+        build."""
+        sv, state, rng = self._servicer()
         _flat(sv)
-        prio = state["pod_priority"].copy()
-        prio[0] = 9500   # -> PROD band
-        prio[1] = prio[1] + 1 if prio[1] % 1000 < 900 else prio[1] - 1
+        old = state["pod_priority"]
+        old_cls = [PriorityClass.from_priority_value(int(v)) for v in old]
+        # one value base per class (NONE at 1000), indexed by class
+        base = (9000, 7000, 5000, 3000, 1000)
+        prio = np.asarray(
+            [base[(int(c) + 1) % len(base)] for c in old_cls], np.int64
+        ) + rng.randint(0, 1000, len(old))
+        prio[1] = old[1] + 1 if old[1] % 1000 < 900 else old[1] - 1
         state["pod_priority"] = prio
         req = pb2.SyncRequest()
         req.pods.priority.extend(int(v) for v in prio)
         sv.sync(req)
+        assert sv.state.last_sync_path == "warm"
+        new_cls = [PriorityClass.from_priority_value(int(v)) for v in prio]
+        moved = {i for i, (a, b) in enumerate(zip(old_cls, new_cls)) if a != b}
+        assert moved == set(range(len(old))) - {1}
         res = sv.state.score_residency()
         assert res is not None
-        assert 0 in res.dirty_pods or res.dirty_pods == set()
+        assert res.dirty_pods == moved
         oracle = ScorerServicer(score_memo=False, score_incr=False)
         oracle.sync(_full_sync_request(state))
+        P = len(prio)
+        resident = np.asarray(sv.state.snapshot().pods.priority_class)[:P]
+        np.testing.assert_array_equal(resident, np.asarray(new_cls, np.int32))
+        np.testing.assert_array_equal(
+            resident, np.asarray(oracle.state.snapshot().pods.priority_class)[:P]
+        )
         assert _flat(sv) == _flat(oracle)
 
     def test_ratio_gate_falls_back_to_full(self):
