@@ -5,8 +5,13 @@ are compared with ``reference.py`` on the very state each Sync carried:
 
 * Assign traffic: a sample of the window's cycles, drawn from the seed
   by reservoir sampling (every cycle equally likely, whatever their
-  number); every pod row of a sampled cycle is compared.  The number
-  compared is the count of pods placed differently (limit 0).
+  number); every pod row of a sampled cycle is compared.  Both sides
+  give bound answers: the program's is the node where its status is
+  ASSIGNED, else -1 (``Loop.cycle``); the reference's places Strict
+  gangs all or nothing.  Where no pod has a gang, every engine sets
+  ASSIGNED exactly when it places the pod, so this is the comparison of
+  placements.  The number compared is the count of pods whose bound
+  answers differ (limit 0).
 * ScoreFlat traffic: every cycle of the window.  The reference replays
   the window from the state of its first Sync, binding each pod where
   its own list puts it first, and the number compared is the count of
@@ -72,7 +77,7 @@ class AssignCheck:
             if self.control and prev is not None:
                 stale = dict(state, **{k: prev[k] for k in (
                     "node_requested", "node_usage", "pod_requests",
-                    "pod_estimated", "priority", "quota_id",
+                    "pod_estimated", "priority", "gang_id", "quota_id",
                     "quota_runtime", "quota_used", "quota_limited")
                     if k in prev})
                 got = reference.assign(stale, scoring)

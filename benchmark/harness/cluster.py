@@ -11,6 +11,17 @@ config 4 exactly as ``quota_colocation`` builds it
 
 Everything is dense numpy on the program's resource axis: int64 rows of
 ``R`` resources, cpu in milli-cores, byte resources in MiB.
+
+Coscheduling gangs.  A configuration may declare
+``"gangs": {"members": m, "min_member": k, "mode": "Strict"}``
+(Koordinator's gang annotations ``gang.scheduling.koordinator.sh/
+min-available`` and ``.../mode``).  Then every backlog row belongs to a
+gang: gang ``g`` is rows ``[g*m, (g+1)*m)`` with id ``g``, the backlog is
+a multiple of ``m``, and the gang table's min member is ``k`` for every
+gang, for the whole run.  A gang's members are one job's identical
+workers: one draw of (cpu, memory, BE coin, priority) per gang,
+repeated over its rows, and one ElasticQuota tenant.  Only Strict gangs
+that bind whole (``k == m``) are taken for now (``validate_gangs``).
 """
 
 from __future__ import annotations
@@ -103,32 +114,69 @@ def _pod_rows(spec, cpu, mem_mib, be, prio_draw):
     return req, lim, prio.astype(np.int64), pclass.astype(np.int64)
 
 
-def make_pods(rng, spec, count: int, prefix: str) -> Pods:
+def validate_gangs(config, traffic) -> None:
+    """Raises ValueError where the configuration's ``gangs`` block is one
+    this harness cannot run, or the traffic mix is not whole gangs."""
+    g = config.get("gangs")
+    if g is None:
+        return
+    backlog = int(traffic["backlog_pods"])
+    m, k, mode = g.get("members"), g.get("min_member"), g.get("mode")
+    if not isinstance(m, int) or m < 1:
+        raise ValueError(f"gangs: members must be a whole number >= 1, "
+                         f"not {m!r}")
+    if mode != "Strict":
+        raise ValueError(f"gangs: only mode 'Strict' is supported, not "
+                         f"{mode!r} (NonStrict waits for a configuration "
+                         f"that needs it)")
+    if k != m:
+        raise ValueError(f"gangs: only min_member == members is supported "
+                         f"(a gang binds whole), not min_member {k!r} with "
+                         f"members {m}")
+    if traffic["rpc"] != "assign":
+        raise ValueError(f"gangs: a gang is placed by Assign, not by "
+                         f"{traffic['rpc']!r} traffic")
+    if backlog % m:
+        raise ValueError(f"gangs: backlog_pods {backlog} is not a multiple "
+                         f"of members {m}")
+
+
+def make_pods(rng, spec, count: int, prefix: str, members: int = 1) -> Pods:
     """``_random_pods``, drawn pod by pod in the original's order (cpu,
-    memory, BE coin, priority), so seed 0 gives config 4's backlog."""
-    cpu = np.zeros(count, np.int64)
-    mem = np.zeros(count, np.int64)
-    be = np.zeros(count, bool)
-    draw = np.zeros(count, np.int64)
+    memory, BE coin, priority), so seed 0 gives config 4's backlog.
+    With ``members`` > 1 one draw is made per gang of that many rows."""
+    n = count // members
+    cpu = np.zeros(n, np.int64)
+    mem = np.zeros(n, np.int64)
+    be = np.zeros(n, bool)
+    draw = np.zeros(n, np.int64)
     ls_spread, be_spread = spec["ls"]["priority"][1], spec["be"]["priority"][1]
-    for i in range(count):
+    for i in range(n):
         cpu[i] = int(rng.choice(spec["cpu_milli"]))
         mem[i] = int(rng.choice(spec["memory_mib"]))
         be[i] = rng.uniform() < spec["be_share"]
         draw[i] = int(rng.randint(0, be_spread if be[i] else ls_spread))
-    req, lim, prio, pclass = _pod_rows(spec, cpu, mem, be, draw)
-    names = [f"{prefix}-{i}" for i in range(count)]
-    return Pods(req, lim, prio, pclass, np.full(count, -1, np.int64), names)
+    return _pods(spec, cpu, mem, be, draw, members, prefix, 0)
 
 
-def draw_pods(gen, spec, count: int, prefix: str, first: int) -> Pods:
+def draw_pods(gen, spec, count: int, prefix: str, first: int,
+              members: int = 1) -> Pods:
     """Arrivals after set-up, drawn from the same distribution in bulk
-    (``gen`` is a numpy Generator); names run on from ``first``."""
-    cpu = gen.choice(np.asarray(spec["cpu_milli"], np.int64), count)
-    mem = gen.choice(np.asarray(spec["memory_mib"], np.int64), count)
-    be = gen.random(count) < spec["be_share"]
+    (``gen`` is a numpy Generator); names run on from ``first``.  With
+    ``members`` > 1 one draw is made per gang of that many rows."""
+    n = count // members
+    cpu = gen.choice(np.asarray(spec["cpu_milli"], np.int64), n)
+    mem = gen.choice(np.asarray(spec["memory_mib"], np.int64), n)
+    be = gen.random(n) < spec["be_share"]
     spread = np.where(be, spec["be"]["priority"][1], spec["ls"]["priority"][1])
-    draw = (gen.random(count) * spread).astype(np.int64)
+    draw = (gen.random(n) * spread).astype(np.int64)
+    return _pods(spec, cpu, mem, be, draw, members, prefix, first)
+
+
+def _pods(spec, cpu, mem, be, draw, members, prefix, first) -> Pods:
+    """Pods from drawn columns, each draw repeated over ``members`` rows."""
+    cpu, mem, be, draw = (np.repeat(c, members) for c in (cpu, mem, be, draw))
+    count = len(cpu)
     req, lim, prio, pclass = _pod_rows(spec, cpu, mem, be, draw)
     names = [f"{prefix}-{first + i}" for i in range(count)]
     return Pods(req, lim, prio, pclass, np.full(count, -1, np.int64), names)

@@ -5,11 +5,16 @@ module reads its parameters and drives one ``ScorerClient`` through the
 served path.  Each cycle is one delta Sync followed by one request:
 
 * ``"rpc": "assign"`` — an Assign over a pending backlog of
-  ``backlog_pods`` rows.  The binder commits every placement into node
-  ``requested`` and the quota's used; as many bound pods depart, oldest
-  first (``"departures": "as_placed"``), so the fill stays where set-up
-  left it; new pods drawn from the configuration arrive in the rows of
-  the pods placed.
+  ``backlog_pods`` rows.  The answer kept is the bound answer: the node
+  of every pod whose status is ASSIGNED, else -1.  The binder commits
+  every bound pod into node ``requested`` and the quota's used; as many
+  bound pods depart, oldest first (``"departures": "as_placed"``), so
+  the fill stays where set-up left it; new pods drawn from the
+  configuration arrive in the rows of the pods bound.  Where the
+  configuration declares gangs (``cluster.validate_gangs``), every row
+  is a gang member: the binder binds a gang only when all its members
+  are ASSIGNED (Permit lets the group through together), and one
+  freshly drawn gang takes the rows of each gang bound.
 * ``"rpc": "score_flat"`` — ``ScoreFlat(top_k)`` for a one-pod table,
   the Go plugin's PreScore.  The binder puts the pod on its
   highest-scored node (the lowest index on ties); the next pod arrives.
@@ -29,6 +34,9 @@ import time
 import numpy as np
 
 from . import cluster as C
+
+# the wire's status of a pod that may bind (solver STATUS_ASSIGNED)
+ASSIGNED = 0
 
 # the arrays a Sync carries as delta-encoded tensors whose changed cells
 # the server scatters on the device
@@ -90,11 +98,19 @@ class Loop:
         self.nodes = C.make_nodes(rs, config["nodes"])
         n = len(self.nodes.names)
         backlog = int(traffic["backlog_pods"])
-        self.pods = C.make_pods(rs, config["pods"], backlog, "pod")
+        # rows a gang (the block is checked at load); 1 where every row
+        # is a pod of its own
+        self.gangs = config.get("gangs")
+        m = self.members = self.gangs["members"] if self.gangs else 1
+        self.pods = C.make_pods(rs, config["pods"], backlog, "pod", m)
+        self.gang_id = (np.arange(backlog) // m if self.gangs
+                        else np.full(backlog, -1, np.int64))
         self.quotas = None
         if config.get("quota"):
             self.quotas = C.make_quotas(rs, config["quota"], self.nodes.alloc)
-            self.pods.quota = np.arange(backlog) % config["quota"]["tenants"]
+            # a gang's members are of one tenant
+            self.pods.quota = (np.arange(backlog) // m
+                               % config["quota"]["tenants"])
         # the traffic's own stream: arrivals, departures, usage reports
         self.gen = np.random.default_rng([seed % 2**63, 1])
         self.serial = backlog
@@ -151,7 +167,7 @@ class Loop:
         if not len(rows):
             return
         new = C.draw_pods(self.gen, self.config["pods"], len(rows), "pod",
-                          self.serial)
+                          self.serial, self.members)
         self.serial += len(rows)
         p = self.pods
         p.requests[rows] = new.requests
@@ -192,7 +208,9 @@ class Loop:
         and arrive, take the usage reports."""
         if self.answer is not None:
             if self.rpc == "assign":
-                rows = np.flatnonzero(self.answer >= 0)
+                m = self.members
+                whole = (self.answer >= 0).reshape(-1, m).all(axis=1)
+                rows = np.flatnonzero(np.repeat(whole, m))
                 self._bind(self.answer[rows], self.pods.requests[rows],
                            self.pods.quota[rows])
                 if self.traffic["departures"] == "as_placed":
@@ -217,6 +235,7 @@ class Loop:
             "pod_estimated": self.pod_est,
             "priority": self.pods.priority,
             "quota_id": self.pods.quota,
+            "gang_id": self.gang_id,
         }
         if self.quotas is not None:
             s["quota_runtime"], s["quota_used"], s["quota_limited"] = (
@@ -225,8 +244,9 @@ class Loop:
 
     def sync_kwargs(self, state):
         """ScorerClient.sync keywords.  Node names and freshness ride the
-        first Sync only (the Go plugin omits them on deltas); pod names
-        and priorities ride every Sync, since pods change."""
+        first Sync only (the Go plugin omits them on deltas), and so
+        does the gang table, which is constant for the run; pod names,
+        priorities and gang ids ride every Sync, since pods change."""
         # copies: the client keeps the arrays it sent as its delta
         # baseline, and this loop updates its own in place
         kw = dict(
@@ -237,12 +257,15 @@ class Loop:
             pod_estimated=state["pod_estimated"].copy(),
             pod_names=list(self.pods.names),
             priority=state["priority"].tolist(),
-            gang_id=[-1] * len(self.pods),
+            gang_id=state["gang_id"].tolist(),
             quota_id=state["quota_id"].tolist(),
         )
         if not self.synced_once:
             kw["node_names"] = list(self.nodes.names)
             kw["metric_fresh"] = state["metric_fresh"].tolist()
+            if self.gangs:
+                kw["gang_min_member"] = (
+                    [self.members] * (len(self.pods) // self.members))
         if self.quotas is not None:
             kw["quota_runtime"] = state["quota_runtime"]
             kw["quota_used"] = state["quota_used"]
@@ -306,9 +329,11 @@ class Loop:
         rec = {"binder_ms": (t0 - tb) * 1e3, "sync_ms": (t1 - t0) * 1e3}
         if self.rpc == "assign":
             with note("bench.assign"):
-                assignment, _status, cycle_ms, path = client.assign()
+                assignment, status, cycle_ms, path = client.assign()
             t2 = time.perf_counter()
-            self.answer = np.asarray(assignment[:len(self.pods)], np.int64)
+            p = len(self.pods)
+            self.answer = np.where(np.asarray(status[:p]) == ASSIGNED,
+                                   np.asarray(assignment[:p], np.int64), -1)
             rec.update(rpc_ms=(t2 - t1) * 1e3, server_ms=float(cycle_ms),
                        path=path, placed=int((self.answer >= 0).sum()))
         else:

@@ -17,6 +17,20 @@ files state:
 * Assign: pods in queue order (priority descending, then row), each to
   its highest-scored feasible node, the lowest index on ties; its
   request and estimate then count on that node and its quota.
+* Coscheduling, Strict mode (Koordinator's gang scheduling proposal,
+  docs/proposals/scheduling/; coscheduling core.go rejectGangGroupById):
+  where the state carries ``gang_id`` (a pod's gang, -1 for none), a
+  gang member that finds no feasible node fails its whole gang.  PostFilter
+  rejects the gang's waiting members and Unreserve releases what they
+  reserved: every placement the gang made in this cycle is undone (node
+  requested, the cycle's assigned estimates, quota used), so later pods
+  see the freed capacity; the gang's schedule cycle is then invalid, so
+  its remaining members fail PreFilter and are skipped.  A gang binds
+  only with all its members placed (min member equal to the gang's
+  size, the only kind a configuration declares).  A gang's members
+  share a priority and sit in adjacent rows, so they are contiguous in
+  the queue.  A pod with no gang (no ``gang_id`` in the state, or -1)
+  is placed on its own, as above.
 * Score lists: every feasible node with its score, highest first, the
   lowest index on ties.
 
@@ -93,29 +107,53 @@ class Cycle:
                 ok[:] = False
         return ok
 
-    def place(self, node, req, est, quota):
-        self.requested[node] += req
-        self.assigned_est[node] += est
+    def place(self, node, req, est, quota, sign=1):
+        """Commit a placement (``sign`` -1 releases it)."""
+        self.requested[node] += sign * req
+        self.assigned_est[node] += sign * est
         if quota >= 0 and self.runtime is not None:
-            self.used[quota] += req
+            self.used[quota] += sign * req
 
 
 def assign(state, scoring) -> np.ndarray:
-    """Placements of every pod row of ``state``: node index or -1."""
+    """Bound answers of every pod row of ``state``: node index or -1."""
+    return run(state, scoring)[0]
+
+
+def run(state, scoring):
+    """The whole cycle over every pod row of ``state``.  Returns the
+    bound answers, the number of gangs undone partway (a member placed,
+    then one that fit nowhere), and the ``Cycle`` as it ends."""
     cyc = Cycle(state, scoring)
     req = np.asarray(state["pod_requests"], np.int64)
     est = np.asarray(state["pod_estimated"], np.int64)
     prio = np.asarray(state["priority"], np.int64)
     quota = np.asarray(state["quota_id"], np.int64)
     out = np.full(len(req), -1, np.int64)
+    gang = np.asarray(state.get("gang_id", np.full(len(req), -1)), np.int64)
+    placed = {}  # gang -> its rows placed in this cycle
+    failed = set()  # gangs whose schedule cycle is invalid
+    partway = 0
     for i in np.lexsort((np.arange(len(req)), -prio)):
+        g = int(gang[i])
+        if g in failed:
+            continue
         ok = cyc.feasible(req[i], quota[i])
         if not ok.any():
+            if g >= 0:
+                rows = placed.pop(g, [])
+                for r in rows:  # PostFilter rejects, Unreserve releases
+                    cyc.place(out[r], req[r], est[r], quota[r], sign=-1)
+                    out[r] = -1
+                failed.add(g)
+                partway += bool(rows)
             continue
         node = int(np.argmax(np.where(ok, cyc.scores(req[i], est[i]), -1)))
         out[i] = node
         cyc.place(node, req[i], est[i], quota[i])
-    return out
+        if g >= 0:
+            placed.setdefault(g, []).append(i)
+    return out, partway, cyc
 
 
 def score_list(state, scoring, pod: int = 0):

@@ -19,6 +19,8 @@ import json
 import os
 from typing import Dict, List, Optional
 
+from . import cluster
+
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -41,6 +43,8 @@ class Cell:
         with open(os.path.join(bench_dir, "traffic",
                                self.entry["traffic"] + ".json")) as fh:
             self.traffic = json.load(fh)
+        # a gangs block this harness cannot run fails here, at load
+        cluster.validate_gangs(self.config, self.traffic)
         self.bench_dir = bench_dir
 
     def metrics(self, kind: str) -> List[Dict]:

@@ -72,3 +72,20 @@ def test_quota_division_shares_what_is_left_by_weight():
     assert runtime[:, C.CPU].tolist() == [200, 400, 50]
     assert limited[:, C.CPU].tolist() == [1, 1, 1]
     assert not limited[:, C.MEMORY].any()
+
+
+def test_gang_draws_one_per_gang():
+    """One draw of (cpu, memory, BE coin, priority) per gang, repeated
+    over its rows: the gangs of 8 rows read as 8 pods drawn alone."""
+    spec = _colo2k()["pods"]
+    pairs = ((C.make_pods(np.random.RandomState(5), spec, 64, "p", 8),
+              C.make_pods(np.random.RandomState(5), spec, 8, "p")),
+             (C.draw_pods(np.random.default_rng(5), spec, 64, "p", 0, 8),
+              C.draw_pods(np.random.default_rng(5), spec, 8, "p", 0)))
+    for pods, alone in pairs:
+        for col, one in ((pods.requests, alone.requests),
+                         (pods.limits, alone.limits),
+                         (pods.priority, alone.priority),
+                         (pods.pclass, alone.pclass)):
+            np.testing.assert_array_equal(col, np.repeat(one, 8, axis=0))
+        assert len(set(pods.names)) == 64

@@ -4,6 +4,8 @@ files and BENCHMARK.json entries alone: the dummy ones under
 
 import os
 
+import pytest
+
 from benchmark.harness.spec import load_cell, read_metrics
 
 DUMMY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "dummy")
@@ -33,3 +35,32 @@ def test_a_reader_with_nothing_to_read_is_left_out():
     assert read_metrics(cell, "per_layer", Run()) == {}
     assert read_metrics(cell, "end_to_end", Run()) == {
         "pods_total": {"value": 1.0, "unit": "pods"}}
+
+
+def test_gang_config_runs_from_files_alone():
+    """A configuration with a ``gangs`` block (``dummy/configs/
+    tinygang.json``) runs through the unchanged harness: the Sync carries
+    its gang table, and every cycle binds whole gangs."""
+    import koordinator_tpu  # noqa: F401
+    from benchmark.run import run_cell
+
+    cell = load_cell(DUMMY, "tinygang.trickle", bench_dir=DUMMY)
+    assert cell.config["gangs"]["members"] == 8
+    res = run_cell(cell, 2**31 + 77, 0.5, False, out=lambda *a, **k: None)
+    assert res["correct"], res
+    assert res["checks"]["placement_mismatch"]["value"] == 0
+    placed = res["metrics"]["pods_total"]["value"]
+    assert placed >= 40 and placed % 8 == 0
+
+
+def test_unsupported_gang_config_fails_at_load(tmp_path):
+    import json
+    import shutil
+
+    shutil.copytree(DUMMY, tmp_path / "d")
+    path = tmp_path / "d" / "configs" / "tinygang.json"
+    cfg = json.loads(path.read_text())
+    cfg["gangs"]["mode"] = "NonStrict"
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(ValueError, match="only mode 'Strict'"):
+        load_cell(str(tmp_path / "d"), "tinygang.trickle", bench_dir=DUMMY)
