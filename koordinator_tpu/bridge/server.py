@@ -90,6 +90,7 @@ from koordinator_tpu.replication.admission import (
     ResourceExhausted,
 )
 from koordinator_tpu.solver import (
+    STATUS_WAIT_GANG,
     CandidateOverflow,
     build_candidates,
     masked_top_k,
@@ -1951,6 +1952,7 @@ class ScorerServicer:
         # queued behind other launches (the coalesce families carry
         # queueing)
         t0 = [0.0]
+        pod_gang = [None]
 
         @launch_section
         def launch():
@@ -1979,8 +1981,11 @@ class ScorerServicer:
                     self._check_generation(req, None)
                     snap = self.state.snapshot()
                     i32_ok = self.state.i32_fits()
+                    has_gangs = self.state.has_gangs()
+                    # the gang ids the cycle ran with, for its counters
+                    pod_gang[0] = self.state.pod_gang if has_gangs else None
                 result, rounds, eff_wave = self._assign_cycle(
-                    snap, scope, i32_ok
+                    snap, scope, i32_ok, has_gangs
                 )
 
             def _readback():
@@ -2051,6 +2056,7 @@ class ScorerServicer:
             )
             reply.assignment.extend(assignment[valid].tolist())
             reply.status.extend(status[valid].tolist())
+            self._count_gangs(status, pod_gang[0])
             # publish for concurrent waiters — on the OWNED entry: if a
             # Sync bumped the generation while the readback drained,
             # the dict slot is already gone (cleared under this very
@@ -2072,8 +2078,20 @@ class ScorerServicer:
             )
         return reply
 
+    def _count_gangs(self, status, pod_gang) -> None:
+        """The Strict gang counters of one Assign cycle, from the status
+        vector read back: members placed then released are WAIT_GANG,
+        and the gangs they belong to were rejected after placing."""
+        if pod_gang is None:
+            return
+        released = status[: len(pod_gang)] == STATUS_WAIT_GANG
+        if released.any():
+            self.telemetry.metrics.count_gang_rejections(
+                len(np.unique(pod_gang[released])), int(released.sum())
+            )
+
     @launch_section
-    def _assign_cycle(self, snap, spans, i32_ok):
+    def _assign_cycle(self, snap, spans, i32_ok, has_gangs=False):
         """Run the device cycle (shard-first when a mesh is configured)
         and return ``(CycleResult, rounds or None, effective wave
         width)`` — the shard path widens cfg.wave<=1 to its own
@@ -2088,7 +2106,10 @@ class ScorerServicer:
         # into extra_mask/extra_scores) — bit-identical placements, the
         # reply's path field shows what ran.  Score keeps its full mesh
         # path either way: the terms live INSIDE score_all.
-        if self.mesh is not None and not self._term_names:
+        # a snapshot with Strict gangs (``has_gangs``, from the mirrors)
+        # serves through run_cycle too: only the dense kernel and the
+        # scan roll a rejected gang back
+        if self.mesh is not None and not self._term_names and not has_gangs:
             from koordinator_tpu.parallel import greedy_assign_waves
 
             # the CycleConfig wave knobs thread through to the
@@ -2103,12 +2124,12 @@ class ScorerServicer:
                     wave=eff_wave, top_m=self.cfg.top_m, spans=spans,
                 )
             return result, rounds, eff_wave
-        result = run_cycle(snap, self.cfg, i32_ok=i32_ok)
+        result = run_cycle(snap, self.cfg, i32_ok=i32_ok, has_gangs=has_gangs)
         # device-derived wave count: returned UN-materialized (a device
         # scalar) — blocking on it here would hold the launch lock for
         # the whole cycle; the pipelined readback fetches it in the
         # same stacked device_get as assignment/status, off the lock
-        return result, result.rounds, self.cfg.wave
+        return result, result.rounds, 1 if has_gangs else self.cfg.wave
 
 
 def _handler(fn, req_cls, method: str, metrics):

@@ -356,6 +356,7 @@ class ResidentState:
         # store_candidates, advanced by warm commits, dropped cold
         self._cand_res: Optional[CandidateResidency] = None
         self._i32_ok: Optional[bool] = None
+        self._has_gangs: Optional[bool] = None
         # observability: how the last Sync landed on the device
         # ("cold" = residency dropped, rebuild at next snapshot();
         #  "warm" = resident tensors updated in place)
@@ -482,6 +483,7 @@ class ResidentState:
                 self._cand_res = None
                 self.last_sync_path = "cold"
         self._i32_ok = None
+        self._has_gangs = None
         kinds = [kind for kind, _, _ in tinfo.values()]
         return {
             "path": self.last_sync_path,
@@ -1072,34 +1074,43 @@ class ResidentState:
         """Whether the resident tensors fit the Pallas kernel's i32
         arithmetic — computed from the host-side numpy mirrors so the
         per-cycle device round-trip in solver.pallas_inputs_fit_i32 is
-        skipped on the Assign hot path."""
+        skipped on the Assign hot path (the same ``i32_summary``, so the
+        two checks agree on one state)."""
         if self._i32_ok is None:
-            from koordinator_tpu.solver import check_i32_bounds
+            from koordinator_tpu.solver import check_i32_bounds, i32_summary
 
-            zeros = np.zeros(1, np.int64)
+            def mirror(a, like=np.zeros((0, R), np.int64)):
+                return a if a is not None else np.zeros_like(like)
 
-            def amax(a):
-                return int(np.abs(a).max()) if a is not None and a.size else 0
-
+            P = self.pod_requests.shape[0]
             est = (
                 self.pod_estimated
                 if self.pod_estimated is not None
                 else self.pod_requests
             )
-            scored = max(
-                amax(self.node_alloc),
-                amax(self.node_requested),
-                amax(self.node_usage),
-                amax(self.pod_requests),
-                amax(est),
-            )
-            quota = max(amax(self.quota_runtime), amax(self.quota_used))
-            est_sum = int(
-                np.abs(est if est is not None else zeros).sum(axis=0).max()
-            )
-            req_sum = int(np.abs(self.pod_requests).sum(axis=0).max())
-            self._i32_ok = check_i32_bounds((scored, quota, est_sum, req_sum))
+            self._i32_ok = check_i32_bounds(i32_summary(
+                self.node_alloc,
+                mirror(self.node_requested, self.node_alloc),
+                mirror(self.node_usage, self.node_alloc),
+                self.pod_requests,
+                est,
+                np.ones(P, bool),
+                mirror(self.quota_runtime),
+                mirror(self.quota_used),
+                np,
+            ))
         return self._i32_ok
+
+    def has_gangs(self) -> bool:
+        """Whether any pod is a Strict gang member
+        (``constraints.gang.has_gang_members`` on the host mirrors), so
+        the Assign runs an engine with the gang rollback."""
+        if self._has_gangs is None:
+            from koordinator_tpu.constraints.gang import has_gang_members
+
+            gmin = self.gang_min if self.gang_min is not None else ()
+            self._has_gangs = has_gang_members(self.pod_gang, True, gmin, np)
+        return self._has_gangs
 
     def _pad2(self, a: np.ndarray, rows: int) -> np.ndarray:
         out = np.zeros((rows, a.shape[1]), np.int64)

@@ -29,6 +29,8 @@ koord_scorer_coalesce_requests_total   counter   —
 koord_scorer_coalesce_window_ms        gauge     —
 koord_scorer_coalesce_device_idle_ms   gauge     — (cumulative)
 koord_scorer_assign_memo_total         counter   result (hit|miss)
+koord_scorer_gang_rejected_total       counter   —
+koord_scorer_gang_released_pods_total  counter   —
 koord_scorer_score_memo_total          counter   result (hit|miss)
 koord_scorer_score_incr_total          counter   result (incr|full|fallback)
 koord_scorer_incr_cols                 histogram —
@@ -88,6 +90,11 @@ counts Assign RPCs served from the (snapshot id, CycleConfig) result
 memo vs. those that ran a device cycle; ``score_memo_total`` is the
 Score-side twin (ISSUE 7 satellite) — requests served as sliced
 prefixes of a memoized padded top-k readback vs. those that launched.
+``gang_rejected_total`` counts the Strict gangs an Assign cycle
+rejected after placing members (their placements undone; a gang whose
+first member already fit nowhere undoes nothing and is not counted),
+``gang_released_pods_total`` those members (status WAIT_GANG).  Both
+are counted on the host from the status vector the Assign reads back.
 
 The ``koord_scorer_shed_total`` and ``koord_scorer_replica_*`` families
 observe the replicated serving tier (ISSUE 8).  ``shed_total`` counts
@@ -139,6 +146,8 @@ COALESCE_REQUESTS = "koord_scorer_coalesce_requests_total"
 COALESCE_WINDOW = "koord_scorer_coalesce_window_ms"
 COALESCE_DEVICE_IDLE = "koord_scorer_coalesce_device_idle_ms"
 ASSIGN_MEMO = "koord_scorer_assign_memo_total"
+GANG_REJECTED = "koord_scorer_gang_rejected_total"
+GANG_RELEASED = "koord_scorer_gang_released_pods_total"
 SCORE_MEMO = "koord_scorer_score_memo_total"
 SCORE_INCR = "koord_scorer_score_incr_total"
 INCR_COLS = "koord_scorer_incr_cols"
@@ -244,6 +253,13 @@ _FAMILIES = (
     (ASSIGN_MEMO, "counter",
      "Assign RPCs served from the (snapshot, config) result memo (hit) "
      "vs. ran a device cycle (miss)"),
+    (GANG_REJECTED, "counter",
+     "Strict gangs an Assign cycle rejected after placing members: a "
+     "member fit nowhere before min member were placed, and every "
+     "placement the gang made was undone"),
+    (GANG_RELEASED, "counter",
+     "gang members an Assign cycle placed and then released when their "
+     "gang was rejected (reply status WAIT_GANG)"),
     (SCORE_MEMO, "counter",
      "Score requests served as sliced prefixes of the memoized "
      "(snapshot, config, k-bucket) top-k readback (hit) vs. launched "
@@ -550,6 +566,10 @@ class ScorerMetrics:
 
     def count_assign_memo(self, result: str) -> None:
         self.registry.counter_add(ASSIGN_MEMO, 1, {"result": result})
+
+    def count_gang_rejections(self, gangs: int, released: int) -> None:
+        self.registry.counter_add(GANG_REJECTED, int(gangs))
+        self.registry.counter_add(GANG_RELEASED, int(released))
 
     def count_score_memo(self, result: str, n: int = 1) -> None:
         self.registry.counter_add(SCORE_MEMO, int(n), {"result": result})

@@ -269,6 +269,8 @@ def _assign_sharded(
 
     Pcap = pods.capacity
     assignment = jnp.full((Pcap,), -1, jnp.int32).at[order].set(chosen_in_order)
+    # no Strict gang rollback in this engine (run_cycle and the bridge
+    # send it no gang): a gang short of minMember only WAITs here
     status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
     assigned = (assignment >= 0) & pods.valid
     _, pod_gang_ok = gang_satisfaction(
@@ -588,6 +590,8 @@ def _assign_waves(
 
     Pcap = pods.capacity
     assignment = jnp.full((Pcap,), -1, jnp.int32).at[order].set(chosen_in_order)
+    # no Strict gang rollback in this engine (run_cycle and the bridge
+    # send it no gang): a gang short of minMember only WAITs here
     status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
     assigned = (assignment >= 0) & pods.valid
     _, pod_gang_ok = gang_satisfaction(

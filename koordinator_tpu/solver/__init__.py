@@ -60,18 +60,64 @@ _I32_SCORED_LIMIT = 2**31 // 100
 _I32_QUOTA_LIMIT = 2**31 - 2**27
 
 
-def check_i32_bounds(maxima) -> bool:
-    """``maxima``: (scored_max, quota_max, est_sum_max, req_sum_max).
+def i32_summary(alloc, requested, usage, pod_requests, pod_estimated,
+                pod_valid, quota_runtime, quota_used, xp):
+    """The few numbers the i32 gate needs, from the node, pod and quota
+    tables as ``xp`` (numpy on the bridge's host mirrors, jax.numpy on
+    a snapshot, where it is one fused transfer): a vector of
+    [scored_max, quota_max, req_sum_max, slots_bound, est_max[R],
+    usage_max[R], est_sum[R]].  ``slots_bound`` is how many pods one node
+    can receive in a cycle, the largest free ``pods`` count of any node
+    plus one, where every valid pod requests a ``pods`` slot (Fit then
+    caps the slots), else -1."""
+    from koordinator_tpu.model import resources as res
 
-    Bounds the kernel's in-loop accumulators, not just its inputs: the
-    LoadAware term sums usage + all assigned pods' estimates on one node,
-    and a quota's used row sums every assigned request in the cycle, so
-    the worst-case cycle-end values must themselves fit i32."""
-    scored_max, quota_max, est_sum_max, req_sum_max = (int(v) for v in maxima)
+    slot = res.RESOURCE_INDEX[res.PODS]
+    est = xp.abs(pod_estimated)
+    takes_slot = xp.all(xp.where(pod_valid, pod_requests[:, slot] >= 1, True))
+    free = xp.max(alloc[:, slot] - requested[:, slot]) if alloc.shape[0] else 0
+    slots = xp.where(takes_slot, xp.maximum(free, 0) + 1, -1)
+
+    def amax(a):
+        return xp.max(xp.abs(a)) if a.size else xp.zeros((), xp.int64)
+
+    head = [
+        xp.max(xp.stack([amax(t) for t in (
+            alloc, requested, usage, pod_requests, pod_estimated)])),
+        xp.max(xp.stack([amax(quota_runtime), amax(quota_used)])),
+        xp.max(xp.sum(xp.abs(pod_requests), axis=0)),
+        slots,
+    ]
+    return xp.concatenate([
+        xp.stack([xp.asarray(v, xp.int64) for v in head]),
+        xp.max(est, axis=0, initial=0).astype(xp.int64),
+        xp.max(xp.abs(usage), axis=0, initial=0).astype(xp.int64),
+        xp.sum(est, axis=0).astype(xp.int64),
+    ])
+
+
+def check_i32_bounds(summary) -> bool:
+    """``summary``: ``i32_summary``'s vector.
+
+    Bounds the kernel's in-loop accumulators, not just its inputs.  The
+    LoadAware term on one node sums its usage, the estimates assigned to
+    it in the cycle and the scored pod's own: never more than every
+    pod's estimate, and at most ``slots_bound`` estimates where Fit caps
+    the pods a node takes.
+    A quota's used row sums every assigned request in the cycle.  The
+    worst-case cycle-end values must themselves fit i32."""
+    import numpy as np
+
+    v = np.asarray(summary, np.int64)
+    scored_max, quota_max, req_sum_max, slots = (int(x) for x in v[:4])
+    est_max, usage_max, est_sum = np.split(v[4:], 3)
+    load = usage_max + (
+        np.minimum(slots * est_max, est_sum) if slots >= 0 else est_sum
+    )
     return (
         scored_max < _I32_SCORED_LIMIT
         and quota_max + req_sum_max < _I32_QUOTA_LIMIT
-        and scored_max + est_sum_max < _I32_SCORED_LIMIT
+        and int(load.max()) < _I32_SCORED_LIMIT
     )
 
 
@@ -83,29 +129,16 @@ def pallas_inputs_fit_i32(snapshot) -> bool:
     import jax.numpy as jnp
     import numpy as np
 
-    scored = (
-        snapshot.nodes.allocatable,
-        snapshot.nodes.requested,
-        snapshot.nodes.usage,
-        snapshot.pods.requests,
-        snapshot.pods.estimated,
+    n, p, q = snapshot.nodes, snapshot.pods, snapshot.quotas
+    summary = i32_summary(
+        n.allocatable, n.requested, n.usage, p.requests, p.estimated,
+        p.valid, q.runtime, q.used, jnp,
     )
-    quota = (snapshot.quotas.runtime, snapshot.quotas.used)
-    # one fused device->host transfer for the whole check
-    maxima = np.asarray(
-        jnp.stack(
-            [
-                jnp.max(jnp.stack([jnp.max(jnp.abs(t)) for t in scored])),
-                jnp.max(jnp.stack([jnp.max(jnp.abs(t)) for t in quota])),
-                jnp.max(jnp.sum(jnp.abs(snapshot.pods.estimated), axis=0)),
-                jnp.max(jnp.sum(jnp.abs(snapshot.pods.requests), axis=0)),
-            ]
-        )
-    )
-    return check_i32_bounds(maxima)
+    return check_i32_bounds(np.asarray(summary))
 
 
-def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None, i32_ok=None):
+def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None,
+              i32_ok=None, has_gangs=None):
     """Backend-dispatched scheduling cycle.
 
     On TPU the dense-layout single-kernel Pallas cycle
@@ -127,6 +160,13 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None, i32_ok=Non
     ``i32_ok``: callers that already know whether the snapshot fits the
     kernel's i32 arithmetic (e.g. the bridge server, which checks host-side
     numpy mirrors at Sync time) pass it to skip the per-cycle device check.
+
+    Strict Coscheduling gangs (``constraints/gang.py``): only the dense
+    kernel and the scan roll a rejected gang back, so a snapshot with a
+    gang member (``has_gangs``, as ``i32_ok`` from the caller's mirrors,
+    else read off the device) never goes to the wide kernel or the wave
+    path, whatever ``cfg.wave`` asks: the dense kernel or the scan
+    serves it.
 
     Fused scoring terms (ISSUE 15): a ``cfg`` with term configs set
     materializes the registry's cellwise [P, N] tensors ONCE
@@ -172,9 +212,17 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None, i32_ok=Non
             if t_scores is not None:
                 scores_hi += terms_upper_bound(cfg)
         extras_ok = scores_hi < 2**29
+    if has_gangs is None:
+        from koordinator_tpu.constraints.gang import has_gang_members
+
+        has_gangs = has_gang_members(
+            snapshot.pods.gang_id, snapshot.pods.valid,
+            snapshot.gangs.min_member,
+        )
+    # the wave-batched engines have no gang rollback
+    wave = 1 if has_gangs else int(cfg.wave)
     variant = (
-        pallas_variant(snapshot.nodes.allocatable.shape[0], has_extras,
-                       int(cfg.wave))
+        pallas_variant(snapshot.nodes.allocatable.shape[0], has_extras, wave)
         if backend != "cpu" and extras_ok
         and (i32_ok if i32_ok is not None else pallas_inputs_fit_i32(snapshot))
         else None
@@ -189,9 +237,10 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None, i32_ok=Non
         from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
         return greedy_assign_dense(
-            snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores
+            snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores,
+            has_gangs=has_gangs,
         )
-    if cfg.wave > 1 and (scores_hi is None or scores_hi < 2**31):
+    if wave > 1 and (scores_hi is None or scores_hi < 2**31):
         # run_cycle never raises for in-contract inputs: extra_scores
         # beyond the packed-key range take the bit-identical scan below
         # instead of tripping wave_assign's magnitude guard

@@ -11,12 +11,18 @@ stateless "score every pending pod at once" tensor program for score-only
 parity and for the descheduler's candidate ranking.
 
 Queue order follows the Coscheduling QueueSort (``coscheduling.go:118``):
-higher priority first, then stable by submission index.
+higher priority first, then stable by submission index, with a gang's
+members adjacent at the place of its first member (``queue_order``).
 
-Gang all-or-nothing (Permit, ``coscheduling/core/core.go:308``): after the
-scan, gangs whose assigned-member count is below minMember have their pods
-marked WAIT_GANG — resources stay reserved within the cycle, exactly like
-waiting pods hold their reservations in the reference's Permit stage.
+Strict Coscheduling (``constraints/gang.py``; PostFilter
+``rejectGangGroupById`` and Unreserve, ``coscheduling/core/core.go``):
+the carry holds a checkpoint of (node_requested, node_estimated,
+quota_used) taken at each gang's first member.  A member that finds no
+feasible node while its gang has fewer than minMember placed restores
+the checkpoint, so later pods see the freed capacity, and the gang's
+remaining members are skipped; ``strict_post_pass`` then gives every
+member of a rejected gang assignment -1 (WAIT_GANG where it had been
+placed).  Every gang is Strict: the wire carries no mode.
 """
 
 from __future__ import annotations
@@ -30,7 +36,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from koordinator_tpu.config import CycleConfig, DEFAULT_CYCLE_CONFIG, MOST_ALLOCATED
-from koordinator_tpu.constraints.gang import gang_satisfaction
+from koordinator_tpu.constraints.gang import (
+    gang_opens,
+    strict_gangs,
+    strict_post_pass,
+)
 from koordinator_tpu.model.snapshot import ClusterSnapshot
 from koordinator_tpu.obs import devprof
 from koordinator_tpu.ops.fit import fit_mask, nonzero_requests
@@ -86,10 +96,35 @@ jax.tree_util.register_dataclass(
 )
 
 
-def queue_order(priority: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """Pod visit order: priority desc, stable by index; padding last."""
+def queue_order(
+    priority: jnp.ndarray,
+    valid: jnp.ndarray,
+    gang: Optional[jnp.ndarray] = None,
+    num_gangs: int = 0,
+) -> jnp.ndarray:
+    """Pod visit order: priority desc, stable by index; padding last.
+
+    With ``gang`` (i32[P], -1 = none, else below ``num_gangs``; from
+    ``constraints.gang.strict_gangs``) every member of gang g takes the
+    key (-priority of g's first member, g's first row, row), "first"
+    being the member that comes first in the order above, so a gang's
+    members are adjacent at its first member's place; a pod with no gang
+    keeps (-priority, row, row).  Where a gang's members already sit
+    together in the order above (one priority and adjacent rows, as one
+    job's workers are synced) the order is unchanged, and it is
+    Coscheduling's QueueSort (priority, then the PodGroup's creation,
+    then its id).  A mixed-priority gang's later members depart from
+    Koordinator's per-pod priority to travel with their first member."""
     key = jnp.where(valid, -priority.astype(jnp.int64), jnp.iinfo(jnp.int64).max)
-    return jnp.argsort(key, stable=True)
+    order = jnp.argsort(key, stable=True)
+    if gang is None:
+        return order
+    P = priority.shape[0]
+    rank = jnp.zeros((P,), jnp.int64).at[order].set(jnp.arange(P, dtype=jnp.int64))
+    slot = jnp.where(gang >= 0, gang, num_gangs)
+    first = jnp.full((num_gangs + 1,), P, jnp.int64).at[slot].min(rank)
+    lead = jnp.where(gang >= 0, first[slot], rank)
+    return jnp.argsort(lead * P + jnp.arange(P, dtype=jnp.int64))
 
 
 def _fit_score_requests(requests: jnp.ndarray) -> jnp.ndarray:
@@ -297,7 +332,10 @@ def greedy_assign(
     P = pods.capacity
     N = nodes.allocatable.shape[0]
 
-    order = queue_order(pods.priority, pods.valid)
+    G = gangs.min_member.shape[0]
+    gang, valid, need = strict_gangs(pods.gang_id, pods.valid, gangs.min_member)
+    order = queue_order(pods.priority, valid, gang, G)
+    opens = gang_opens(gang[order])
     score_requests = _fit_score_requests(pods.requests)
 
     mask_default, mask_prod = loadaware_node_masks(nodes, cfg)
@@ -312,8 +350,19 @@ def greedy_assign(
         or bool(dict(cfg.loadaware.prod_usage_thresholds))
     )
 
-    def step(state, p):
-        node_requested, node_estimated, quota_used = state
+    def step(state, x):
+        p, opens_p = x
+        cur, ck, placed, rejected = state
+        # Strict gangs: checkpoint at the gang's first member; a
+        # rejected gang's remaining members are skipped
+        ck = jax.tree_util.tree_map(
+            lambda c, v: jnp.where(opens_p, v, c), ck, cur
+        )
+        placed = jnp.where(opens_p, 0, placed)
+        rejected = jnp.where(opens_p, False, rejected)
+        in_gang = need[p] > 0
+        is_valid = valid[p] & ~(in_gang & rejected)
+        node_requested, node_estimated, quota_used = cur
         req = pods.requests[p]
         est = pods.estimated[p]
         qid = pods.quota_id[p]
@@ -342,7 +391,7 @@ def greedy_assign(
             score_requests[p],
             est,
             qid,
-            pods.valid[p],
+            is_valid,
             quotas.runtime,
             quotas.limited,
             cfg,
@@ -368,26 +417,28 @@ def greedy_assign(
             quota_used.at[q].add(req),
             quota_used,
         )
-        return (node_requested, node_estimated, quota_used), chosen
+        cur = (node_requested, node_estimated, quota_used)
+        # a member with no node before its gang reached minMember
+        # rejects the gang: Unreserve every placement it made
+        fail = in_gang & is_valid & ~any_feasible & (placed < need[p])
+        cur = jax.tree_util.tree_map(
+            lambda c, v: jnp.where(fail, c, v), ck, cur
+        )
+        placed = placed + (in_gang & any_feasible).astype(jnp.int32)
+        return (cur, ck, placed, rejected | fail), chosen
 
-    init = (nodes.requested, jnp.zeros_like(nodes.requested), quotas.used)
-    (node_requested, node_estimated, quota_used), chosen_in_order = lax.scan(
-        step, init, order
+    start = (nodes.requested, jnp.zeros_like(nodes.requested), quotas.used)
+    init = (start, start, jnp.int32(0), jnp.bool_(False))
+    ((node_requested, node_estimated, quota_used), *_), chosen_in_order = (
+        lax.scan(step, init, (order, opens))
     )
 
-    assignment = jnp.full((P,), -1, jnp.int32).at[order].set(chosen_in_order)
-    status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
-
-    # Gang all-or-nothing: a gang below minMember keeps its pods WAITing.
-    assigned = (assignment >= 0) & pods.valid
-    _, pod_gang_ok = gang_satisfaction(
-        assignment, pods.valid, pods.gang_id, gangs.min_member
-    )
-    status = jnp.where(assigned & ~pod_gang_ok, STATUS_WAIT_GANG, status)
+    chosen = jnp.full((P,), -1, jnp.int32).at[order].set(chosen_in_order)
+    assignment, status = strict_post_pass(chosen, gang, need, G)
 
     return CycleResult(
         assignment=assignment,
-        status=status.astype(jnp.int32),
+        status=status,
         node_requested=node_requested,
         node_estimated=node_estimated,
         quota_used=quota_used,

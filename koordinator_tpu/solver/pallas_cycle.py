@@ -991,6 +991,8 @@ def _greedy_assign_pallas(
         rounds = None
 
     assignment = jnp.full((P,), -1, jnp.int32).at[order].set(chosen[:P, 0])
+    # no Strict gang rollback in this engine (run_cycle and the bridge
+    # send it no gang): a gang short of minMember only WAITs here
     status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
     assigned = (assignment >= 0) & pods.valid
     _, pod_gang_ok = gang_satisfaction(

@@ -20,6 +20,20 @@ oracle mirroring the reference's sequential cycle,
 ``pkg/scheduler/frameworkext/framework_extender.go:192,216``); the same
 i32-soundness contract as the wide kernel applies (model/resources.py MiB
 units; dispatcher gates via pallas_inputs_fit_i32).
+
+Strict Coscheduling gangs (``constraints/gang.py``) compile a variant of
+the kernel (``has_gangs``, static; a snapshot with no gang member runs
+the kernel without it).  Its fourth scalar-prefetch operand holds each
+pod's gang word, 0 for a pod with no gang, else ``min_member << 1 |
+first``.  A gang-free pod runs the plain step behind a scalar gate.  A
+gang member runs the step with the gang's state, carried across grid
+steps in scratch: at its gang's first member the kernel checkpoints
+(node requested, assigned estimates, quota used) in VMEM and clears the
+gang's placed count and rejected flag; a rejected gang's member is
+skipped; a member with no feasible node while fewer than min member are
+placed restores the checkpoint and rejects its gang.  The rows of the
+members placed before are already written, so the XLA post-pass
+(``strict_post_pass``) gives every member of a rejected gang -1.
 """
 
 from __future__ import annotations
@@ -34,7 +48,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from koordinator_tpu.config import CycleConfig, DEFAULT_CYCLE_CONFIG, MOST_ALLOCATED
-from koordinator_tpu.constraints.gang import gang_satisfaction
+from koordinator_tpu.constraints.gang import (
+    gang_opens,
+    has_gang_members,
+    strict_gangs,
+    strict_post_pass,
+)
 from koordinator_tpu.model import resources as res
 from koordinator_tpu.model.snapshot import MAX_NODE_SCORE, ClusterSnapshot
 from koordinator_tpu.obs import devprof
@@ -47,7 +66,6 @@ from koordinator_tpu.ops.loadaware import (
 from koordinator_tpu.solver.greedy import (
     STATUS_ASSIGNED,
     STATUS_UNSCHEDULABLE,
-    STATUS_WAIT_GANG,
     CycleResult,
     queue_order,
 )
@@ -116,24 +134,29 @@ def _cycle_kernel_dense(
     qid_ref,  # i32[P]
     pvalid_ref,  # i32[P]
     pprod_ref,  # i32[P]
+    *refs,  # with has_gangs the gang words i32[P] (SMEM) first; then
     # inputs (VMEM) — all [RP, *] with nodes/pods/quotas on lanes
-    preq_ref,  # i32[RP, B]
-    psreq_ref,  # i32[RP, B]
-    pest_ref,  # i32[RP, B]
-    alloc_ref,  # i32[RP, N]
-    req0_ref,  # i32[RP, N] initial node-requested
-    usage_ref,  # i32[RP, N]; flag rows OK/FRESH/PROD_OK
-    qrt_ref,  # i32[RP, Qp]
-    qlim_ref,  # i32[RP, Qp]
-    quse0_ref,  # i32[RP, Qp]
-    w_ref,  # i32[RP, 128]: col 0 = fit weights, col 1 = loadaware weights
-    *rest,  # optional uprod_ref i32[RP, N]; optional xcomb_ref i32[B, N];
-    # then outputs (chosen_ref, nreq_ref, nest_ref, quse_ref)
+    #   preq, psreq, pest i32[RP, B]; alloc, req0 (initial node
+    #   requested), usage (flag rows OK/FRESH/PROD_OK) i32[RP, N];
+    #   qrt, qlim, quse0 i32[RP, Qp]; w i32[RP, 128] (col 0 fit weights,
+    #   col 1 loadaware weights); optional uprod i32[RP, N]; optional
+    #   xcomb i32[B, N]; then outputs (chosen, nreq, nest, quse); then,
+    #   with has_gangs, scratch (ck_nreq, ck_nest, ck_quse, gstate)
     block: int,
     cfg: CycleConfig,
     has_extras: bool,
     has_prod: bool,
+    has_gangs: bool,
 ):
+    if has_gangs:
+        pgang_ref, refs = refs[0], refs[1:]
+    (preq_ref, psreq_ref, pest_ref, alloc_ref, req0_ref, usage_ref, qrt_ref,
+     qlim_ref, quse0_ref, w_ref) = refs[:10]
+    rest = refs[10:]
+    if has_gangs:
+        rest, (ck_nreq_ref, ck_nest_ref, ck_quse_ref, gstate_ref) = (
+            rest[:-4], rest[-4:]
+        )
     if has_prod:
         uprod_ref = rest[0]
         rest = rest[1:]
@@ -168,14 +191,27 @@ def _cycle_kernel_dense(
     la_w_sum = sum(res.weights_vector(dict(cfg.loadaware.resource_weights)))
     recip = 1.0 / jnp.maximum(alloc, _i32(1)).astype(jnp.float32)
 
-    def step(j, _):
-        p = i * block + j
+    def pod_step(j, p, gang_word=None):
+        """One pod's Filter, Score and Reserve; ``gang_word`` (a scalar,
+        nonzero) runs it as a member of a Strict gang."""
         req = _onehot_col(preq_ref[:], j, block)  # [RP, 1]
         sreq = _onehot_col(psreq_ref[:], j, block)
         est = _onehot_col(pest_ref[:], j, block)
         qid = qid_ref[p]
         is_valid = pvalid_ref[p] != _i32(0)
         qidx = jnp.maximum(qid, _i32(0))
+        if gang_word is not None:
+
+            @pl.when((gang_word & _i32(1)) != _i32(0))
+            def _checkpoint():
+                ck_nreq_ref[:] = nreq_ref[:]
+                ck_nest_ref[:] = nest_ref[:]
+                ck_quse_ref[:] = quse_ref[:]
+                gstate_ref[:] = jnp.zeros_like(gstate_ref)
+
+            placed = gstate_ref[0:1, 0:1]  # [1, 1] members placed
+            # a rejected gang's remaining members are skipped
+            is_valid = is_valid & (gstate_ref[1:2, 0:1] == _i32(0))
         if has_prod:
             is_prod = pprod_ref[p] != _i32(0)
             node_ok_p = (
@@ -258,12 +294,54 @@ def _cycle_kernel_dense(
 
         # Reserve: one-hot-lane adds on the [RP, N] state
         commit_lane = (lane_iota == chosen) & any_feasible  # [1, N]
-        nreq_ref[:] = nreq + jnp.where(commit_lane, req, _i32(0))
-        nest_ref[:] = nest_ref[:] + jnp.where(commit_lane, est, _i32(0))
+        nreq_new = nreq + jnp.where(commit_lane, req, _i32(0))
+        nest_new = nest_ref[:] + jnp.where(commit_lane, est, _i32(0))
         quse_commit = qlane & any_feasible & (qid >= _i32(0))
-        quse_ref[:] = quse_ref[:] + jnp.where(quse_commit, req, _i32(0))
+        quse_new = quse_ref[:] + jnp.where(quse_commit, req, _i32(0))
+        if gang_word is not None:
+            # no node before the gang reached min member: Unreserve
+            # every placement it made, reject it
+            placed_now = jnp.where(any_feasible, _i32(1), _i32(0))
+            fail = jnp.where(
+                is_valid & (placed_now == _i32(0))
+                & (placed < (gang_word >> _i32(1))),
+                _i32(1),
+                _i32(0),
+            )  # [1, 1]
+            # Mosaic broadcasts along one axis at a time: lanes first
+            fail_n = jnp.broadcast_to(fail, (1, n_lanes)) != _i32(0)
+            fail_q = jnp.broadcast_to(fail, (1, q_lanes)) != _i32(0)
+            nreq_new = jnp.where(fail_n, ck_nreq_ref[:], nreq_new)
+            nest_new = jnp.where(fail_n, ck_nest_ref[:], nest_new)
+            quse_new = jnp.where(fail_q, ck_quse_ref[:], quse_new)
+            gstate_ref[0:1, :] = jnp.broadcast_to(
+                placed + placed_now, (1, LANES)
+            )
+            gstate_ref[1:2, :] = jnp.broadcast_to(
+                gstate_ref[1:2, 0:1] | fail, (1, LANES)
+            )
+        nreq_ref[:] = nreq_new
+        nest_ref[:] = nest_new
+        quse_ref[:] = quse_new
 
         chosen_ref[pl.ds(j, 1), :] = jnp.full((1, LANES), chosen, jnp.int32)
+
+    def step(j, _):
+        p = i * block + j
+        if not has_gangs:
+            pod_step(j, p)
+            return jnp.int32(0)
+        # the scalar gate: a gang-free pod runs the plain step
+        gang_word = pgang_ref[p]
+
+        @pl.when(gang_word == _i32(0))
+        def _plain():
+            pod_step(j, p)
+
+        @pl.when(gang_word != _i32(0))
+        def _member():
+            pod_step(j, p, gang_word)
+
         return jnp.int32(0)
 
     lax.fori_loop(jnp.int32(0), jnp.int32(block), step, jnp.int32(0))
@@ -273,7 +351,7 @@ def _cycle_kernel_dense(
 @partial(jax.jit, static_argnames=("cfg", "block", "interpret"))
 def _run_cycle_dense(
     preq, psreq, pest, qid, pvalid, pprod, alloc, req0, usage, qrt,
-    qlim, quse0, weights, uprod=None, xcomb=None, *,
+    qlim, quse0, weights, uprod=None, xcomb=None, pgang=None, *,
     cfg: CycleConfig, block: int, interpret: bool
 ):
     P = preq.shape[1]
@@ -281,6 +359,7 @@ def _run_cycle_dense(
     Qp = qrt.shape[1]
     has_extras = xcomb is not None
     has_prod = uprod is not None
+    has_gangs = pgang is not None
     grid = (P // block,)
     _z = np.int32(0)
     node_spec = pl.BlockSpec(
@@ -313,8 +392,19 @@ def _run_cycle_dense(
             pl.BlockSpec((block, N), lambda i, *_: (i, _z), memory_space=pltpu.VMEM)
         ]
         operands += [xcomb]
+    scalars = [qid, pvalid, pprod]
+    scratch = []
+    if has_gangs:
+        scalars += [pgang]
+        # the open gang's checkpoint and its (placed, rejected) words
+        scratch = [
+            pltpu.VMEM((RP, N), jnp.int32),
+            pltpu.VMEM((RP, N), jnp.int32),
+            pltpu.VMEM((RP, Qp), jnp.int32),
+            pltpu.VMEM((8, LANES), jnp.int32),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -325,6 +415,7 @@ def _run_cycle_dense(
             node_spec,
             quota_spec,
         ],
+        scratch_shapes=scratch,
     )
     kernel = partial(
         _cycle_kernel_dense,
@@ -332,6 +423,7 @@ def _run_cycle_dense(
         cfg=cfg,
         has_extras=has_extras,
         has_prod=has_prod,
+        has_gangs=has_gangs,
     )
     return pl.pallas_call(
         kernel,
@@ -343,7 +435,7 @@ def _run_cycle_dense(
             jax.ShapeDtypeStruct((RP, Qp), jnp.int32),
         ],
         interpret=interpret,
-    )(qid, pvalid, pprod, *operands)
+    )(*scalars, *operands)
 
 
 def _rows(a: jnp.ndarray, lanes: int) -> jnp.ndarray:
@@ -358,11 +450,16 @@ def greedy_assign_dense(
     interpret: bool = False,
     extra_mask=None,  # bool[P, N] extended-plugin Filter tensor
     extra_scores=None,  # i64[P, N] extended-plugin Score tensor
+    has_gangs=None,
 ) -> CycleResult:
     """Dense-layout drop-in for greedy_assign on TPU (path="pallas").
 
     Same i32-headroom guard as the wide kernel: extended scores must stay
     under 2^29 so the accumulation cannot wrap.
+
+    ``has_gangs``: whether any valid pod is a gang member, which selects
+    the kernel's Strict-gang variant; callers that know it from host
+    mirrors pass it, None reads it off the device.
     """
     if extra_scores is not None:
         peak = int(jnp.max(jnp.abs(extra_scores)))
@@ -371,17 +468,25 @@ def greedy_assign_dense(
                 f"extra_scores magnitude {peak} >= 2^29: out of the Pallas "
                 "kernel's i32 headroom; use the lax.scan path (greedy_assign)"
             )
-    return _greedy_assign_dense(snapshot, cfg, interpret, extra_mask, extra_scores)
+    if has_gangs is None:
+        has_gangs = has_gang_members(
+            snapshot.pods.gang_id, snapshot.pods.valid,
+            snapshot.gangs.min_member,
+        )
+    return _greedy_assign_dense(
+        snapshot, cfg, interpret, extra_mask, extra_scores, bool(has_gangs)
+    )
 
 
 @devprof.boundary("solver.pallas_dense._greedy_assign_dense")
-@partial(jax.jit, static_argnames=("cfg", "interpret"))
+@partial(jax.jit, static_argnames=("cfg", "interpret", "has_gangs"))
 def _greedy_assign_dense(
     snapshot: ClusterSnapshot,
     cfg: CycleConfig = DEFAULT_CYCLE_CONFIG,
     interpret: bool = False,
     extra_mask=None,
     extra_scores=None,
+    has_gangs: bool = False,
 ) -> CycleResult:
     pods, nodes, gangs, quotas = (
         snapshot.pods,
@@ -392,10 +497,23 @@ def _greedy_assign_dense(
     P = pods.capacity
     N = nodes.allocatable.shape[0]
 
-    order = queue_order(pods.priority, pods.valid)
     P_pad = -(-P // 128) * 128
     block = 128
     N_pad = -(-N // LANES) * LANES  # nodes ride the lane axis now
+    valid = pods.valid
+    pgang = None
+    if has_gangs:
+        G = gangs.min_member.shape[0]
+        gang, valid, need = strict_gangs(pods.gang_id, valid, gangs.min_member)
+        order = queue_order(pods.priority, valid, gang, G)
+        word = jnp.where(
+            gang[order] >= 0,
+            need[order] * 2 + gang_opens(gang[order]).astype(jnp.int32),
+            0,
+        )
+        pgang = jnp.pad(word.astype(jnp.int32), (0, P_pad - P))
+    else:
+        order = queue_order(pods.priority, valid)
 
     def _pods(a):
         return _rows(a[order], P_pad)
@@ -404,7 +522,7 @@ def _greedy_assign_dense(
     psreq = _pods(nonzero_requests(pods.requests))
     pest = _pods(pods.estimated)
     qid = jnp.pad(pods.quota_id[order].astype(jnp.int32), (0, P_pad - P))
-    pvalid = jnp.pad(pods.valid[order].astype(jnp.int32), (0, P_pad - P))
+    pvalid = jnp.pad(valid[order].astype(jnp.int32), (0, P_pad - P))
 
     mask_default, mask_prod = loadaware_node_masks(nodes, cfg)
     if not cfg.enable_loadaware:
@@ -486,18 +604,19 @@ def _greedy_assign_dense(
         weights,
         uprod,
         xcomb,
+        pgang,
         cfg=cfg,
         block=block,
         interpret=interpret,
     )
 
     assignment = jnp.full((P,), -1, jnp.int32).at[order].set(chosen[:P, 0])
-    status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
-    assigned = (assignment >= 0) & pods.valid
-    _, pod_gang_ok = gang_satisfaction(
-        assignment, pods.valid, pods.gang_id, gangs.min_member
-    )
-    status = jnp.where(assigned & ~pod_gang_ok, STATUS_WAIT_GANG, status)
+    if has_gangs:
+        assignment, status = strict_post_pass(assignment, gang, need, G)
+    else:
+        status = jnp.where(
+            assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE
+        )
 
     R = res.NUM_RESOURCES
     nq = quotas.used.shape[0]

@@ -396,9 +396,11 @@ def _wave_assign(
     pod's global top-``top_m`` packed keys via ``lax.top_k``, and runs
     the shared ``resolve_wave`` certification; the committed prefix
     lands on the carried node/quota state and the pointer advances by
-    its length.  Bit-identical with ``greedy_assign`` (same packed-key
-    tie-break, same WAIT_GANG semantics, same ElasticQuota admission
-    order); parity fuzzed in tests/test_parity_fuzz.py.
+    its length.  Bit-identical with ``greedy_assign`` on gang-free
+    snapshots (same packed-key tie-break, same ElasticQuota admission
+    order); parity fuzzed in tests/test_parity_fuzz.py.  It has no
+    Strict gang rollback, so ``run_cycle`` serves a snapshot with gangs
+    on the dense kernel or the scan.
     """
     pods, nodes, gangs, quotas = (
         snapshot.pods,
@@ -584,6 +586,8 @@ def _wave_assign(
     assignment = (
         jnp.full((PCAP,), -1, jnp.int32).at[order].set(chosen_buf[:PCAP])
     )
+    # no Strict gang rollback in this engine (run_cycle and the bridge
+    # send it no gang): a gang short of minMember only WAITs here
     status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
     assigned = (assignment >= 0) & pods.valid
     _, pod_gang_ok = gang_satisfaction(

@@ -5,7 +5,8 @@ described, unattached ``v5e:2x2`` topology, which raises what the chip's
 compiler would raise (an unsupported 64-bit type inside a kernel, more
 VMEM than a kernel may use, a program that does not fit HBM).  Shapes
 are the served buckets of BASELINE.json config 4: 10,000 pods in the
-16,384 pod bucket, 2,000 nodes in the 2,048 node bucket.  The dense
+16,384 pod bucket, 2,000 nodes in the 2,048 node bucket (a smaller node
+bucket holds five pods a node).  The dense
 kernel is also compiled at the node-bucket limits run_cycle routes by
 (``solver.DENSE_MAX_NODES``), and refused just past them.
 
@@ -57,10 +58,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _spec(one_chip, node_bucket=NODE_BUCKET):
+def _spec(one_chip, node_bucket=NODE_BUCKET, pod_bucket=POD_BUCKET):
+    nodes = min(2_000, node_bucket)
     snap = generators.quota_colocation_snapshot(
-        pods=10_000, nodes=2_000,
-        node_bucket=node_bucket, pod_bucket=POD_BUCKET,
+        pods=nodes * 5, nodes=nodes,
+        node_bucket=node_bucket, pod_bucket=pod_bucket,
     )[0]
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -68,17 +70,17 @@ def _spec(one_chip, node_bucket=NODE_BUCKET):
     )
 
 
-def _dense(one_chip, node_bucket, extras):
+def _dense(one_chip, node_bucket, extras, gangs=False, pod_bucket=POD_BUCKET):
     from koordinator_tpu.solver.pallas_dense import _greedy_assign_dense
 
-    spec = _spec(one_chip, node_bucket)
-    cells = (POD_BUCKET, node_bucket)
+    spec = _spec(one_chip, node_bucket, pod_bucket)
+    cells = (pod_bucket, node_bucket)
     mask = scores = None
     if extras:
         mask = jax.ShapeDtypeStruct(cells, bool, sharding=one_chip)
         scores = jax.ShapeDtypeStruct(cells, "int64", sharding=one_chip)
     return _greedy_assign_dense.__wrapped__.lower(
-        spec, DEFAULT_CYCLE_CONFIG, False, mask, scores
+        spec, DEFAULT_CYCLE_CONFIG, False, mask, scores, gangs
     ).compile()
 
 
@@ -93,6 +95,16 @@ def test_dense_kernel_at_node_limit(one_chip, extras):
 
     limit = solver.DENSE_MAX_NODES_EXTRAS if extras else solver.DENSE_MAX_NODES
     compiled = _dense(one_chip, limit, extras)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("node_bucket,pod_bucket", [(512, 8192),
+                                                    (16384, POD_BUCKET)])
+def test_dense_gang_kernel_compiles(one_chip, node_bucket, pod_bucket):
+    """The Strict-gang variant (its checkpoint scratch and scalar gate)
+    at the gang cell's buckets and at the dense node limit."""
+    compiled = _dense(one_chip, node_bucket, extras=False, gangs=True,
+                      pod_bucket=pod_bucket)
     assert "tpu_custom_call" in compiled.as_text()
 
 

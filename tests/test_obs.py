@@ -34,13 +34,15 @@ from koordinator_tpu.obs.spans import MAX_SPANS_PER_CYCLE, SpanRecorder
 from test_resident_warm import _full_sync_request, _random_state
 
 
-def _servicer(tmp=None, cfg=None):
+def _servicer(tmp=None, cfg=None, gang_free=False):
     kwargs = {"state_dir": tmp} if tmp else {}
     if cfg is not None:
         kwargs["cfg"] = cfg
     sv = ScorerServicer(**kwargs)
     rng = np.random.RandomState(3)
     state = _random_state(rng, n_nodes=4, n_pods=8, with_quota=False)
+    if gang_free:
+        state["pod_gang"][:] = -1
     reply = sv.sync(_full_sync_request(state))
     return sv, state, reply
 
@@ -370,8 +372,9 @@ class TestServicerTelemetry:
     def test_wave_cycle_notes_rounds(self, tmp_path):
         from koordinator_tpu.config import CycleConfig
 
+        # a snapshot with Strict gangs is served off the wave path
         sv, state, reply = _servicer(
-            str(tmp_path), cfg=CycleConfig(wave=4, top_m=2)
+            str(tmp_path), cfg=CycleConfig(wave=4, top_m=2), gang_free=True
         )
         sv.assign(pb2.AssignRequest(snapshot_id=reply.snapshot_id))
         rec = sv.telemetry.flight.snapshot()[-1]

@@ -610,6 +610,8 @@ class TestRetraceBudget:
 
         rng = np.random.RandomState(23)
         state = _random_state(rng, n_nodes=5, n_pods=12, with_quota=False)
+        # a snapshot with Strict gangs is served off the wave path
+        state["pod_gang"][:] = -1
         sv = ScorerServicer(CycleConfig(wave=8, top_m=2))
         sv.sync(_full_sync_request(state))
         sv.state.snapshot()
@@ -725,6 +727,8 @@ class TestRetraceBudget:
             pytest.skip("needs 8 (virtual) devices")
         rng = np.random.RandomState(37)
         state = _random_state(rng, n_nodes=5, n_pods=12, with_quota=False)
+        # a snapshot with Strict gangs is served off the shard path
+        state["pod_gang"][:] = -1
         sv = ScorerServicer(
             mesh=cluster_mesh(jax.devices()), mesh_resident=True
         )

@@ -120,7 +120,9 @@ def test_priority_order_wins_contention():
 
 
 def test_gang_wait_status():
-    # gang of 3 but only capacity for 2 -> assigned members flip to WAIT_GANG
+    # gang of 3 but only capacity for 2 -> Strict: the third member
+    # rejects the gang, the two placed are released (-1, WAIT_GANG) and
+    # their requests leave the node
     nodes = [{"name": "n", "allocatable": {"cpu": "2", "memory": "16Gi"}, "usage": {}}]
     gangs = [{"name": "g", "min_member": 3}]
     pods = [
@@ -130,9 +132,12 @@ def test_gang_wait_status():
     snap = encode_snapshot(nodes, pods, gangs)
     result = greedy_assign(snap)
     status = np.asarray(result.status)[:3]
-    assert (np.asarray(result.assignment)[:3] >= 0).sum() == 2
+    assert (np.asarray(result.assignment)[:3] == -1).all()
     assert (status == STATUS_WAIT_GANG).sum() == 2
     assert (status == STATUS_UNSCHEDULABLE).sum() == 1
+    np.testing.assert_array_equal(
+        np.asarray(result.node_requested), np.asarray(snap.nodes.requested)
+    )
 
 
 def test_gang_satisfied_all_assigned():
