@@ -1992,9 +1992,9 @@ class ScorerServicer:
                 # blocking stacked transfer — OFF the launch lock, so a
                 # coalesced Score batch can launch while it drains.
                 # ``rounds`` rides the same stacked device_get: a
-                # device scalar (single-chip wave path), a host int
-                # (shard path) or None — device_get passes the last
-                # two through.
+                # device scalar (single-chip wave path, dense kernel),
+                # a host int (shard path) or None (scan) — device_get
+                # passes the last two through.
                 with scope.span("readback"):
                     assignment, status, valid, got_rounds = jax.device_get(
                         (result.assignment, result.status,
@@ -2125,10 +2125,11 @@ class ScorerServicer:
                 )
             return result, rounds, eff_wave
         result = run_cycle(snap, self.cfg, i32_ok=i32_ok, has_gangs=has_gangs)
-        # device-derived wave count: returned UN-materialized (a device
-        # scalar) — blocking on it here would hold the launch lock for
-        # the whole cycle; the pipelined readback fetches it in the
-        # same stacked device_get as assignment/status, off the lock
+        # device-derived step count (wave rounds, dense kernel pod
+        # steps): returned UN-materialized (a device scalar) — blocking
+        # on it here would hold the launch lock for the whole cycle; the
+        # pipelined readback fetches it in the same stacked device_get
+        # as assignment/status, off the lock
         return result, result.rounds, 1 if has_gangs else self.cfg.wave
 
 

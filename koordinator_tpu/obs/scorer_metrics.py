@@ -75,6 +75,12 @@ koord_scorer_prewarm_compile_ms_total  counter   —
 koord_scorer_prewarm_pending           gauge     —
 ====================================== ========= ==========================
 
+``cycle_rounds`` and ``rounds_total`` count the sequential device steps
+of a cycle, by path: a wave cycle's rounds (``path="wave"``, ``"shard"``)
+and the dense kernel's pod steps (``path="pallas"``), one per valid pod,
+its padding rows not run; rounds over the pod bucket is the share of
+the kernel's rows that ran.  The scan reports none.
+
 The ``koord_scorer_coalesce_*`` families observe the coalescing
 dispatch engine (ISSUE 5/6, bridge/coalesce.py): how long a Score
 request waited in the gather queue before its batch launched, and how
@@ -208,9 +214,12 @@ _FAMILIES = (
      "end-to-end Assign/Score cycle latency on the bridge, by device "
      "path and wave width"),
     (CYCLE_ROUNDS, "gauge",
-     "sequential device rounds of the last wave-batched cycle (~P/wave "
-     "certified-prefix rounds vs P per-pod steps)"),
-    (ROUNDS_TOTAL, "counter", "cumulative wave-cycle rounds, by path"),
+     "sequential device steps of the last cycle: wave rounds (~P/wave "
+     "certified-prefix rounds vs P per-pod steps), or the dense "
+     "kernel's pod steps, one per valid pod"),
+    (ROUNDS_TOTAL, "counter",
+     "cumulative sequential device steps (wave rounds, dense kernel pod "
+     "steps), by path"),
     (CYCLES_TOTAL, "counter", "completed scoring cycles, by device path"),
     (CYCLE_ERRORS, "counter", "cycles that raised, by pipeline stage"),
     (SYNC_TOTAL, "counter",

@@ -68,10 +68,11 @@ class CycleResult:
     node_requested: Optional[jnp.ndarray] = None  # i64[N, R] post-cycle
     node_estimated: Optional[jnp.ndarray] = None  # i64[N, R] post-cycle
     quota_used: Optional[jnp.ndarray] = None  # i64[Q, R] post-cycle
-    # sequential round count of the wave-batched paths (solver/wave.py,
-    # the wave Pallas kernel, parallel/shard_assign.py): ~P/wave-prefix
-    # rounds vs P scan steps — surfaced so bench.py can publish the win;
-    # None on the per-pod paths
+    # sequential steps the device ran: the wave-batched paths'
+    # (solver/wave.py, the wave Pallas kernel, parallel/shard_assign.py)
+    # rounds, ~P/wave-prefix vs P scan steps; the dense kernel's pod
+    # steps, one per valid pod (its padding rows are not run); None on
+    # the scan
     rounds: Optional[jnp.ndarray] = None
     # which code path produced the result ("pallas" single-kernel cycle,
     # "scan" lax.scan, "wave" round-based single chip, "shard" multi-chip

@@ -34,6 +34,15 @@ skipped; a member with no feasible node while fewer than min member are
 placed restores the checkpoint and rejects its gang.  The rows of the
 members placed before are already written, so the XLA post-pass
 (``strict_post_pass``) gives every member of a rejected gang -1.
+
+The kernel runs the rows of the valid pods alone.  Their count, a
+scalar-prefetch operand (data: a backlog of another size runs the same
+compiled kernel), bounds the last block's row loop, and a grid step
+past it does no vector work, so every row run is a valid pod and the
+kernel takes no per-row valid flag; every step first fills its ``chosen``
+block with -1, so a row not run reads as an infeasible one.  This rests
+on ``queue_order`` putting every valid pod before every invalid one.
+The count is the result's ``rounds``: the pod steps run.
 """
 
 from __future__ import annotations
@@ -132,8 +141,8 @@ def _onehot_col(tile, j, width):
 def _cycle_kernel_dense(
     # scalar prefetch (SMEM)
     qid_ref,  # i32[P]
-    pvalid_ref,  # i32[P]
     pprod_ref,  # i32[P]
+    nvalid_ref,  # i32[1]: the valid pods, rows [0, n_valid) of the order
     *refs,  # with has_gangs the gang words i32[P] (SMEM) first; then
     # inputs (VMEM) — all [RP, *] with nodes/pods/quotas on lanes
     #   preq, psreq, pest i32[RP, B]; alloc, req0 (initial node
@@ -177,180 +186,196 @@ def _cycle_kernel_dense(
         nest_ref[:] = jnp.zeros_like(nest_ref)
         quse_ref[:] = quse0_ref[:]
 
-    alloc = alloc_ref[:]
-    n_lanes = alloc.shape[1]
-    q_lanes = quse0_ref.shape[1]
-    node_ok = usage_ref[FLAG_ROW_OK : FLAG_ROW_OK + 1, :] != _i32(0)
-    fresh = usage_ref[FLAG_ROW_FRESH : FLAG_ROW_FRESH + 1, :] != _i32(0)
-    lane_iota = lax.broadcasted_iota(jnp.int32, (1, n_lanes), 1)
-    qlane_iota = lax.broadcasted_iota(jnp.int32, (1, q_lanes), 1)
-
-    fit_w_col = w_ref[:, 0:1]
-    la_w_col = w_ref[:, 1:2]
+    # a row not run reads as an infeasible one; queue_order puts every
+    # valid pod before the padding, so rows [0, n_valid) are all run
+    chosen_ref[:] = jnp.full(chosen_ref.shape, -1, jnp.int32)
+    first = i * block
+    n_valid = nvalid_ref[0]
     fit_w_sum = sum(res.weights_vector(dict(cfg.fit_resource_weights)))
     la_w_sum = sum(res.weights_vector(dict(cfg.loadaware.resource_weights)))
-    recip = 1.0 / jnp.maximum(alloc, _i32(1)).astype(jnp.float32)
 
-    def pod_step(j, p, gang_word=None):
-        """One pod's Filter, Score and Reserve; ``gang_word`` (a scalar,
-        nonzero) runs it as a member of a Strict gang."""
-        req = _onehot_col(preq_ref[:], j, block)  # [RP, 1]
-        sreq = _onehot_col(psreq_ref[:], j, block)
-        est = _onehot_col(pest_ref[:], j, block)
-        qid = qid_ref[p]
-        is_valid = pvalid_ref[p] != _i32(0)
-        qidx = jnp.maximum(qid, _i32(0))
-        if gang_word is not None:
+    @pl.when(first < n_valid)
+    def _valid_rows():
+        alloc = alloc_ref[:]
+        n_lanes = alloc.shape[1]
+        q_lanes = quse0_ref.shape[1]
+        node_ok = usage_ref[FLAG_ROW_OK : FLAG_ROW_OK + 1, :] != _i32(0)
+        fresh = usage_ref[FLAG_ROW_FRESH : FLAG_ROW_FRESH + 1, :] != _i32(0)
+        lane_iota = lax.broadcasted_iota(jnp.int32, (1, n_lanes), 1)
+        qlane_iota = lax.broadcasted_iota(jnp.int32, (1, q_lanes), 1)
 
-            @pl.when((gang_word & _i32(1)) != _i32(0))
-            def _checkpoint():
-                ck_nreq_ref[:] = nreq_ref[:]
-                ck_nest_ref[:] = nest_ref[:]
-                ck_quse_ref[:] = quse_ref[:]
-                gstate_ref[:] = jnp.zeros_like(gstate_ref)
+        fit_w_col = w_ref[:, 0:1]
+        la_w_col = w_ref[:, 1:2]
+        recip = 1.0 / jnp.maximum(alloc, _i32(1)).astype(jnp.float32)
 
-            placed = gstate_ref[0:1, 0:1]  # [1, 1] members placed
-            # a rejected gang's remaining members are skipped
-            is_valid = is_valid & (gstate_ref[1:2, 0:1] == _i32(0))
-        if has_prod:
-            is_prod = pprod_ref[p] != _i32(0)
-            node_ok_p = (
-                jnp.where(
-                    is_prod,
-                    usage_ref[FLAG_ROW_PROD_OK : FLAG_ROW_PROD_OK + 1, :],
-                    usage_ref[FLAG_ROW_OK : FLAG_ROW_OK + 1, :],
+        def pod_step(j, p, gang_word=None):
+            """One pod's Filter, Score and Reserve; ``gang_word`` (a scalar,
+            nonzero) runs it as a member of a Strict gang."""
+            req = _onehot_col(preq_ref[:], j, block)  # [RP, 1]
+            sreq = _onehot_col(psreq_ref[:], j, block)
+            est = _onehot_col(pest_ref[:], j, block)
+            qid = qid_ref[p]
+            qidx = jnp.maximum(qid, _i32(0))
+            if gang_word is not None:
+
+                @pl.when((gang_word & _i32(1)) != _i32(0))
+                def _checkpoint():
+                    ck_nreq_ref[:] = nreq_ref[:]
+                    ck_nest_ref[:] = nest_ref[:]
+                    ck_quse_ref[:] = quse_ref[:]
+                    gstate_ref[:] = jnp.zeros_like(gstate_ref)
+
+                placed = gstate_ref[0:1, 0:1]  # [1, 1] members placed
+                # a rejected gang's remaining members are skipped
+                live = gstate_ref[1:2, 0:1] == _i32(0)
+            if has_prod:
+                is_prod = pprod_ref[p] != _i32(0)
+                node_ok_p = (
+                    jnp.where(
+                        is_prod,
+                        usage_ref[FLAG_ROW_PROD_OK : FLAG_ROW_PROD_OK + 1, :],
+                        usage_ref[FLAG_ROW_OK : FLAG_ROW_OK + 1, :],
+                    )
+                    != _i32(0)
                 )
-                != _i32(0)
-            )
-            usage_p = jnp.where(is_prod, uprod_ref[:], usage_ref[:])
-        else:
-            node_ok_p = node_ok
-            usage_p = usage_ref[:]
-
-        nreq = nreq_ref[:]
-        # Filter: Fit (only requested resources constrain) + node flags
-        need = req > _i32(0)  # [RP, 1] broadcasts over lanes
-        fviol = jnp.where(need & (nreq + req > alloc), _i32(1), _i32(0))
-        fits = jnp.max(fviol, axis=0, keepdims=True) == _i32(0)  # [1, N]
-        # ElasticQuota admission on limited dimensions
-        qlane = qlane_iota == qidx
-        quse_col = jnp.sum(
-            jnp.where(qlane, quse_ref[:], _i32(0)),
-            axis=1,
-            keepdims=True,
-            dtype=jnp.int32,
-        )
-        qrt_col = jnp.sum(
-            jnp.where(qlane, qrt_ref[:], _i32(0)),
-            axis=1,
-            keepdims=True,
-            dtype=jnp.int32,
-        )
-        qlim_col = jnp.sum(
-            jnp.where(qlane, qlim_ref[:], _i32(0)),
-            axis=1,
-            keepdims=True,
-            dtype=jnp.int32,
-        )
-        qviol = jnp.where(
-            (qlim_col != _i32(0)) & (quse_col + req > qrt_col),
-            _i32(1),
-            _i32(0),
-        )
-        qok = jnp.max(qviol) == _i32(0)
-        feasible = fits & node_ok_p & ((qid < _i32(0)) | qok) & is_valid
-        if has_extras:
-            xv = xcomb_ref[pl.ds(j, 1), :]  # [1, N]
-            feasible = feasible & (xv != _i32(XCOMB_INFEASIBLE))
-
-        # Score: NodeResourcesFit + LoadAware, exact integer math
-        total = jnp.zeros((1, n_lanes), jnp.int32)
-        if cfg.enable_fit_score:
-            t = nreq + sreq
-            if cfg.fit_scoring_strategy == MOST_ALLOCATED:
-                per_res = _most_requested(t, alloc, recip)
+                usage_p = jnp.where(is_prod, uprod_ref[:], usage_ref[:])
             else:
-                per_res = _least_requested(t, alloc, recip)
-            total = total + _i32(cfg.fit_plugin_weight) * _weighted_rows(
-                per_res, fit_w_col, fit_w_sum
-            )
-        if cfg.enable_loadaware:
-            est_used = usage_p + nest_ref[:] + est
-            per_res = _least_requested(est_used, alloc, recip)
-            la = _weighted_rows(per_res, la_w_col, la_w_sum)
-            total = total + _i32(cfg.loadaware_plugin_weight) * jnp.where(
-                fresh, la, _i32(0)
-            )
-        if has_extras:
-            total = total + jnp.where(
-                xv == _i32(XCOMB_INFEASIBLE), _i32(0), xv
-            )
+                node_ok_p = node_ok
+                usage_p = usage_ref[:]
 
-        masked = jnp.where(feasible, total, I32_MIN)
-        best = jnp.max(masked)
-        any_feasible = best > I32_MIN
-        chosen = jnp.min(jnp.where(masked == best, lane_iota, _i32(n_lanes)))
-        chosen = jnp.where(any_feasible, chosen, _i32(-1))
-
-        # Reserve: one-hot-lane adds on the [RP, N] state
-        commit_lane = (lane_iota == chosen) & any_feasible  # [1, N]
-        nreq_new = nreq + jnp.where(commit_lane, req, _i32(0))
-        nest_new = nest_ref[:] + jnp.where(commit_lane, est, _i32(0))
-        quse_commit = qlane & any_feasible & (qid >= _i32(0))
-        quse_new = quse_ref[:] + jnp.where(quse_commit, req, _i32(0))
-        if gang_word is not None:
-            # no node before the gang reached min member: Unreserve
-            # every placement it made, reject it
-            placed_now = jnp.where(any_feasible, _i32(1), _i32(0))
-            fail = jnp.where(
-                is_valid & (placed_now == _i32(0))
-                & (placed < (gang_word >> _i32(1))),
+            nreq = nreq_ref[:]
+            # Filter: Fit (only requested resources constrain) + node flags
+            need = req > _i32(0)  # [RP, 1] broadcasts over lanes
+            fviol = jnp.where(need & (nreq + req > alloc), _i32(1), _i32(0))
+            fits = jnp.max(fviol, axis=0, keepdims=True) == _i32(0)  # [1, N]
+            # ElasticQuota admission on limited dimensions
+            qlane = qlane_iota == qidx
+            quse_col = jnp.sum(
+                jnp.where(qlane, quse_ref[:], _i32(0)),
+                axis=1,
+                keepdims=True,
+                dtype=jnp.int32,
+            )
+            qrt_col = jnp.sum(
+                jnp.where(qlane, qrt_ref[:], _i32(0)),
+                axis=1,
+                keepdims=True,
+                dtype=jnp.int32,
+            )
+            qlim_col = jnp.sum(
+                jnp.where(qlane, qlim_ref[:], _i32(0)),
+                axis=1,
+                keepdims=True,
+                dtype=jnp.int32,
+            )
+            qviol = jnp.where(
+                (qlim_col != _i32(0)) & (quse_col + req > qrt_col),
                 _i32(1),
                 _i32(0),
-            )  # [1, 1]
-            # Mosaic broadcasts along one axis at a time: lanes first
-            fail_n = jnp.broadcast_to(fail, (1, n_lanes)) != _i32(0)
-            fail_q = jnp.broadcast_to(fail, (1, q_lanes)) != _i32(0)
-            nreq_new = jnp.where(fail_n, ck_nreq_ref[:], nreq_new)
-            nest_new = jnp.where(fail_n, ck_nest_ref[:], nest_new)
-            quse_new = jnp.where(fail_q, ck_quse_ref[:], quse_new)
-            gstate_ref[0:1, :] = jnp.broadcast_to(
-                placed + placed_now, (1, LANES)
             )
-            gstate_ref[1:2, :] = jnp.broadcast_to(
-                gstate_ref[1:2, 0:1] | fail, (1, LANES)
+            qok = jnp.max(qviol) == _i32(0)
+            feasible = fits & node_ok_p & ((qid < _i32(0)) | qok)
+            if gang_word is not None:
+                feasible = feasible & live
+            if has_extras:
+                xv = xcomb_ref[pl.ds(j, 1), :]  # [1, N]
+                feasible = feasible & (xv != _i32(XCOMB_INFEASIBLE))
+
+            # Score: NodeResourcesFit + LoadAware, exact integer math
+            total = jnp.zeros((1, n_lanes), jnp.int32)
+            if cfg.enable_fit_score:
+                t = nreq + sreq
+                if cfg.fit_scoring_strategy == MOST_ALLOCATED:
+                    per_res = _most_requested(t, alloc, recip)
+                else:
+                    per_res = _least_requested(t, alloc, recip)
+                total = total + _i32(cfg.fit_plugin_weight) * _weighted_rows(
+                    per_res, fit_w_col, fit_w_sum
+                )
+            if cfg.enable_loadaware:
+                est_used = usage_p + nest_ref[:] + est
+                per_res = _least_requested(est_used, alloc, recip)
+                la = _weighted_rows(per_res, la_w_col, la_w_sum)
+                total = total + _i32(cfg.loadaware_plugin_weight) * jnp.where(
+                    fresh, la, _i32(0)
+                )
+            if has_extras:
+                total = total + jnp.where(
+                    xv == _i32(XCOMB_INFEASIBLE), _i32(0), xv
+                )
+
+            masked = jnp.where(feasible, total, I32_MIN)
+            best = jnp.max(masked)
+            any_feasible = best > I32_MIN
+            chosen = jnp.min(
+                jnp.where(masked == best, lane_iota, _i32(n_lanes))
             )
-        nreq_ref[:] = nreq_new
-        nest_ref[:] = nest_new
-        quse_ref[:] = quse_new
+            chosen = jnp.where(any_feasible, chosen, _i32(-1))
 
-        chosen_ref[pl.ds(j, 1), :] = jnp.full((1, LANES), chosen, jnp.int32)
+            # Reserve: one-hot-lane adds on the [RP, N] state
+            commit_lane = (lane_iota == chosen) & any_feasible  # [1, N]
+            nreq_new = nreq + jnp.where(commit_lane, req, _i32(0))
+            nest_new = nest_ref[:] + jnp.where(commit_lane, est, _i32(0))
+            quse_commit = qlane & any_feasible & (qid >= _i32(0))
+            quse_new = quse_ref[:] + jnp.where(quse_commit, req, _i32(0))
+            if gang_word is not None:
+                # no node before the gang reached min member: Unreserve
+                # every placement it made, reject it
+                placed_now = jnp.where(any_feasible, _i32(1), _i32(0))
+                fail = jnp.where(
+                    live & (placed_now == _i32(0))
+                    & (placed < (gang_word >> _i32(1))),
+                    _i32(1),
+                    _i32(0),
+                )  # [1, 1]
+                # Mosaic broadcasts along one axis at a time: lanes first
+                fail_n = jnp.broadcast_to(fail, (1, n_lanes)) != _i32(0)
+                fail_q = jnp.broadcast_to(fail, (1, q_lanes)) != _i32(0)
+                nreq_new = jnp.where(fail_n, ck_nreq_ref[:], nreq_new)
+                nest_new = jnp.where(fail_n, ck_nest_ref[:], nest_new)
+                quse_new = jnp.where(fail_q, ck_quse_ref[:], quse_new)
+                gstate_ref[0:1, :] = jnp.broadcast_to(
+                    placed + placed_now, (1, LANES)
+                )
+                gstate_ref[1:2, :] = jnp.broadcast_to(
+                    gstate_ref[1:2, 0:1] | fail, (1, LANES)
+                )
+            nreq_ref[:] = nreq_new
+            nest_ref[:] = nest_new
+            quse_ref[:] = quse_new
 
-    def step(j, _):
-        p = i * block + j
-        if not has_gangs:
-            pod_step(j, p)
+            chosen_ref[pl.ds(j, 1), :] = jnp.full(
+                (1, LANES), chosen, jnp.int32
+            )
+
+        def step(j, _):
+            p = first + j
+            if not has_gangs:
+                pod_step(j, p)
+                return jnp.int32(0)
+            # the scalar gate: a gang-free pod runs the plain step
+            gang_word = pgang_ref[p]
+
+            @pl.when(gang_word == _i32(0))
+            def _plain():
+                pod_step(j, p)
+
+            @pl.when(gang_word != _i32(0))
+            def _member():
+                pod_step(j, p, gang_word)
+
             return jnp.int32(0)
-        # the scalar gate: a gang-free pod runs the plain step
-        gang_word = pgang_ref[p]
 
-        @pl.when(gang_word == _i32(0))
-        def _plain():
-            pod_step(j, p)
-
-        @pl.when(gang_word != _i32(0))
-        def _member():
-            pod_step(j, p, gang_word)
-
-        return jnp.int32(0)
-
-    lax.fori_loop(jnp.int32(0), jnp.int32(block), step, jnp.int32(0))
+        lax.fori_loop(
+            jnp.int32(0), jnp.minimum(n_valid - first, _i32(block)), step,
+            jnp.int32(0),
+        )
 
 
 @devprof.boundary("solver.pallas_dense._run_cycle_dense")
 @partial(jax.jit, static_argnames=("cfg", "block", "interpret"))
 def _run_cycle_dense(
-    preq, psreq, pest, qid, pvalid, pprod, alloc, req0, usage, qrt,
+    preq, psreq, pest, qid, pprod, nvalid, alloc, req0, usage, qrt,
     qlim, quse0, weights, uprod=None, xcomb=None, pgang=None, *,
     cfg: CycleConfig, block: int, interpret: bool
 ):
@@ -392,7 +417,7 @@ def _run_cycle_dense(
             pl.BlockSpec((block, N), lambda i, *_: (i, _z), memory_space=pltpu.VMEM)
         ]
         operands += [xcomb]
-    scalars = [qid, pvalid, pprod]
+    scalars = [qid, pprod, nvalid]
     scratch = []
     if has_gangs:
         scalars += [pgang]
@@ -522,7 +547,9 @@ def _greedy_assign_dense(
     psreq = _pods(nonzero_requests(pods.requests))
     pest = _pods(pods.estimated)
     qid = jnp.pad(pods.quota_id[order].astype(jnp.int32), (0, P_pad - P))
-    pvalid = jnp.pad(valid[order].astype(jnp.int32), (0, P_pad - P))
+    # the kernel runs the rows of the valid pods alone: data, so a
+    # backlog of another size runs the same compiled kernel
+    n_valid = jnp.sum(valid, dtype=jnp.int32)
 
     mask_default, mask_prod = loadaware_node_masks(nodes, cfg)
     if not cfg.enable_loadaware:
@@ -593,8 +620,8 @@ def _greedy_assign_dense(
         psreq,
         pest,
         qid,
-        pvalid,
         pprod,
+        n_valid[None],
         alloc_rows,
         req0_rows,
         usage_rows,
@@ -626,5 +653,6 @@ def _greedy_assign_dense(
         node_requested=nreq[:R, :N].T.astype(jnp.int64),
         node_estimated=nest[:R, :N].T.astype(jnp.int64),
         quota_used=quse[:R, :nq].T.astype(jnp.int64),
+        rounds=n_valid,
         path="pallas",
     )

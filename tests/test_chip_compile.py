@@ -7,14 +7,18 @@ VMEM than a kernel may use, a program that does not fit HBM).  Shapes
 are the served buckets of BASELINE.json config 4: 10,000 pods in the
 16,384 pod bucket, 2,000 nodes in the 2,048 node bucket (a smaller node
 bucket holds five pods a node).  The dense
-kernel is also compiled at the node-bucket limits run_cycle routes by
-(``solver.DENSE_MAX_NODES``), and refused just past them.
+kernel is also compiled at k8s5k's 8,192 node lanes, at the gang cell's
+512 x 8,192 buckets, at the node-bucket limits run_cycle routes by
+(``solver.DENSE_MAX_NODES``), and refused just past them; each compile
+checks that the kernel takes the valid pod count, the operand that
+bounds the rows it runs.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import pytest
@@ -84,9 +88,22 @@ def _dense(one_chip, node_bucket, extras, gangs=False, pod_bucket=POD_BUCKET):
     ).compile()
 
 
-def test_dense_kernel_served_buckets(one_chip):
-    compiled = _dense(one_chip, NODE_BUCKET, extras=False)
-    assert "tpu_custom_call" in compiled.as_text()
+def _takes_valid_count(compiled) -> bool:
+    """Whether the kernel's custom call takes the valid pod count: an
+    ``s32[1]`` scalar-prefetch operand after the two ``[P]`` ones (queue
+    ids, prod flags)."""
+    return re.search(
+        r"custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+        r"\{(s32\[\d+\]\{0\}, ){2}s32\[1\]\{0\}",
+        compiled.as_text(),
+    ) is not None
+
+
+@pytest.mark.parametrize("node_bucket", [NODE_BUCKET, 8192])
+def test_dense_kernel_served_buckets(one_chip, node_bucket):
+    """colo2k.batch's 2,048 and k8s5k.batch's 8,192 node lanes."""
+    compiled = _dense(one_chip, node_bucket, extras=False)
+    assert _takes_valid_count(compiled)
 
 
 @pytest.mark.parametrize("extras", [False, True])
@@ -95,7 +112,7 @@ def test_dense_kernel_at_node_limit(one_chip, extras):
 
     limit = solver.DENSE_MAX_NODES_EXTRAS if extras else solver.DENSE_MAX_NODES
     compiled = _dense(one_chip, limit, extras)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _takes_valid_count(compiled)
 
 
 @pytest.mark.parametrize("node_bucket,pod_bucket", [(512, 8192),
@@ -105,7 +122,7 @@ def test_dense_gang_kernel_compiles(one_chip, node_bucket, pod_bucket):
     at the gang cell's buckets and at the dense node limit."""
     compiled = _dense(one_chip, node_bucket, extras=False, gangs=True,
                       pod_bucket=pod_bucket)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _takes_valid_count(compiled)
 
 
 def test_dense_kernel_refused_past_node_limit(one_chip):

@@ -214,6 +214,101 @@ def test_scan_dense_parity_fuzz(seed):
     _assert_matches(want, greedy_assign_dense(snap, cfg, interpret=True), seed)
 
 
+TAIL_ROWS = 256  # the pod bucket of the tail-skip cases: two kernel blocks
+
+
+def _tail_snapshot(n, variant):
+    """``n`` pods valid after PreFilter in a 256-row pod bucket, their rows
+    shuffled among the padding's.  The "gang" variant heads the queue with
+    a gang of 2 that PreFilter drops (min member 3) where the bucket has
+    room, and ends it with a gang of 8 that one node's cpu rejects at its
+    last member: at 129 valid pods the gang holds queue rows 121-128, so
+    it straddles into the last valid block and is undone there."""
+    rng = np.random.RandomState(n)
+    gangs, quotas = [], []
+    if variant == "gang":
+        big = 8 if n >= 8 else 0
+        fill = n - big
+        pods = [{"name": f"f{i}", "requests": {"cpu": "100m", "pods": 1},
+                 "priority": 9000 - i} for i in range(fill)]
+        pods += [{"name": f"b{i}", "requests": {"cpu": "1", "pods": 1},
+                  "gang": "big", "priority": 100} for i in range(big)]
+        if n + 2 <= TAIL_ROWS:
+            pods += [{"name": f"s{i}", "requests": {"cpu": "1", "pods": 1},
+                      "gang": "short", "priority": 9999} for i in range(2)]
+        gangs = [{"name": "big", "min_member": 8},
+                 {"name": "short", "min_member": 3}]
+        nodes = [{"name": "n", "allocatable": {
+            "cpu": f"{fill * 100 + 7300}m", "memory": "64Gi", "pods": 1000},
+            "usage": {}}]
+    else:
+        pods = [{"name": f"p{i}", "requests": {
+                    "cpu": f"{int(rng.randint(200, 2000))}m",
+                    "memory": int(rng.randint(1, 5)) * Gi, "pods": 1},
+                 "priority": int(rng.randint(0, 4)) * 1000,
+                 "quota": "q" if i % 5 == 0 else None} for i in range(n)]
+        quotas = [{"name": "q", "runtime": {"cpu": "40"}, "used": {}}]
+        # room for every pod the quota admits, so the last rows place
+        nodes = [{"name": f"n{k}", "allocatable": {
+            "cpu": "64", "memory": "128Gi", "pods": 110},
+            "usage": {"cpu": f"{4 * k}", "memory": f"{8 * k}Gi"}}
+            for k in range(8)]
+    snap = encode_snapshot(nodes, pods, gangs, quotas, pod_bucket=TAIL_ROWS)
+    perm = rng.permutation(TAIL_ROWS)
+    shuffled = {
+        f.name: getattr(snap.pods, f.name)[perm]
+        for f in dataclasses.fields(snap.pods)
+        if f.name != "names" and getattr(snap.pods, f.name) is not None
+    }
+    return dataclasses.replace(
+        snap, pods=dataclasses.replace(snap.pods, **shuffled)
+    )
+
+
+@pytest.mark.parametrize("variant", ["plain", "gang", "extras"])
+@pytest.mark.parametrize("n", [0, 1, 127, 128, 129, TAIL_ROWS - 1, TAIL_ROWS])
+def test_dense_skips_padding_rows(n, variant):
+    """The dense kernel runs the rows of the valid pods alone: at every
+    valid count across its block edges, gang-free, Strict-gang and with
+    an extras tile, it equals the scan on placements, statuses and the
+    post-cycle state, every invalid row reads -1, and ``rounds`` counts
+    the pod steps run, the pods valid after PreFilter."""
+    from koordinator_tpu.constraints.gang import strict_gangs
+    from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
+
+    snap = _tail_snapshot(n, variant)
+    extras = {}
+    if variant == "extras":
+        rng = np.random.RandomState(n)
+        cells = (TAIL_ROWS, snap.nodes.allocatable.shape[0])
+        extras = {"extra_mask": rng.rand(*cells) > 0.2,
+                  "extra_scores": rng.randint(0, 50, cells).astype(np.int64)}
+    want = greedy_assign(snap, **extras)
+    got = greedy_assign_dense(
+        snap, interpret=True, has_gangs=variant == "gang", **extras
+    )
+    for field in ("assignment", "status", "node_requested",
+                  "node_estimated", "quota_used"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
+            err_msg=field,
+        )
+    _, valid, _ = strict_gangs(
+        snap.pods.gang_id, snap.pods.valid, snap.gangs.min_member
+    )
+    valid = np.asarray(valid)
+    assert int(valid.sum()) == n
+    assert int(np.asarray(got.rounds)) == n
+    assignment = np.asarray(got.assignment)
+    assert (assignment[~valid] == -1).all()
+    if variant == "gang" and n >= 8:
+        # the big gang was undone: none placed, the node holds the fill
+        big = np.asarray(snap.pods.gang_id) == 0
+        assert (assignment[big] == -1).all()
+        cpu = np.asarray(got.node_requested)[0, 0]
+        assert cpu == (n - 8) * 100
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_scan_waves_parity_fuzz(seed):
     """The round-based sharded path holds it too (node_requested comes

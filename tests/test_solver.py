@@ -2,8 +2,10 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from koordinator_tpu.config import CycleConfig
+from koordinator_tpu.constraints.gang import strict_gangs
 from koordinator_tpu.harness import generators
 from koordinator_tpu.harness.reference import ReferenceCycle
 from koordinator_tpu.model import resources as res
@@ -15,6 +17,7 @@ from koordinator_tpu.solver import (
     greedy_assign,
     score_cycle,
 )
+from koordinator_tpu.solver.greedy import queue_order
 
 R = res.NUM_RESOURCES
 
@@ -319,3 +322,31 @@ class TestPrelaunchKernelChoice:
         assert pallas_variant(DENSE_MAX_NODES + 1, False, 1) is None
         assert pallas_variant(DENSE_MAX_NODES_EXTRAS, True, 1) == "dense"
         assert pallas_variant(DENSE_MAX_NODES_EXTRAS + 1, True, 1) is None
+
+
+@pytest.mark.parametrize("gangs", [False, True])
+@pytest.mark.parametrize("seed", range(3))
+def test_queue_order_puts_valid_rows_first(seed, gangs):
+    """Every valid row precedes every invalid one in the visit order, with
+    invalid rows scattered among the valid in the input and, with gangs,
+    a gang that PreFilter drops: the dense kernel runs the first
+    ``sum(valid)`` rows of the order and skips the rest."""
+    rng = np.random.RandomState(seed)
+    P = 96
+    priority = rng.randint(0, 4, P) * 1000
+    valid = rng.rand(P) > 0.4
+    order_args = ()
+    if gangs:
+        # gang 2 asks for more members than it has: PreFilter drops it
+        min_member = np.array([2, 3, P, 1], np.int32)
+        gang_id = rng.randint(-1, len(min_member), P).astype(np.int32)
+        gang, after, _ = strict_gangs(gang_id, valid, min_member)
+        after = np.asarray(after)
+        assert (valid & ~after).any(), "no gang dropped by PreFilter"
+        valid = after
+        order_args = (gang, len(min_member))
+    order = np.asarray(queue_order(priority, valid, *order_args))
+    np.testing.assert_array_equal(np.sort(order), np.arange(P))
+    in_order = valid[order]
+    n = int(valid.sum())
+    assert in_order[:n].all() and not in_order[n:].any()
