@@ -586,7 +586,7 @@ def child(platform: str) -> None:
     spans = {
         "init": None, "rtt_floor": None, "snapshot": None,
         "lowering_probe": None, "compile": None, "steady": None,
-        "wave_compile": None, "wave": None, "incr_score": None,
+        "incr_score": None,
         "cpu_native": None, "cpu_native_mt": None, "devprof": None,
     }
 
@@ -677,37 +677,6 @@ def child(platform: str) -> None:
     assigned = int((np.asarray(result.assignment)[:PODS] >= 0).sum())
     assert assigned > 0, "benchmark snapshot scheduled nothing"
     assert result.path == path, f"expected {path} path, ran {result.path}"
-
-    # wave-batched cycle (ISSUE 3): the same snapshot through the
-    # wave=32/top_m=4 round-based path — the wide Pallas kernel's
-    # in-VMEM wave rounds on TPU, solver.wave.wave_assign on CPU.
-    # Best-effort for the TIMING (the per-pod artifact must survive a
-    # wave failure), but placement parity is asserted hard below.
-    wave_ms = None
-    wave_rounds = None
-    wave_parity = None
-    try:
-        wave_ms, wave_rounds, wassign, wpath, wcompile = _wave_measure(
-            snap, on_tpu, reps=2
-        )
-        spans["wave_compile"] = round(wcompile, 2)
-        spans["wave"] = round(wave_ms, 2)
-        wave_parity = bool(
-            (wassign[:PODS] == np.asarray(result.assignment)[:PODS]).all()
-        )
-        phase(
-            "wave",
-            ms=round(wave_ms, 2),
-            compile_ms=round(wcompile, 1),
-            rounds=wave_rounds,
-            path=wpath,
-        )
-    except Exception as exc:  # noqa: BLE001
-        phase("wave_failed", error=str(exc)[:200])
-    if wave_parity is not None:
-        # outside the best-effort guard: a divergence is a bench FAILURE,
-        # never a logged hiccup next to a published artifact
-        assert wave_parity, "wave placements diverged from the per-pod cycle"
 
     # incremental score engine (ISSUE 9) at headline scale: the warm
     # Score cost through the dirty-column rescore vs the full-rescore
@@ -866,19 +835,6 @@ def child(platform: str) -> None:
                 # per-call transport floor of this platform; subtract for
                 # net device-kernel time
                 "rtt_floor_ms": round(rtt_ms, 2),
-                # wave-batched cycle on the same snapshot: ~W pods
-                # committed per sequential round ("rounds", vs the
-                # 10,000 per-pod steps the headline `value` pays).
-                # null wave = the stage failed and measured nothing
-                # (never claim a config that did not run)
-                "wave": 32 if wave_ms is not None else None,
-                "rounds": wave_rounds,
-                "wave_ms": (
-                    round(wave_ms, 2) if wave_ms is not None else None
-                ),
-                "wave_speedup": (
-                    round(ms / wave_ms, 3) if wave_ms else None
-                ),
                 # incremental score engine (ISSUE 9): warm Score via
                 # dirty-column rescore vs full-rescore oracle, <=64
                 # dirty nodes; null = the probe failed / did not run
@@ -916,40 +872,6 @@ def child(platform: str) -> None:
         ),
         flush=True,
     )
-
-
-def _wave_measure(snap, on_tpu, reps=1):
-    """One wave=32/top_m=4 measurement of the wave-batched cycle on a
-    prepared snapshot — the wide Pallas kernel's in-VMEM rounds on TPU,
-    solver.wave.wave_assign elsewhere.  The ONE implementation behind
-    both the headline and smoke artifacts' wave fields.
-
-    Returns (best_ms, rounds, assignment ndarray, path, compile_ms)."""
-    import numpy as np
-
-    from koordinator_tpu.config import CycleConfig
-
-    wave_cfg = CycleConfig(wave=32, top_m=4)
-    if on_tpu:
-        from koordinator_tpu.solver.pallas_cycle import greedy_assign_pallas
-
-        run = lambda: greedy_assign_pallas(snap, wave_cfg)
-    else:
-        from koordinator_tpu.solver import wave_assign
-
-        run = lambda: wave_assign(snap, wave_cfg)
-    t0 = time.perf_counter()
-    res = run()
-    np.asarray(res.assignment)
-    compile_ms = _ms(t0)
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        res = run()
-        np.asarray(res.assignment)
-        times.append(_ms(t0))
-    rounds = int(np.asarray(res.rounds)) if res.rounds is not None else None
-    return min(times), rounds, np.asarray(res.assignment), res.path, compile_ms
 
 
 def _native_prepare(nodes, pods, gangs, quotas, tmpdir):
@@ -2517,19 +2439,6 @@ def child_config(platform: str, config: str) -> None:
         np.asarray(r2.assignment)
         steady_ms = _ms(t0)
 
-        # the wave-batched cycle smokes here too (cheap proof per round
-        # that the batching still lowers and stays bit-exact): the wave
-        # Pallas kernel through the REAL Mosaic lowering on TPU, the
-        # scan-wave path on the CPU fallback.  Hard-fail here — smoke
-        # exists to prove the path, not to survive it breaking.
-        wave_ms, wave_rounds, wgot, _, _ = _wave_measure(
-            snap, on_tpu=not interp
-        )
-        assert (wgot == want).all(), (
-            "smoke: wave placements diverged from scan"
-        )
-        phase("wave", ms=round(wave_ms, 2), rounds=wave_rounds)
-
         print(
             json.dumps(
                 {
@@ -2542,15 +2451,9 @@ def child_config(platform: str, config: str) -> None:
                     "compile_ms": round(compile_ms, 1),
                     "parity": "exact",
                     "assigned": int((got[: len(pods)] >= 0).sum()),
-                    # wave-batched rounds on the same snapshot (512 pods
-                    # per-pod would be 512 sequential steps)
-                    "wave": 32,
-                    "rounds": wave_rounds,
-                    "wave_ms": round(wave_ms, 2),
                     "spans": {
                         "compile": round(compile_ms, 2),
                         "steady": round(steady_ms, 2),
-                        "wave": round(wave_ms, 2),
                     },
                 }
             ),
@@ -3191,7 +3094,7 @@ def child_config(platform: str, config: str) -> None:
         # the node axis always divides over a power-of-two mesh
         req, _ = build_sync_request(nodes, pods, gangs, quotas)
         payload = req.SerializeToString()
-        cfg = CycleConfig(wave=32, top_m=4)
+        cfg = CycleConfig()
 
         def drive(sv, label):
             """Full Sync -> cold Assign -> 3 warm delta-Sync/Assign

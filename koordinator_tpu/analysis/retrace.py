@@ -22,9 +22,8 @@ Six statically detectable shapes of the PR-1 name-tuple retrace:
 4. TRACED wave knobs at a jit boundary: a jitted function that takes
    ``wave`` or ``top_m`` without declaring it static traces the wave
    width into the program — the loop structure then re-specializes on
-   every distinct value, a silent per-cycle retrace of the hottest
-   program in the repo (solver/wave.py, the wave Pallas kernel, and
-   parallel/shard_assign.py all pass them via ``static_argnames``).
+   every distinct value, a silent per-cycle retrace of the round-based
+   cycle (parallel/shard_assign.py passes them via ``static_argnames``).
    ISSUE 7 extends the same shape to the MESH knobs: a traced ``mesh``
    / device-count / shard-width argument at a jit boundary
    re-specializes the partitioned program per value exactly the same

@@ -1579,11 +1579,11 @@ class ScorerServicer:
                         spans.note("coalesced", len(served))
                     spans.note("memo_hit", True)
                     tel.commit_cycle(
-                        exec_ms, path="memo", wave=self.cfg.wave
+                        exec_ms, path="memo", wave=1
                     )
                 for _ in range(n_observe):
                     tel.metrics.observe_cycle(
-                        exec_ms, path="memo", wave=self.cfg.wave
+                        exec_ms, path="memo", wave=1
                     )
 
         return _hook
@@ -1704,10 +1704,10 @@ class ScorerServicer:
             tel.metrics.set_device_idle(stats["device_idle_ms"])
             n_observe = len(assembled) if pending else len(assembled) - 1
             if not pending:
-                tel.commit_cycle(exec_ms, path="score", wave=self.cfg.wave)
+                tel.commit_cycle(exec_ms, path="score", wave=1)
             for _ in range(n_observe):
                 tel.metrics.observe_cycle(
-                    exec_ms, path="score", wave=self.cfg.wave
+                    exec_ms, path="score", wave=1
                 )
 
     def assign(self, req: "pb2.AssignRequest", ctx=None) -> "pb2.AssignReply":
@@ -1992,7 +1992,7 @@ class ScorerServicer:
                 # blocking stacked transfer — OFF the launch lock, so a
                 # coalesced Score batch can launch while it drains.
                 # ``rounds`` rides the same stacked device_get: a
-                # device scalar (single-chip wave path, dense kernel),
+                # device scalar (dense kernel),
                 # a host int (shard path) or None (scan) — device_get
                 # passes the last two through.
                 with scope.span("readback"):
@@ -2093,10 +2093,10 @@ class ScorerServicer:
     @launch_section
     def _assign_cycle(self, snap, spans, i32_ok, has_gangs=False):
         """Run the device cycle (shard-first when a mesh is configured)
-        and return ``(CycleResult, rounds or None, effective wave
-        width)`` — the shard path widens cfg.wave<=1 to its own
-        default, and the telemetry labels must say what actually ran.
-        Caller holds the launch lock and owns error accounting.
+        and return ``(CycleResult, rounds or None, wave width)`` — the
+        width that ran, for the telemetry labels: the shard path's
+        ``WAVE_WIDTH``, else 1.  Caller holds the launch lock and owns
+        error accounting.
 
         ``spans`` is the RPC's CycleScope (obs/spans.py) — same span
         surface as the recorder, but private to this cycle."""
@@ -2111,26 +2111,22 @@ class ScorerServicer:
         # scan roll a rejected gang back
         if self.mesh is not None and not self._term_names and not has_gangs:
             from koordinator_tpu.parallel import greedy_assign_waves
+            from koordinator_tpu.parallel.shard_assign import WAVE_WIDTH
 
-            # the CycleConfig wave knobs thread through to the
-            # round-based sharded cycle; wave=1 (the per-pod default)
-            # keeps the multichip path's own proven width.  A failed
-            # shard cycle raises: the RPC fails, no single-chip
-            # fallback hides it.
-            eff_wave = self.cfg.wave if self.cfg.wave > 1 else 32
+            # A failed shard cycle raises: the RPC fails, no
+            # single-chip fallback hides it.
             with spans.span("dispatch_shard"):
                 result, rounds = greedy_assign_waves(
-                    snap, self.mesh, self.cfg,
-                    wave=eff_wave, top_m=self.cfg.top_m, spans=spans,
+                    snap, self.mesh, self.cfg, spans=spans
                 )
-            return result, rounds, eff_wave
+            return result, rounds, WAVE_WIDTH
         result = run_cycle(snap, self.cfg, i32_ok=i32_ok, has_gangs=has_gangs)
-        # device-derived step count (wave rounds, dense kernel pod
-        # steps): returned UN-materialized (a device scalar) — blocking
-        # on it here would hold the launch lock for the whole cycle; the
-        # pipelined readback fetches it in the same stacked device_get
-        # as assignment/status, off the lock
-        return result, result.rounds, 1 if has_gangs else self.cfg.wave
+        # device-derived step count (dense kernel pod steps): returned
+        # UN-materialized (a device scalar) — blocking on it here would
+        # hold the launch lock for the whole cycle; the pipelined
+        # readback fetches it in the same stacked device_get as
+        # assignment/status, off the lock
+        return result, result.rounds, 1
 
 
 def _handler(fn, req_cls, method: str, metrics):

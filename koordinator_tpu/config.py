@@ -165,16 +165,6 @@ class CycleConfig:
 
     Plugin score weights mirror the k8s framework's per-plugin weight
     multiplier applied when summing plugin scores.
-
-    ``wave``/``top_m`` select the wave-batched single-chip cycle
-    (solver/wave.py, docs/KERNEL.md "Wave batching"): each sequential
-    round scores ``wave`` pods at once, freezes their top-``top_m``
-    candidate keys, and commits the certified prefix — bit-identical
-    placements with ~wave pods per round instead of one.  ``wave=1``
-    (the default) keeps the per-pod scan/kernel paths.  Both ride the
-    config as STATIC jit arguments; passing them traced at any jit
-    boundary is a silent per-cycle retrace (the koordlint
-    ``retrace-hazard`` rule rejects that shape statically).
     """
 
     loadaware: LoadAwareArgs = LoadAwareArgs()
@@ -184,8 +174,6 @@ class CycleConfig:
     loadaware_plugin_weight: int = 1
     enable_loadaware: bool = True
     enable_fit_score: bool = True
-    wave: int = 1
-    top_m: int = 4
     # fused scoring terms (ISSUE 15; solver/terms.py registry): None =
     # term disabled.  Frozen hashable dataclasses — the configs are
     # static jit arguments, and the registry derives each term's score

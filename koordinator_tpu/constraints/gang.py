@@ -22,7 +22,7 @@ default mode, Strict.  The wire carries no mode (``GangTable`` has only
   skipped members UNSCHEDULABLE.
 
 ``gang_satisfaction`` is the Permit count (``core.go:308``) the engines
-without a rollback (wave, wide, shard) still apply after their cycle;
+without a rollback (the multi-chip shard path) still applies after its cycle;
 no served snapshot with gangs reaches them (``solver.run_cycle``).
 """
 

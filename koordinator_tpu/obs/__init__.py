@@ -53,8 +53,6 @@ def _config_knobs(cfg) -> Dict[str, object]:
     if cfg is None:
         return {}
     return {
-        "wave": int(getattr(cfg, "wave", 1)),
-        "top_m": int(getattr(cfg, "top_m", 0)),
         "fit_scoring_strategy": getattr(cfg, "fit_scoring_strategy", ""),
         "enable_loadaware": bool(getattr(cfg, "enable_loadaware", False)),
         "enable_fit_score": bool(getattr(cfg, "enable_fit_score", False)),

@@ -154,8 +154,9 @@ class FlightRecorder:
     ):
         self.capacity = int(capacity)
         self.state_dir = state_dir
-        # config knobs frozen into every dump (CycleConfig wave/top_m,
-        # strategy names — whatever the owner deems reconstruction-worthy)
+        # config knobs frozen into every dump (CycleConfig strategy
+        # names and switches — whatever the owner deems
+        # reconstruction-worthy)
         self.config: Dict[str, object] = dict(config or {})
         self._wall_clock = wall_clock
         self._ring: collections.deque = collections.deque(maxlen=self.capacity)

@@ -65,14 +65,11 @@ PREWARM_BOUNDARIES = (
     "solver.greedy.greedy_assign",
     "solver.greedy.score_cycle",
     "solver.incremental._rescore",
-    "solver.pallas_cycle._greedy_assign_pallas",
-    "solver.pallas_cycle._run_cycle",
     "solver.pallas_dense._greedy_assign_dense",
     "solver.pallas_dense._run_cycle_dense",
     "solver.resident._scatter_flat",
     "solver.terms._term_extras_jit",
     "solver.topk.masked_top_k",
-    "solver.wave._wave_assign",
 )
 
 # Boundaries prewarm can never replay, with the reason on record: their
@@ -99,12 +96,10 @@ _BOUNDARY_MODULES = (
     "koordinator_tpu.solver.candidates",
     "koordinator_tpu.solver.greedy",
     "koordinator_tpu.solver.incremental",
-    "koordinator_tpu.solver.pallas_cycle",
     "koordinator_tpu.solver.pallas_dense",
     "koordinator_tpu.solver.resident",
     "koordinator_tpu.solver.terms",
     "koordinator_tpu.solver.topk",
-    "koordinator_tpu.solver.wave",
     "koordinator_tpu.parallel.shard_assign",
 )
 

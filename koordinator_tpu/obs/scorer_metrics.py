@@ -76,7 +76,7 @@ koord_scorer_prewarm_pending           gauge     —
 ====================================== ========= ==========================
 
 ``cycle_rounds`` and ``rounds_total`` count the sequential device steps
-of a cycle, by path: a wave cycle's rounds (``path="wave"``, ``"shard"``)
+of a cycle, by path: the multi-chip wave cycle's rounds (``path="shard"``)
 and the dense kernel's pod steps (``path="pallas"``), one per valid pod,
 its padding rows not run; rounds over the pod bucket is the share of
 the kernel's rows that ran.  The scan reports none.
@@ -212,7 +212,7 @@ _INCR_COLS_BUCKETS = (
 _FAMILIES = (
     (CYCLE_LATENCY, "histogram",
      "end-to-end Assign/Score cycle latency on the bridge, by device "
-     "path and wave width"),
+     "path and the wave width that ran (1 off the multi-chip path)"),
     (CYCLE_ROUNDS, "gauge",
      "sequential device steps of the last cycle: wave rounds (~P/wave "
      "certified-prefix rounds vs P per-pod steps), or the dense "

@@ -61,9 +61,7 @@ from koordinator_tpu.solver.greedy import (
 )
 
 # the packed-key encode/decode, the cross-shard top-M merge and the
-# in-wave certification are the ONE shared implementation
-# (solver/wave.py) this path and the single-chip wave_assign both
-# consume — no copy-pasted math
+# in-wave certification (solver/wave.py)
 from koordinator_tpu.solver.wave import (
     is_most_allocated,
     merge_topm,
@@ -73,6 +71,12 @@ from koordinator_tpu.solver.wave import (
     resolve_wave,
     score_feasible,
 )
+
+
+# the round-based cycle's width: pods scored per round (measured at the
+# 10k x 2k benchmark snapshot, see _assign_waves); the served mesh path
+# runs it and labels its telemetry with it
+WAVE_WIDTH = 32
 
 
 def _pad_nodes_to(snap: ClusterSnapshot, multiple: int) -> ClusterSnapshot:
@@ -618,7 +622,7 @@ def greedy_assign_waves(
     cfg: CycleConfig = DEFAULT_CYCLE_CONFIG,
     extra_mask: Optional[jnp.ndarray] = None,
     extra_scores: Optional[jnp.ndarray] = None,
-    wave: int = 32,
+    wave: int = WAVE_WIDTH,
     top_m: int = 4,
     spans=None,
     candidates: Optional[jnp.ndarray] = None,

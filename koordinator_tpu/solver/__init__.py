@@ -21,35 +21,25 @@ from koordinator_tpu.solver.topk import (  # noqa: F401
     masked_top_k,
     score_upper_bound,
 )
-from koordinator_tpu.solver.wave import wave_assign  # noqa: F401
 
 
-# VMEM node limits of the Pallas cycle kernels, in node lanes (the
+# VMEM node limits of the dense Pallas cycle kernel, in node lanes (the
 # padded node count), from AOT compiles at 10k pods for a described v5e
-# (tests/test_chip_compile.py keeps the limits honest).  The dense
-# kernel holds its [16, N] node rows in VMEM: 16,384 lanes compile and
-# 32,768 do not; a caller extras tile adds a double-buffered [128, N]
-# block, so with extras 8,192 compile and 16,384 do not.  The wide
-# (wave) kernel holds [N, 128] node rows: 2,048 compile, 4,096 do not.
-# Beyond a limit the cycle runs the XLA scan (or wave) path — chosen
-# before launch, never by catching a failed compile.
+# (tests/test_chip_compile.py keeps the limits honest).  The kernel holds
+# its [16, N] node rows in VMEM: 16,384 lanes compile and 32,768 do not;
+# a caller extras tile adds a double-buffered [128, N] block, so with
+# extras 8,192 compile and 16,384 do not.  Beyond a limit the cycle runs
+# the XLA scan — chosen before launch, never by catching a failed compile.
 DENSE_MAX_NODES = 16384
 DENSE_MAX_NODES_EXTRAS = 8192
-WIDE_MAX_NODES = 2048
 
 
-def pallas_variant(n_nodes: int, has_extras: bool, wave: int):
-    """The Pallas cycle kernel that serves ``n_nodes`` (the node
-    bucket) on TPU: ``"wide"`` runs the in-VMEM wave rounds when
-    ``wave > 1`` and its node rows fit, ``"dense"`` runs the per-pod
-    loop, None means no kernel fits VMEM and the XLA path serves.
-    Placements are identical across all three."""
+def dense_kernel_fits(n_nodes: int, has_extras: bool) -> bool:
+    """Whether the dense Pallas kernel's node rows for ``n_nodes`` (the
+    node bucket) fit VMEM on TPU; where they do not, the scan serves,
+    with identical placements."""
     lanes = -(-int(n_nodes) // 128) * 128
-    if wave > 1 and lanes <= WIDE_MAX_NODES:
-        return "wide"
-    if lanes <= (DENSE_MAX_NODES_EXTRAS if has_extras else DENSE_MAX_NODES):
-        return "dense"
-    return None
+    return lanes <= (DENSE_MAX_NODES_EXTRAS if has_extras else DENSE_MAX_NODES)
 
 
 # The kernel's scoring multiplies clamped free capacity by MAX_NODE_SCORE
@@ -144,38 +134,28 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None,
     On TPU the dense-layout single-kernel Pallas cycle
     (solver/pallas_dense.py) runs the per-pod loop in VMEM; elsewhere,
     or when the input fails the kernel's i32 bounds or its node bucket
-    exceeds the kernel's VMEM limit (``pallas_variant``), the lax.scan
+    exceeds the kernel's VMEM limit (``dense_kernel_fits``), the lax.scan
     path runs.  The choice is made before launch from what the input
-    shows; a kernel that fails raises.  All paths are bit-identical
-    (tests/test_pallas_cycle.py).
-
-    ``cfg.wave > 1`` selects the wave-batched cycle: the wide kernel
-    (solver/pallas_cycle.py) runs its in-VMEM wave rounds where its
-    node rows fit (the dense kernel keeps its per-pod loop and ignores
-    the knobs, placements identical either way), and the XLA path runs
-    ``solver.wave.wave_assign`` instead of the scan.  The knobs ride the
-    static config, so a warm Sync/Assign stream stays retrace-free
-    (tests/test_resident_warm.py).
+    shows; a kernel that fails raises.  Both paths are bit-identical
+    (tests/test_parity_fuzz.py).
 
     ``i32_ok``: callers that already know whether the snapshot fits the
     kernel's i32 arithmetic (e.g. the bridge server, which checks host-side
     numpy mirrors at Sync time) pass it to skip the per-cycle device check.
 
-    Strict Coscheduling gangs (``constraints/gang.py``): only the dense
-    kernel and the scan roll a rejected gang back, so a snapshot with a
-    gang member (``has_gangs``, as ``i32_ok`` from the caller's mirrors,
-    else read off the device) never goes to the wide kernel or the wave
-    path, whatever ``cfg.wave`` asks: the dense kernel or the scan
-    serves it.
+    Strict Coscheduling gangs (``constraints/gang.py``): a snapshot with
+    a gang member (``has_gangs``, as ``i32_ok`` from the caller's
+    mirrors, else read off the device) runs the dense kernel's gang
+    variant, which rolls a rejected gang back as the scan does.
 
     Fused scoring terms (ISSUE 15): a ``cfg`` with term configs set
     materializes the registry's cellwise [P, N] tensors ONCE
     (solver/terms.py ``term_extras``, one async launch, no readback) and
-    folds them into ``extra_mask``/``extra_scores`` — the scan, the wave
-    path and the Pallas kernels all consume the fused total through the
-    seam they already had.  A terms-only extra needs NO device
-    reduction for its magnitude bound: the registry's bound is a config
-    property (``terms_upper_bound``).
+    folds them into ``extra_mask``/``extra_scores`` — the scan and the
+    Pallas kernel both consume the fused total through the seam they
+    already had.  A terms-only extra needs NO device reduction for its
+    magnitude bound: the registry's bound is a config property
+    (``terms_upper_bound``).
     """
     import jax
 
@@ -196,13 +176,12 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None,
         extra_mask = t_mask if extra_mask is None else extra_mask & t_mask
     has_extras = extra_mask is not None or extra_scores is not None
     extras_ok = True
-    scores_hi = None
     if extra_scores is not None:
-        # magnitude bound for the kernel's i32 accumulation headroom and
-        # the wave path's packed-key range.  Terms-only extras take the
-        # STATIC registry bound (no device sync on the warm Assign
-        # path); caller extras still need the one device reduction, and
-        # a composed total is bounded by the sum of the two bounds.
+        # magnitude bound for the kernel's i32 accumulation headroom.
+        # Terms-only extras take the STATIC registry bound (no device
+        # sync on the warm Assign path); caller extras still need the
+        # one device reduction, and a composed total is bounded by the
+        # sum of the two bounds.
         if caller_scores is None:
             scores_hi = terms_upper_bound(cfg)
         else:
@@ -219,33 +198,15 @@ def run_cycle(snapshot, cfg=None, extra_mask=None, extra_scores=None,
             snapshot.pods.gang_id, snapshot.pods.valid,
             snapshot.gangs.min_member,
         )
-    # the wave-batched engines have no gang rollback
-    wave = 1 if has_gangs else int(cfg.wave)
-    variant = (
-        pallas_variant(snapshot.nodes.allocatable.shape[0], has_extras, wave)
-        if backend != "cpu" and extras_ok
+    if (
+        backend != "cpu" and extras_ok
+        and dense_kernel_fits(snapshot.nodes.allocatable.shape[0], has_extras)
         and (i32_ok if i32_ok is not None else pallas_inputs_fit_i32(snapshot))
-        else None
-    )
-    if variant == "wide":
-        from koordinator_tpu.solver.pallas_cycle import greedy_assign_pallas
-
-        return greedy_assign_pallas(
-            snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores
-        )
-    if variant == "dense":
+    ):
         from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
         return greedy_assign_dense(
             snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores,
             has_gangs=has_gangs,
-        )
-    if wave > 1 and (scores_hi is None or scores_hi < 2**31):
-        # run_cycle never raises for in-contract inputs: extra_scores
-        # beyond the packed-key range take the bit-identical scan below
-        # instead of tripping wave_assign's magnitude guard
-        return wave_assign(
-            snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores,
-            scores_hi=scores_hi,
         )
     return greedy_assign(snapshot, cfg, extra_mask=extra_mask, extra_scores=extra_scores)

@@ -566,7 +566,7 @@ def sparse_top_k(scores, feasible, cand, *, k, hi):
 def candidate_membership_mask(cand, num_nodes: int) -> jnp.ndarray:
     """bool[P, N] membership mask of the candidate lists — the
     assign-side bridge (parallel/shard_assign.py ``candidates=``): the
-    wave engines AND it into their ``extra_mask`` seam, so gang/quota
+    wave engine ANDs it into its ``extra_mask`` seam, so gang/quota
     resolution (the replicated wave top-M merge) sees only candidate
     cells.  Sentinel slots are out of range and drop; this tensor is
     dense [P, N] by design — the wave assign already materializes

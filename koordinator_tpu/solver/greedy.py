@@ -68,15 +68,13 @@ class CycleResult:
     node_requested: Optional[jnp.ndarray] = None  # i64[N, R] post-cycle
     node_estimated: Optional[jnp.ndarray] = None  # i64[N, R] post-cycle
     quota_used: Optional[jnp.ndarray] = None  # i64[Q, R] post-cycle
-    # sequential steps the device ran: the wave-batched paths'
-    # (solver/wave.py, the wave Pallas kernel, parallel/shard_assign.py)
-    # rounds, ~P/wave-prefix vs P scan steps; the dense kernel's pod
-    # steps, one per valid pod (its padding rows are not run); None on
-    # the scan
+    # sequential steps the device ran: the multi-chip wave path's
+    # (parallel/shard_assign.py) rounds, ~P/wave-prefix vs P scan steps;
+    # the dense kernel's pod steps, one per valid pod (its padding rows
+    # are not run); None on the scan
     rounds: Optional[jnp.ndarray] = None
     # which code path produced the result ("pallas" single-kernel cycle,
-    # "scan" lax.scan, "wave" round-based single chip, "shard" multi-chip
-    # shard_map) — static metadata so callers (bridge AssignReply, bench)
+    # "scan" lax.scan, "shard" multi-chip shard_map) — static metadata so callers (bridge AssignReply, bench)
     # can surface degraded-path runs; VERDICT r2 flagged the
     # silent-fallback invisibility
     path: Optional[str] = None

@@ -1,9 +1,9 @@
 """Dense-layout Pallas cycle kernel: nodes on lanes, resources on sublanes.
 
-The first-generation kernel (solver/pallas_cycle.py) puts RESOURCES on the
-128-lane axis, so every per-pod vector op touches [N, 128] i32 tiles (256
-vregs at 2k nodes) while only ~13 lanes carry data — measured ~12us/pod on
-v5e, entirely VPU-occupancy-bound on padding.  This kernel transposes the
+The first-generation kernel (since deleted) put RESOURCES on the 128-lane
+axis, so every per-pod vector op touched [N, 128] i32 tiles (256 vregs at
+2k nodes) while only ~13 lanes carried data — measured ~12us/pod on v5e,
+entirely VPU-occupancy-bound on padding.  This kernel transposes the
 whole state to ``[RP=16, N]`` — resources (13) plus three node-flag rows on
 the SUBLANE axis, nodes riding the lane axis — so the same math touches 32
 vregs instead of 256:
@@ -17,9 +17,9 @@ vregs instead of 256:
 
 Semantics are bit-identical with solver/greedy.py's lax.scan (the parity
 oracle mirroring the reference's sequential cycle,
-``pkg/scheduler/frameworkext/framework_extender.go:192,216``); the same
-i32-soundness contract as the wide kernel applies (model/resources.py MiB
-units; dispatcher gates via pallas_inputs_fit_i32).
+``pkg/scheduler/frameworkext/framework_extender.go:192,216``).  All score
+math is exact i32 arithmetic (model/resources.py MiB units; the dispatcher
+gates via pallas_inputs_fit_i32).
 
 Strict Coscheduling gangs (``constraints/gang.py``) compile a variant of
 the kernel (``has_gangs``, static; a snapshot with no gang member runs
@@ -78,12 +78,22 @@ from koordinator_tpu.solver.greedy import (
     CycleResult,
     queue_order,
 )
-from koordinator_tpu.solver.pallas_cycle import (
-    I32_MIN,
-    LANES,
-    XCOMB_INFEASIBLE,
-    _i32,
-)
+
+LANES = 128
+I32_MIN = np.int32(np.iinfo(np.int32).min)
+# combined extended-plugin tensor: score where feasible, sentinel where
+# masked out (scores are magnitude-guarded < 2^29, far from the sentinel)
+XCOMB_INFEASIBLE = I32_MIN
+
+
+# In-kernel i32 scalar constants.  With x64 enabled, a bare Python scalar
+# in jnp.where/floor_divide/etc. enters the traced sub-jaxpr as a weak
+# i64[] argument whose i64->i32 convert has NO Mosaic lowering (it
+# recurses forever in _convert_helper).  Every scalar that reaches kernel
+# math must therefore be a strong i32.
+def _i32(v) -> jnp.ndarray:
+    return jnp.int32(v)
+
 
 # sublane rows: resources occupy [0, NUM_RESOURCES); flags ride the spare
 # rows of the usage tensor (their weight rows are zero, their request rows
@@ -98,8 +108,18 @@ assert res.NUM_RESOURCES <= FLAG_ROW_OK, (
 
 
 def _exact_div(v, safe, recip):
-    """Exact nonnegative i32 floor division via f32 reciprocal (see
-    pallas_cycle._exact_div for the ablation and soundness argument)."""
+    """Exact nonnegative i32 floor division via f32 reciprocal.
+
+    The VPU has no integer divide — Mosaic emulates ``lax.div`` in
+    software, and ablation on v5e measured it at HALF the whole kernel's
+    runtime (a [N,1] column div costs the same per-vreg as a full
+    [N,128] one).  Every quotient in the score math is bounded by
+    MAX_NODE_SCORE (=100): free/clamped <= cap and weighted sums divide
+    by their weight total, so ``v/safe <= 100`` and the f32 rounding
+    error (rel ~2^-22) is far below the +-1 a single correction step
+    absorbs.  Exactness at floor boundaries is restored by the two
+    corrections; parity tests assert bit-identity with ``//``.
+    """
     q = (v.astype(jnp.float32) * recip).astype(jnp.int32)
     r = v - q * safe
     q = q + jnp.where(r >= safe, _i32(1), _i32(0))
@@ -479,8 +499,8 @@ def greedy_assign_dense(
 ) -> CycleResult:
     """Dense-layout drop-in for greedy_assign on TPU (path="pallas").
 
-    Same i32-headroom guard as the wide kernel: extended scores must stay
-    under 2^29 so the accumulation cannot wrap.
+    The i32-headroom guard: extended scores must stay under 2^29 so the
+    accumulation cannot wrap.
 
     ``has_gangs``: whether any valid pod is a gang member, which selects
     the kernel's Strict-gang variant; callers that know it from host

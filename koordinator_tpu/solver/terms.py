@@ -35,8 +35,8 @@ The registry generalizes the ``extra_mask``/``extra_scores`` seam
 (solver/greedy.py:240): ``apply_terms`` fuses contributions into
 ``score_all``'s one tensor program, and ``term_extras`` materializes
 the same cellwise tensors once per Assign cycle so the sequential
-engines — the scan, ``solver/wave.py`` and the Pallas kernels — consume
-the fused total through the seam they already have.  Missing snapshot
+engines — the scan and the Pallas kernel — consume the fused total
+through the seam they already have.  Missing snapshot
 data (a term enabled before its tensors synced) contributes nothing:
 enabling a term must never fault a cycle, only inform it.
 """
@@ -274,8 +274,8 @@ def _term_extras_jit(snapshot, cfg):
 def term_extras(snapshot, cfg):
     """(extra_scores, extra_mask) [P, N] tensors of the enabled terms —
     the fused total the sequential Assign engines consume through the
-    existing ``extra_mask``/``extra_scores`` seam (greedy scan,
-    solver/wave.py, the Pallas kernels).  Returns (None, None) with no
+    existing ``extra_mask``/``extra_scores`` seam (greedy scan, the
+    Pallas kernel).  Returns (None, None) with no
     terms enabled, so untermed configs pay nothing; otherwise ONE jit
     launch (async, no readback) whose cache keys only on (geometry,
     cfg).  The mask half is None when no enabled term masks (an

@@ -1,11 +1,9 @@
-"""Wave/top-M certification: the shared math of the round-based cycle.
+"""Wave/top-M certification: the resolution math of the multi-chip
+round-based cycle (``parallel/shard_assign.py greedy_assign_waves``).
 
-``greedy_assign`` replays the reference's one-pod-at-a-time cycle, so any
-batched variant must prove each pod's choice equals what the sequential
-scan would have picked.  Both round-based paths — the multi-chip
-``parallel/shard_assign.py greedy_assign_waves`` and the single-chip
-``wave_assign`` below — share that proof, so its primitives live here
-exactly once:
+``greedy_assign`` replays the reference's one-pod-at-a-time cycle, so the
+round-based cycle must prove each pod's choice equals what the sequential
+scan would have picked.  Its primitives:
 
 * the packed (score, node) key: ``score * N + (N - 1 - node)``.  One
   integer max selects the highest score with the LOWEST node index — the
@@ -20,7 +18,7 @@ exactly once:
   next round against fresh state.
 
 Certification, in full (the part a maintainer can silently break; also
-docs/KERNEL.md "Wave batching"):
+docs/KERNEL.md "The round-based multi-chip cycle"):
 
 * under LeastAllocated scoring keys are non-increasing as load commits,
   so any node outside a pod's frozen top-M stays strictly below the
@@ -43,42 +41,19 @@ docs/KERNEL.md "Wave batching"):
   frozen-feasible nodes exist, and committed load never turns an
   infeasible node feasible under either strategy).
 
-The Pallas kernel (solver/pallas_cycle.py) mirrors this resolution in
-i32 with an unpacked (score, index) lexicographic compare — the packed
-key would overflow i32 — and tests/test_parity_fuzz.py holds all three
-implementations bit-identical to the scan.
+tests/test_parity_fuzz.py and tests/test_parallel.py hold the round-based
+cycle bit-identical to the scan.
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
-from koordinator_tpu.config import (
-    CycleConfig,
-    DEFAULT_CYCLE_CONFIG,
-    MOST_ALLOCATED,
-)
-from koordinator_tpu.constraints.gang import gang_satisfaction
-from koordinator_tpu.model.snapshot import ClusterSnapshot, PriorityClass
-from koordinator_tpu.obs import devprof
-from koordinator_tpu.ops.fit import nonzero_requests
-from koordinator_tpu.ops.loadaware import (
-    loadaware_node_masks,
-    select_score_usage,
-)
-from koordinator_tpu.solver.greedy import (
-    STATUS_ASSIGNED,
-    STATUS_UNSCHEDULABLE,
-    STATUS_WAIT_GANG,
-    CycleResult,
-    queue_order,
-    step_feasible_scores,
-)
+from koordinator_tpu.config import CycleConfig, MOST_ALLOCATED
+from koordinator_tpu.solver.greedy import step_feasible_scores
 
 # scores are bounded by plugin weights * MAX_NODE_SCORE (tiny); this
 # sentinel for infeasible nodes leaves the packed key far from i64 limits
@@ -369,291 +344,3 @@ def resolve_wave(
         0, W, resolve, st0
     )
     return choices, committed, done, quse_new, ncommit
-
-
-@devprof.boundary("solver.wave._wave_assign")
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "wave", "top_m", "has_mask", "has_scores"),
-)
-def _wave_assign(
-    snapshot: ClusterSnapshot,
-    extra_mask,
-    extra_scores,
-    *,
-    cfg: CycleConfig,
-    wave: int,
-    top_m: int,
-    has_mask: bool,
-    has_scores: bool,
-):
-    """Single-chip round-based cycle: O(P / commit-prefix) sequential
-    rounds instead of O(P) scan steps.
-
-    Each round scores the next ``wave`` pods against the frozen node
-    table as ONE ``[W, N]`` tensor op (vmapped ``step_feasible_scores``
-    — VPU/MXU-friendly instead of ``[N]`` vector ops), freezes each
-    pod's global top-``top_m`` packed keys via ``lax.top_k``, and runs
-    the shared ``resolve_wave`` certification; the committed prefix
-    lands on the carried node/quota state and the pointer advances by
-    its length.  Bit-identical with ``greedy_assign`` on gang-free
-    snapshots (same packed-key tie-break, same ElasticQuota admission
-    order); parity fuzzed in tests/test_parity_fuzz.py.  It has no
-    Strict gang rollback, so ``run_cycle`` serves a snapshot with gangs
-    on the dense kernel or the scan.
-    """
-    pods, nodes, gangs, quotas = (
-        snapshot.pods,
-        snapshot.nodes,
-        snapshot.gangs,
-        snapshot.quotas,
-    )
-    PCAP = pods.capacity
-    N = nodes.allocatable.shape[0]
-    W = wave
-    M = max(1, min(top_m, N))
-
-    order = queue_order(pods.priority, pods.valid)
-    order_pad = jnp.concatenate([order, jnp.zeros((W,), order.dtype)])
-    score_requests = nonzero_requests(pods.requests)
-
-    mask_default, mask_prod = loadaware_node_masks(nodes, cfg)
-    if not cfg.enable_loadaware:
-        mask_default = jnp.ones_like(mask_default)
-        mask_prod = mask_default
-    node_ok_default = nodes.valid & mask_default
-    node_ok_prod = nodes.valid & mask_prod
-    usage_np, usage_prod = select_score_usage(nodes, cfg)
-    prod_sensitive = cfg.enable_loadaware and (
-        usage_prod is not None
-        or bool(dict(cfg.loadaware.prod_usage_thresholds))
-    )
-    uprod = usage_prod if usage_prod is not None else usage_np
-    is_prod_pods = pods.priority_class == int(PriorityClass.PROD)
-
-    alloc = nodes.allocatable
-    fresh = nodes.metric_fresh
-    gidx = jnp.arange(N, dtype=jnp.int64)
-    iota_w = jnp.arange(W)
-    qrt, qlim = quotas.runtime, quotas.limited
-    most_alloc = is_most_allocated(cfg)
-
-    def one_pod_keys(nreq, nest, p):
-        """Frozen [N] packed keys for pod p (quota handled in the
-        resolution, so qid=-1 here)."""
-        if prod_sensitive:
-            ok_p = jnp.where(is_prod_pods[p], node_ok_prod, node_ok_default)
-            usage_p = jnp.where(is_prod_pods[p], uprod, usage_np)
-        else:
-            ok_p = node_ok_default
-            usage_p = usage_np
-        feasible, total = step_feasible_scores(
-            nreq, nest, quotas.used, alloc, usage_p, fresh, ok_p,
-            pods.requests[p], score_requests[p], pods.estimated[p],
-            jnp.int32(-1), pods.valid[p], qrt, qlim, cfg,
-        )
-        if has_mask:
-            feasible = feasible & extra_mask[p]
-        if has_scores:
-            total = total + extra_scores[p]
-        return pack_keys(total, feasible, gidx, N)
-
-    def wave_round(carry):
-        ptr, nreq, nest, quse, chosen_buf, nrounds = carry
-        ps = lax.dynamic_slice(order_pad, (ptr,), (W,))
-        wvalid = (ptr + iota_w) < PCAP
-        # ONE [W, N] scoring op for the whole wave
-        keys = jax.vmap(lambda p: one_pod_keys(nreq, nest, p))(ps)
-        cand_key, lidx = lax.top_k(keys, M)  # [W, M]
-
-        preq_wave = pods.requests[ps]
-        pest_wave = pods.estimated[ps]
-        psreq_wave = score_requests[ps]
-        pqid_wave = pods.quota_id[ps]
-        pvalid_wave = pods.valid[ps]
-        pprod_wave = is_prod_pods[ps]
-
-        if most_alloc:
-            # the closed candidate universe: union of the wave's top-M
-            # rows, keyed by node (duplicates harmless)
-            uni = lidx.reshape(-1)  # [W*M]
-            universe = dict(
-                gid=uni.astype(jnp.int64),
-                alloc=alloc[uni],
-                nreq=nreq[uni],
-                nest=nest[uni],
-                usage=usage_np[uni],
-                okd=node_ok_default[uni],
-                fresh=fresh[uni],
-                xval=(
-                    extra_scores[ps[:, None], uni[None, :]]
-                    if has_scores
-                    else jnp.zeros((W, W * M), jnp.int64)
-                ),
-                xfeas=(
-                    extra_mask[ps[:, None], uni[None, :]]
-                    if has_mask
-                    else jnp.ones((W, W * M), bool)
-                ),
-            )
-            if prod_sensitive:
-                universe["uprod"] = uprod[uni]
-                universe["okp"] = node_ok_prod[uni]
-            cand = None
-        else:
-            universe = None
-            if prod_sensitive:
-                usage_rows = jnp.where(
-                    pprod_wave[:, None, None], uprod[lidx], usage_np[lidx]
-                )
-                ok_rows = jnp.where(
-                    pprod_wave[:, None],
-                    node_ok_prod[lidx],
-                    node_ok_default[lidx],
-                )
-            else:
-                usage_rows = usage_np[lidx]
-                ok_rows = node_ok_default[lidx]
-            cand = dict(
-                gid=lidx.astype(jnp.int64),
-                alloc=alloc[lidx],
-                nreq=nreq[lidx],
-                nest=nest[lidx],
-                usage=usage_rows,
-                ok=ok_rows,
-                fresh=fresh[lidx],
-                xval=(
-                    extra_scores[ps[:, None], lidx]
-                    if has_scores
-                    else jnp.zeros((W, M), jnp.int64)
-                ),
-                xfeas=(
-                    extra_mask[ps[:, None], lidx]
-                    if has_mask
-                    else jnp.ones((W, M), bool)
-                ),
-            )
-
-        choices, committed, done, quse_new, ncommit = resolve_wave(
-            cand_key,
-            cand=cand,
-            universe=universe,
-            preq_wave=preq_wave,
-            pest_wave=pest_wave,
-            psreq_wave=psreq_wave,
-            pqid_wave=pqid_wave,
-            pvalid_wave=pvalid_wave,
-            pprod_wave=pprod_wave,
-            wvalid=wvalid,
-            qrt=qrt,
-            qlim=qlim,
-            quse=quse,
-            cfg=cfg,
-            n_total=N,
-            prod_sensitive=prod_sensitive,
-        )
-
-        # apply the committed prefix to the carried node state (an
-        # exact scatter-add; uncommitted rows target N and drop)
-        target = jnp.where(committed, choices, N)
-        nreq = nreq.at[target].add(preq_wave, mode="drop")
-        nest = nest.at[target].add(pest_wave, mode="drop")
-
-        write = jnp.where(done, choices.astype(jnp.int32), jnp.int32(-1))
-        # positions not committed this round keep their buffer value
-        # (they will be rewritten when their round comes)
-        window = lax.dynamic_slice(chosen_buf, (ptr,), (W,))
-        window = jnp.where(done, write, window)
-        chosen_buf = lax.dynamic_update_slice(chosen_buf, window, (ptr,))
-
-        return (ptr + ncommit, nreq, nest, quse_new, chosen_buf, nrounds + 1)
-
-    def cond(carry):
-        return carry[0] < PCAP
-
-    init = (
-        jnp.int64(0),
-        nodes.requested,
-        jnp.zeros_like(nodes.requested),
-        quotas.used,
-        jnp.full((PCAP + W,), -1, jnp.int32),
-        jnp.int64(0),
-    )
-    _, node_requested, node_estimated, quota_used, chosen_buf, nrounds = (
-        lax.while_loop(cond, wave_round, init)
-    )
-
-    assignment = (
-        jnp.full((PCAP,), -1, jnp.int32).at[order].set(chosen_buf[:PCAP])
-    )
-    # no Strict gang rollback in this engine (run_cycle and the bridge
-    # send it no gang): a gang short of minMember only WAITs here
-    status = jnp.where(assignment >= 0, STATUS_ASSIGNED, STATUS_UNSCHEDULABLE)
-    assigned = (assignment >= 0) & pods.valid
-    _, pod_gang_ok = gang_satisfaction(
-        assignment, pods.valid, pods.gang_id, gangs.min_member
-    )
-    status = jnp.where(assigned & ~pod_gang_ok, STATUS_WAIT_GANG, status)
-    return CycleResult(
-        assignment=assignment,
-        status=status.astype(jnp.int32),
-        node_requested=node_requested,
-        node_estimated=node_estimated,
-        quota_used=quota_used,
-        rounds=nrounds,
-        path="wave",
-    )
-
-
-def wave_assign(
-    snapshot: ClusterSnapshot,
-    cfg: CycleConfig = DEFAULT_CYCLE_CONFIG,
-    extra_mask: Optional[jnp.ndarray] = None,
-    extra_scores: Optional[jnp.ndarray] = None,
-    wave: Optional[int] = None,
-    top_m: Optional[int] = None,
-    scores_hi: Optional[int] = None,
-) -> CycleResult:
-    """Wave-batched drop-in for ``greedy_assign``: bit-identical
-    placements, ~W pods committed per sequential round.
-
-    ``wave``/``top_m`` default from the ``CycleConfig`` knobs; both are
-    STATIC jit arguments (a traced wave width would retrace every cycle
-    — the koordlint retrace-hazard rule enforces this at every jit
-    boundary).  Returns a ``CycleResult`` with ``rounds`` set to the
-    number of sequential wave rounds and ``path="wave"``.
-
-    ``scores_hi``: callers that already reduced ``extra_scores`` to its
-    max magnitude (the run_cycle dispatcher does, for its kernel bound)
-    pass it to skip a second blocking device->host reduction per cycle
-    — the ``i32_ok`` pattern.
-    """
-    W = int(cfg.wave if wave is None else wave)
-    M = int(cfg.top_m if top_m is None else top_m)
-    if W < 1 or M < 1:
-        raise ValueError(f"wave ({W}) and top_m ({M}) must be >= 1")
-    if extra_scores is not None:
-        # the packed key multiplies scores by N; plugin scores are tiny
-        # by construction, but extra_scores is caller-supplied — values
-        # at the sentinel's magnitude would decode as infeasible (or
-        # overflow the key), silently breaking parity
-        hi = (
-            int(jnp.max(jnp.abs(extra_scores)))
-            if scores_hi is None
-            else int(scores_hi)
-        )
-        if hi >= 2**31:
-            raise ValueError(
-                f"extra_scores magnitude {hi} too large for the packed "
-                "key (must be < 2^31); use solver.greedy_assign"
-            )
-    return _wave_assign(
-        snapshot,
-        extra_mask,
-        extra_scores,
-        cfg=cfg,
-        wave=W,
-        top_m=M,
-        has_mask=extra_mask is not None,
-        has_scores=extra_scores is not None,
-    )

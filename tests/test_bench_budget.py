@@ -412,7 +412,7 @@ class TestArtifactSchemaSpans:
         import inspect
 
         src = inspect.getsource(bench.child)
-        for key in ("lowering_probe", "wave_compile", "cpu_native_mt"):
+        for key in ("lowering_probe", "cpu_native_mt"):
             assert f'"{key}": None' in src
 
 

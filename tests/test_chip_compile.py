@@ -23,7 +23,7 @@ import re
 import jax
 import pytest
 
-from koordinator_tpu.config import DEFAULT_CYCLE_CONFIG, CycleConfig
+from koordinator_tpu.config import DEFAULT_CYCLE_CONFIG
 from koordinator_tpu.harness import generators
 
 POD_BUCKET, NODE_BUCKET = 16384, 2048
@@ -132,15 +132,6 @@ def test_dense_kernel_refused_past_node_limit(one_chip):
         _dense(one_chip, 2 * solver.DENSE_MAX_NODES, extras=False)
 
 
-def test_wide_wave_kernel_served_buckets(one_chip):
-    from koordinator_tpu.solver.pallas_cycle import _greedy_assign_pallas
-
-    compiled = _greedy_assign_pallas.__wrapped__.lower(
-        _spec(one_chip), CycleConfig(wave=32, top_m=4)
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_score_cycle_served_buckets(one_chip):
     from koordinator_tpu.solver.greedy import score_cycle
 
@@ -178,7 +169,10 @@ def test_shard_wave_cycle_on_four_chips(topo):
         cluster_mesh,
         snapshot_shardings,
     )
-    from koordinator_tpu.parallel.shard_assign import _assign_waves
+    from koordinator_tpu.parallel.shard_assign import (
+        WAVE_WIDTH,
+        _assign_waves,
+    )
 
     mesh = cluster_mesh(topo.devices[:4])
     snap = generators.quota_colocation_snapshot(
@@ -191,8 +185,7 @@ def test_shard_wave_cycle_on_four_chips(topo):
     )
     compiled = _assign_waves.__wrapped__.lower(
         spec, None, None, cfg=DEFAULT_CYCLE_CONFIG, mesh=mesh,
-        has_mask=False, has_scores=False, wave=32,
-        top_m=DEFAULT_CYCLE_CONFIG.top_m,
+        has_mask=False, has_scores=False, wave=WAVE_WIDTH, top_m=4,
     ).compile()
     assert "all-gather" in compiled.as_text()
 

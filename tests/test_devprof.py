@@ -59,9 +59,6 @@ _EXPECTED = {
     "solver.candidates.sparse_top_k",
     "solver.topk.masked_top_k",
     "solver.terms._term_extras_jit",
-    "solver.wave._wave_assign",
-    "solver.pallas_cycle._run_cycle",
-    "solver.pallas_cycle._greedy_assign_pallas",
     "solver.pallas_dense._run_cycle_dense",
     "solver.pallas_dense._greedy_assign_dense",
     "parallel.shard_assign._assign_sharded",
@@ -85,8 +82,6 @@ class TestRegistrationCompleteness:
             "koordinator_tpu.solver.candidates",
             "koordinator_tpu.solver.topk",
             "koordinator_tpu.solver.terms",
-            "koordinator_tpu.solver.wave",
-            "koordinator_tpu.solver.pallas_cycle",
             "koordinator_tpu.solver.pallas_dense",
             "koordinator_tpu.parallel.shard_assign",
         ):

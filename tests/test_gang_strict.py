@@ -10,7 +10,6 @@ test's size, its memory-heavy draws making gangs fail partway.
 """
 
 import copy
-import dataclasses
 import json
 import os
 
@@ -23,7 +22,6 @@ from benchmark.harness.loop import Loop
 from koordinator_tpu.bridge.codegen import pb2
 from koordinator_tpu.bridge.server import ScorerServicer
 from koordinator_tpu.bridge.state import ResidentState, numpy_to_tensor
-from koordinator_tpu.config import CycleConfig
 from koordinator_tpu.model import encode_snapshot
 from koordinator_tpu.solver import (
     STATUS_ASSIGNED,
@@ -31,7 +29,6 @@ from koordinator_tpu.solver import (
     STATUS_WAIT_GANG,
     greedy_assign,
     pallas_inputs_fit_i32,
-    run_cycle,
 )
 from koordinator_tpu.solver.greedy import queue_order
 from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
@@ -320,25 +317,6 @@ def _gang_request(seed=5):
     loop.advance()
     state = loop.state()
     return _sync_request(state, [8] * 40), state, config
-
-
-@pytest.mark.parametrize("wave", [8, 32])
-def test_wave_config_serves_gangs_by_scan(wave):
-    """(i) with ``cfg.wave > 1`` a snapshot with gangs is served by the
-    dense kernel or the scan (the scan on the CPU), equal to the
-    reference; a gang-free snapshot still takes the wave path."""
-    req, state, config = _gang_request()
-    sv = ScorerServicer(CycleConfig(wave=wave, top_m=4))
-    sv.sync(req)
-    reply = sv.assign(pb2.AssignRequest(snapshot_id=sv.snapshot_id()))
-    assert reply.path == "scan"
-    a, s = np.asarray(reply.assignment), np.asarray(reply.status)
-    want = reference.assign(state, config["scoring"])
-    np.testing.assert_array_equal(np.where(s == STATUS_ASSIGNED, a, -1), want)
-    snap = _resident(state, [8] * 40).snapshot()
-    free = dataclasses.replace(snap, pods=dataclasses.replace(
-        snap.pods, gang_id=np.full(snap.pods.gang_id.shape, -1, np.int32)))
-    assert run_cycle(free, CycleConfig(wave=wave, top_m=4)).path == "wave"
 
 
 def test_mesh_servicer_serves_gangs_off_the_shard_path():

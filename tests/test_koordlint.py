@@ -2554,12 +2554,12 @@ class TestPrewarmDrift:
         from koordinator_tpu.analysis import prewarmdrift
 
         regs = self.REGISTRATIONS + [
-            ("solver.wave._wave_assign", "koordinator_tpu/solver/wave.py", 42),
+            ("solver.wave._resolve_jit", "koordinator_tpu/solver/wave.py", 42),
         ]
         got = prewarmdrift.diff_prewarm(regs, self.PREWARM_FIXTURE)
         assert len(got) == 1
         assert got[0].rule == "prewarm-drift"
-        assert "solver.wave._wave_assign" in got[0].message
+        assert "solver.wave._resolve_jit" in got[0].message
         assert "absent from both prewarm tables" in got[0].message
         # flags the registration's own file and line
         assert got[0].path.endswith("wave.py")

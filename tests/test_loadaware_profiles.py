@@ -12,7 +12,8 @@ Reference semantics (``pkg/scheduler/plugins/loadaware/load_aware.go``):
   that percentile.
 
 Three-way parity: lax.scan vs the sequential oracle (independent
-implementation), Pallas (interpret) vs scan, shard_map vs scan.
+implementation), the dense Pallas kernel (interpret) vs scan, shard_map
+vs scan.
 """
 
 import numpy as np
@@ -25,7 +26,7 @@ from koordinator_tpu.harness.reference import ReferenceCycle
 from koordinator_tpu.model import encode_snapshot, resources as res
 from koordinator_tpu.model.snapshot import PERCENTILES
 from koordinator_tpu.solver import greedy_assign, score_cycle
-from koordinator_tpu.solver.pallas_cycle import greedy_assign_pallas
+from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
 Mi = 1024 * 1024
 Gi = 1024 * Mi
@@ -170,7 +171,7 @@ def test_pallas_matches_scan(cfg):
     snap = encode_snapshot(nodes, pods)
     want = np.asarray(greedy_assign(snap, cfg).assignment)
     got = np.asarray(
-        greedy_assign_pallas(snap, cfg, interpret=True).assignment
+        greedy_assign_dense(snap, cfg, interpret=True).assignment
     )
     np.testing.assert_array_equal(got, want)
 
@@ -195,14 +196,14 @@ def test_shard_matches_scan():
 
 def test_default_config_unaffected():
     """No aggregated/prod config -> identical to the legacy single-mask
-    path (flags lane2 == lane0, no extra kernel operand)."""
+    path (the prod-ok flag row equals the ok row)."""
     nodes, pods = _cluster(seed=11)
     for nd in nodes:
         nd.pop("agg_usage", None)
         nd.pop("prod_usage", None)
     snap = encode_snapshot(nodes, pods)
     a = np.asarray(greedy_assign(snap).assignment)
-    b = np.asarray(greedy_assign_pallas(snap, interpret=True).assignment)
+    b = np.asarray(greedy_assign_dense(snap, interpret=True).assignment)
     np.testing.assert_array_equal(a, b)
 
 

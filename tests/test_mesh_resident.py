@@ -4,8 +4,8 @@ The contract under test, in three parts:
 
 * **bit parity** — a servicer whose resident snapshot is sharded over a
   cluster mesh must answer Sync/Score/Assign byte-identically to the
-  single-chip oracle, across wave widths and mesh sizes (the ISSUE 7
-  acceptance fuzz: wave ∈ {1, 32} × mesh ∈ {1, 2, 8} forced-host
+  single-chip oracle, with LoadAware on and off and across mesh sizes
+  (the ISSUE 7 acceptance fuzz over mesh ∈ {1, 2, 8} forced-host
   devices);
 * **shard-local warm path** — a delta Sync lands as a shard-local
   scatter (solver/resident.py ``_scatter_flat_sharded``) and the
@@ -56,19 +56,20 @@ def _score_fields(reply):
 
 class TestMeshParityFuzz:
     @pytest.mark.parametrize("mesh_size", [1, 2, 8])
-    @pytest.mark.parametrize("wave", [1, 32])
-    def test_mesh_cycles_bit_identical_to_single_chip(self, mesh_size, wave):
+    @pytest.mark.parametrize("loadaware", [True, False])
+    def test_mesh_cycles_bit_identical_to_single_chip(self, mesh_size,
+                                                      loadaware):
         """The ISSUE 7 acceptance fuzz: drive the SAME wire frames (one
         full Sync, then randomized warm mutations — sparse deltas, full
         tensors, scalar-column churn, resizes) through a single-chip
         oracle and a mesh-resident servicer, asserting every Assign and
         Score reply identical at each step."""
-        rng = np.random.RandomState(100 + 8 * mesh_size + wave)
+        rng = np.random.RandomState(200 + 8 * mesh_size + loadaware)
         state = _random_state(rng, n_nodes=9, n_pods=24, with_quota=True)
         # the shard cycle has no Strict gang rollback: a snapshot with
         # gangs is served off it (run_cycle), so this fuzz is gang-free
         state["pod_gang"][:] = -1
-        cfg = CycleConfig(wave=wave, top_m=2)
+        cfg = CycleConfig(enable_loadaware=loadaware)
         oracle = ScorerServicer(cfg)
         meshed = ScorerServicer(
             cfg,
@@ -86,9 +87,9 @@ class TestMeshParityFuzz:
                 pb2.AssignRequest(snapshot_id=meshed.snapshot_id())
             )
             # identical placements/statuses; the paths legitimately
-            # differ (shard vs wave/scan) — that is the point
-            assert a.assignment == b.assignment, (mesh_size, wave, step)
-            assert a.status == b.status, (mesh_size, wave, step)
+            # differ (shard vs scan) — that is the point
+            assert a.assignment == b.assignment, (mesh_size, loadaware, step)
+            assert a.status == b.status, (mesh_size, loadaware, step)
             assert b.path == "shard"
             sa = oracle.score(pb2.ScoreRequest(
                 snapshot_id=oracle.snapshot_id(), top_k=3, flat=True
@@ -97,7 +98,7 @@ class TestMeshParityFuzz:
                 snapshot_id=meshed.snapshot_id(), top_k=3, flat=True
             ))
             assert _score_fields(sa) == _score_fields(sb), (
-                mesh_size, wave, step
+                mesh_size, loadaware, step
             )
             mreq, _ = _mutate(rng, state)
             oracle.sync(mreq)
@@ -109,9 +110,7 @@ class TestMeshParityFuzz:
         hold the same parity on the mesh-resident snapshot."""
         rng = np.random.RandomState(77)
         state = _random_state(rng, n_nodes=8, n_pods=20, with_quota=False)
-        cfg = CycleConfig(
-            wave=8, top_m=2, fit_scoring_strategy=MOST_ALLOCATED
-        )
+        cfg = CycleConfig(fit_scoring_strategy=MOST_ALLOCATED)
         oracle = ScorerServicer(cfg)
         meshed = ScorerServicer(
             cfg, mesh=cluster_mesh(jax.devices()), mesh_resident=True

@@ -34,10 +34,10 @@ from koordinator_tpu.obs.spans import MAX_SPANS_PER_CYCLE, SpanRecorder
 from test_resident_warm import _full_sync_request, _random_state
 
 
-def _servicer(tmp=None, cfg=None, gang_free=False):
+def _servicer(tmp=None, gang_free=False, mesh=None):
     kwargs = {"state_dir": tmp} if tmp else {}
-    if cfg is not None:
-        kwargs["cfg"] = cfg
+    if mesh is not None:
+        kwargs["mesh"] = mesh
     sv = ScorerServicer(**kwargs)
     rng = np.random.RandomState(3)
     state = _random_state(rng, n_nodes=4, n_pods=8, with_quota=False)
@@ -202,7 +202,7 @@ class TestFlightRecorder:
     def test_dump_writes_schema_valid_json(self, tmp_path):
         fr = FlightRecorder(
             capacity=8, state_dir=str(tmp_path),
-            config={"wave": 8, "top_m": 2, "epoch": "e1"},
+            config={"fit_scoring_strategy": "MostAllocated", "epoch": "e1"},
         )
         for i in range(3):
             fr.record(self._record(i))
@@ -212,7 +212,7 @@ class TestFlightRecorder:
             doc = json.load(f)
         assert validate_flight_dump(doc) == []
         assert doc["reason"] == "manual"
-        assert doc["config"]["wave"] == 8
+        assert doc["config"]["fit_scoring_strategy"] == "MostAllocated"
         assert [c["cycle_id"] for c in doc["cycles"]] == ["c-0", "c-1", "c-2"]
 
     def test_dump_without_state_dir_is_none(self):
@@ -366,22 +366,35 @@ class TestServicerTelemetry:
         names = [s["name"] for s in rec["spans"]]
         assert "sync_decode" in names  # the Sync stage of this cycle
         assert "dispatch" in names and "readback" in names
-        assert rec["notes"]["path"] in ("scan", "wave", "pallas")
+        assert rec["notes"]["path"] in ("scan", "pallas")
         assert rec["snapshot_id"] == reply.snapshot_id
 
     def test_wave_cycle_notes_rounds(self, tmp_path):
-        from koordinator_tpu.config import CycleConfig
+        """The mesh servicer's round-based cycle notes its rounds, and
+        its latency series carries the wave width that ran."""
+        import jax
 
-        # a snapshot with Strict gangs is served off the wave path
+        from koordinator_tpu.parallel import cluster_mesh
+        from koordinator_tpu.parallel.shard_assign import WAVE_WIDTH
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 (virtual) devices")
+        # a snapshot with Strict gangs is served off the shard path
         sv, state, reply = _servicer(
-            str(tmp_path), cfg=CycleConfig(wave=4, top_m=2), gang_free=True
+            str(tmp_path), gang_free=True,
+            mesh=cluster_mesh(jax.devices()[:4]),
         )
         sv.assign(pb2.AssignRequest(snapshot_id=reply.snapshot_id))
         rec = sv.telemetry.flight.snapshot()[-1]
-        assert rec["notes"]["path"] == "wave"
+        assert rec["notes"]["path"] == "shard"
         assert rec["notes"]["rounds"] >= 1
         reg = sv.telemetry.registry
-        assert reg.get("koord_scorer_cycle_rounds", {"path": "wave"}) >= 1
+        assert reg.get("koord_scorer_cycle_rounds", {"path": "shard"}) >= 1
+        count, _ = reg.get_histogram(
+            "koord_scorer_cycle_latency_ms",
+            {"path": "shard", "wave": str(WAVE_WIDTH)},
+        )
+        assert count == 1
 
     def test_sync_score_assign_correlates_one_record(self, tmp_path):
         """The standard plugin flow (Sync → Score → Assign(cycle_id)):

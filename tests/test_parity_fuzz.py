@@ -2,16 +2,16 @@
 
 Every feature dimension the cycle supports — quotas, gangs, stale
 metrics, prod/aggregated LoadAware profiles, mixed priority bands — is
-sampled randomly and every device path — the wide Pallas kernel, the
-dense-layout kernel (both interpret), and the round-based shard_map wave
-path — must match the lax.scan oracle bit-for-bit on assignments AND
-post-cycle state.  This is the drift alarm for the five-implementation
-invariant the framework maintains (scan / wide / dense / waves, plus the
-C++ baseline in tests/test_native_bridge.py).
+sampled randomly and every device path — the dense-layout Pallas kernel
+(interpret, plain and its Strict-gang variant) and the round-based
+shard_map wave path — must match the lax.scan oracle bit-for-bit on
+assignments AND post-cycle state.  This is the drift alarm for the
+invariant the framework maintains (scan / dense / waves, plus the C++
+baseline in tests/test_native_bridge.py).
 
 Strict gangs roll back only in the scan and the dense kernel
-(solver.run_cycle never sends a gang to the others), so the wide, wave
-and shard engines meet each fuzzed input with its gang ids cleared.
+(solver.run_cycle and the bridge never send a gang to the shard path),
+so the wave path meets each fuzzed input with its gang ids cleared.
 """
 
 import dataclasses
@@ -25,7 +25,7 @@ from koordinator_tpu.constraints import build_quota_table_inputs
 from koordinator_tpu.model import encode_snapshot, resources as res
 from koordinator_tpu.model.snapshot import PERCENTILES
 from koordinator_tpu.solver import greedy_assign
-from koordinator_tpu.solver.pallas_cycle import greedy_assign_pallas
+from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
 Gi = 1024 * 1024 * 1024
 
@@ -173,8 +173,8 @@ def _fuzz_snapshot(seed):
 
 
 def _gang_free(snap):
-    """``snap`` with every pod's gang id cleared: the input the engines
-    without a Strict rollback (wide, wave, shard) are served."""
+    """``snap`` with every pod's gang id cleared: the input the engine
+    without a Strict rollback (the shard path) is served."""
     pods = dataclasses.replace(
         snap.pods, gang_id=np.full(snap.pods.gang_id.shape, -1, np.int32)
     )
@@ -198,17 +198,19 @@ def _assert_matches(want, got, seed):
 
 @pytest.mark.parametrize("seed", range(8))
 def test_scan_pallas_parity_fuzz(seed):
+    """The dense kernel's Strict-gang variant, forced on for a gang-free
+    input, runs every pod through its gang-free gate: placements and
+    state equal the scan's."""
     snap, cfg = _fuzz_snapshot(seed)
     snap = _gang_free(snap)
     want = greedy_assign(snap, cfg)
-    _assert_matches(want, greedy_assign_pallas(snap, cfg, interpret=True), seed)
+    got = greedy_assign_dense(snap, cfg, interpret=True, has_gangs=True)
+    _assert_matches(want, got, seed)
 
 
 @pytest.mark.parametrize("seed", range(8))
 def test_scan_dense_parity_fuzz(seed):
     """The dense-layout kernel holds the same fuzzed invariant."""
-    from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
-
     snap, cfg = _fuzz_snapshot(seed)
     want = greedy_assign(snap, cfg)
     _assert_matches(want, greedy_assign_dense(snap, cfg, interpret=True), seed)
@@ -274,7 +276,6 @@ def test_dense_skips_padding_rows(n, variant):
     post-cycle state, every invalid row reads -1, and ``rounds`` counts
     the pod steps run, the pods valid after PreFilter."""
     from koordinator_tpu.constraints.gang import strict_gangs
-    from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
     snap = _tail_snapshot(n, variant)
     extras = {}
@@ -309,74 +310,50 @@ def test_dense_skips_padding_rows(n, variant):
         assert cpu == (n - 8) * 100
 
 
-@pytest.mark.parametrize("seed", range(4))
-def test_scan_waves_parity_fuzz(seed):
-    """The round-based sharded path holds it too (node_requested comes
-    back node-sharded; gang/quota/prod dimensions all sampled)."""
+# the round-based sweep: the default width (32, 4) on one cluster
+# family, then wave widths x candidate depths on two more, every feature
+# dimension of _fuzz_snapshot sampled underneath
+WAVE_GRID = [(1, 1), (8, 4), (32, 1), (32, 4)]
+WAVE_FUZZ = (
+    [(seed, 32, 4) for seed in range(100, 104)]
+    + [(seed, w, m) for seed in range(200, 203) for w, m in WAVE_GRID]
+    + [(seed, w, m) for seed in range(300, 302) for w, m in [(8, 4), (32, 1)]]
+)
+
+
+@pytest.mark.parametrize("seed,wave,top_m", WAVE_FUZZ)
+def test_scan_waves_parity_fuzz(seed, wave, top_m):
+    """The round-based sharded path holds it too, at every (wave, top_m)
+    width (node_requested comes back node-sharded; quota/prod dimensions
+    all sampled)."""
     import jax
 
     from koordinator_tpu.parallel import greedy_assign_waves, make_mesh
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    seed = seed + 100  # distinct cluster family from the kernel fuzz
     snap, cfg = _fuzz_snapshot(seed)
     snap = _gang_free(snap)
     want = greedy_assign(snap, cfg)
-    got, rounds = greedy_assign_waves(snap, make_mesh(), cfg)
+    got, rounds = greedy_assign_waves(
+        snap, make_mesh(), cfg, wave=wave, top_m=top_m
+    )
     _assert_matches(want, got, seed)
-    assert rounds >= 1
-
-
-# the ISSUE-3 sweep: wave widths x candidate depths, every feature
-# dimension of _fuzz_snapshot sampled underneath
-WAVE_GRID = [(1, 1), (8, 4), (32, 1), (32, 4)]
-
-
-@pytest.mark.parametrize("seed", range(3))
-@pytest.mark.parametrize("wave,top_m", WAVE_GRID)
-def test_scan_wave_assign_parity_fuzz(seed, wave, top_m):
-    """The single-chip wave path (solver/wave.py wave_assign) is
-    bit-identical with the scan across the full random feature matrix,
-    at every (wave, top_m) knob setting."""
-    from koordinator_tpu.solver import wave_assign
-
-    snap, cfg = _fuzz_snapshot(seed + 200)
-    snap = _gang_free(snap)
-    want = greedy_assign(snap, cfg)
-    got = wave_assign(snap, cfg, wave=wave, top_m=top_m)
-    _assert_matches(want, got, seed)
-    rounds = int(np.asarray(got.rounds))
     assert 1 <= rounds <= snap.pods.capacity
-
-
-@pytest.mark.parametrize("seed", range(2))
-@pytest.mark.parametrize("wave,top_m", [(8, 4), (32, 1)])
-def test_wave_pallas_parity_fuzz(seed, wave, top_m):
-    """The wave Pallas kernel (interpret mode) holds the same fuzzed
-    invariant through its i32 unpacked-key resolution."""
-    snap, cfg = _fuzz_snapshot(seed + 300)
-    snap = _gang_free(snap)
-    cfg = dataclasses.replace(cfg, wave=wave, top_m=top_m)
-    want = greedy_assign(snap, cfg)  # the scan ignores the wave knobs
-    got = greedy_assign_pallas(snap, cfg, interpret=True)
-    _assert_matches(want, got, seed)
-    assert int(np.asarray(got.rounds)) >= 1
 
 
 class TestWaveDirectedCases:
     """The adversarial shapes the certification argument must survive
     (ISSUE 3): gang minMember boundaries, quota exhaustion mid-wave, and
-    total contention where every wave degrades to a single commit."""
+    total contention where every wave degrades to a single commit.  The
+    wave cases run the round-based cycle on the test mesh."""
 
     def test_gang_minmember_boundary(self):
         """Gangs sized exactly at/below minMember: the Strict rollback's
         WAIT_GANG statuses must match the scan bit-for-bit through the
-        dense kernel, the engine run_cycle serves a gang with whatever
-        wave width it asks (the wave path has no rollback)."""
+        dense kernel, the engine run_cycle serves a gang with on TPU."""
         from koordinator_tpu.harness import generators
         from koordinator_tpu.model import encode_snapshot
-        from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
         # 2 nodes x small gangs: some gangs land exactly minMember
         # members, some fall short and are released
@@ -397,11 +374,11 @@ class TestWaveDirectedCases:
         pods commit as unschedulable in-wave (node-invariant recheck)
         and quota accounting matches the scan exactly."""
         from koordinator_tpu.harness import generators
-        from koordinator_tpu.solver import wave_assign
+        from koordinator_tpu.parallel import greedy_assign_waves, make_mesh
 
         snap = generators.quota_colocation_snapshot(pods=96, nodes=8)[0]
         want = greedy_assign(snap)
-        got = wave_assign(snap, wave=16, top_m=4)
+        got, _ = greedy_assign_waves(snap, make_mesh(), wave=16, top_m=4)
         _assert_matches(want, got, "quota-mid-wave")
 
     @pytest.mark.parametrize("top_m", [1, 4])
@@ -409,8 +386,10 @@ class TestWaveDirectedCases:
         """Worst case: one big node dominates scoring, every pod's top
         candidate is the same node, and each wave certifies exactly one
         commit — parity must hold and rounds approach pod count."""
+        import jax
+
         from koordinator_tpu.model import encode_snapshot
-        from koordinator_tpu.solver import wave_assign
+        from koordinator_tpu.parallel import greedy_assign_waves, make_mesh
 
         Gi2 = 1 << 30
         nodes = [
@@ -436,9 +415,10 @@ class TestWaveDirectedCases:
         ]
         snap = encode_snapshot(nodes, pods, [], [])
         want = greedy_assign(snap)
-        got = wave_assign(snap, wave=8, top_m=top_m)
+        got, rounds = greedy_assign_waves(
+            snap, make_mesh(jax.devices()[:2]), wave=8, top_m=top_m
+        )
         _assert_matches(want, got, f"contention-top{top_m}")
-        rounds = int(np.asarray(got.rounds))
         # with top_m=1 the contended waves degrade toward one commit
         # per round; the point here is exactness, not speed
         assert rounds >= 1

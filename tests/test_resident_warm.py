@@ -232,10 +232,10 @@ class TestWarmParityFuzz:
 
     @pytest.mark.parametrize("seed", (0, 1))
     def test_warm_resident_matches_cold_on_pallas_interpret(self, seed):
-        """The resident-device snapshot feeds the Pallas kernel too: the
-        interpret-mode kernel must produce the same placements from the
-        warm-updated arrays as from a cold re-encode."""
-        from koordinator_tpu.solver.pallas_cycle import greedy_assign_pallas
+        """The resident-device snapshot feeds the dense Pallas kernel
+        too: the interpret-mode kernel must produce the same placements
+        from the warm-updated arrays as from a cold re-encode."""
+        from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 
         rng = np.random.RandomState(100 + seed)
         state = _random_state(rng, n_nodes=6, n_pods=16, with_quota=True)
@@ -245,10 +245,10 @@ class TestWarmParityFuzz:
             warm.state.snapshot()
             req, _ = _mutate(rng, state)
             warm.sync(req)
-        warm_res = greedy_assign_pallas(
+        warm_res = greedy_assign_dense(
             warm.state.snapshot(), interpret=True
         )
-        cold_res = greedy_assign_pallas(
+        cold_res = greedy_assign_dense(
             _cold_oracle(state).state.snapshot(), interpret=True
         )
         np.testing.assert_array_equal(
@@ -599,20 +599,27 @@ class TestRetraceBudget:
         assert counter.traces == 0 and counter.compiles == 0
         assert len(reply.assignment) == len(first.assignment)
 
-    def test_warm_sync_wave_assign_sequence_is_retrace_free(self):
-        """The wave-batched cycle holds the same compile economics: with
-        wave/top_m riding the STATIC CycleConfig, a warm delta-Sync +
-        wave-Assign stream must hit zero jit cache misses after one
-        warm-up cycle (a traced wave width would retrace every cycle —
-        the hazard the koordlint rule rejects statically)."""
-        from koordinator_tpu.analysis import retrace_guard
-        from koordinator_tpu.config import CycleConfig
+    def test_warm_sync_shard_assign_sequence_is_retrace_free(self):
+        """The round-based wave cycle holds the same compile economics:
+        with its width a static constant, a warm delta-Sync + shard
+        Assign stream on a 2-device mesh servicer (snapshot not
+        mesh-resident) must hit zero jit cache misses after one warm-up
+        cycle (a traced wave width would retrace every cycle — the
+        hazard the koordlint rule rejects statically), and its latency
+        series carries the width that ran."""
+        import jax
 
+        from koordinator_tpu.analysis import retrace_guard
+        from koordinator_tpu.parallel import cluster_mesh
+        from koordinator_tpu.parallel.shard_assign import WAVE_WIDTH
+
+        if len(jax.devices()) < 2:
+            pytest.skip("needs 2 (virtual) devices")
         rng = np.random.RandomState(23)
         state = _random_state(rng, n_nodes=5, n_pods=12, with_quota=False)
-        # a snapshot with Strict gangs is served off the wave path
+        # a snapshot with Strict gangs is served off the shard path
         state["pod_gang"][:] = -1
-        sv = ScorerServicer(CycleConfig(wave=8, top_m=2))
+        sv = ScorerServicer(mesh=cluster_mesh(jax.devices()[:2]))
         sv.sync(_full_sync_request(state))
         sv.state.snapshot()
         first = self._warm_step(sv, state)
@@ -620,8 +627,13 @@ class TestRetraceBudget:
             for _ in range(4):
                 reply = self._warm_step(sv, state)
         assert counter.traces == 0 and counter.compiles == 0
-        assert reply.path == "wave"
+        assert reply.path == "shard"
         assert len(reply.assignment) == len(first.assignment)
+        count, _ = sv.telemetry.registry.get_histogram(
+            "koord_scorer_cycle_latency_ms",
+            {"path": "shard", "wave": str(WAVE_WIDTH)},
+        )
+        assert count >= 5
 
     def test_warm_sequence_with_telemetry_enabled_is_retrace_free(self):
         """ISSUE 4 acceptance: telemetry is ALWAYS on in the servicer

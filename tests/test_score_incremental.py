@@ -309,10 +309,11 @@ class TestDirtyMechanics:
         sv, state, _rng = self._servicer()
         _flat(sv)
         sv.sync(self._node_delta(state))
-        sv.cfg = CycleConfig(wave=8, top_m=2)  # operator reconfig
+        # operator reconfig
+        sv.cfg = CycleConfig(fit_scoring_strategy="MostAllocated")
         oracle = ScorerServicer(
-            CycleConfig(wave=8, top_m=2), score_memo=False,
-            score_incr=False,
+            CycleConfig(fit_scoring_strategy="MostAllocated"),
+            score_memo=False, score_incr=False,
         )
         oracle.sync(_full_sync_request(state))
         assert _flat(sv) == _flat(oracle)

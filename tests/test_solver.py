@@ -260,14 +260,11 @@ class TestPrelaunchKernelChoice:
         with the named Pallas entry points replaced."""
         import jax
 
-        from koordinator_tpu.solver import pallas_cycle, pallas_dense
+        from koordinator_tpu.solver import pallas_dense
 
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        mods = {"dense": (pallas_dense, "greedy_assign_dense"),
-                "wide": (pallas_cycle, "greedy_assign_pallas")}
         for name, fn in kernels.items():
-            mod, attr = mods[name]
-            monkeypatch.setattr(mod, attr, fn)
+            monkeypatch.setattr(pallas_dense, f"greedy_assign_{name}", fn)
 
     def test_kernel_fault_raises(self, monkeypatch):
         import pytest
@@ -277,12 +274,12 @@ class TestPrelaunchKernelChoice:
         def boom(*a, **k):
             raise RuntimeError("kernel refused")
 
-        self._on_tpu(monkeypatch, dense=boom, wide=boom)
+        self._on_tpu(monkeypatch, dense=boom)
         nodes, pods, gangs, quotas = generators.loadaware_joint(
             seed=2, pods=16, nodes=8
         )
         snap = encode_snapshot(nodes, pods, gangs, quotas)
-        for cfg in (CycleConfig(), CycleConfig(wave=8, top_m=2)):
+        for cfg in (CycleConfig(), CycleConfig(enable_loadaware=False)):
             with pytest.raises(RuntimeError, match="kernel refused"):
                 run_cycle(snap, cfg)
 
@@ -292,14 +289,13 @@ class TestPrelaunchKernelChoice:
         def never(*a, **k):
             raise AssertionError("an over-limit bucket reached a kernel")
 
-        self._on_tpu(monkeypatch, dense=never, wide=never)
+        self._on_tpu(monkeypatch, dense=never)
         nodes, pods, gangs, quotas = generators.loadaware_joint(
             seed=3, pods=24, nodes=12
         )
         snap = encode_snapshot(nodes, pods, gangs, quotas,
                                node_bucket=256)
         monkeypatch.setattr(solver, "DENSE_MAX_NODES", 128)
-        monkeypatch.setattr(solver, "WIDE_MAX_NODES", 128)
         got = solver.run_cycle(snap)
         assert got.path == "scan"
         np.testing.assert_array_equal(
@@ -307,21 +303,42 @@ class TestPrelaunchKernelChoice:
             np.asarray(greedy_assign(snap).assignment),
         )
 
+    def test_oversized_extra_scores_take_scan(self, monkeypatch):
+        """extra_scores past the kernel's i32 headroom (>= 2^29) send the
+        cycle to the bit-identical scan before launch: run_cycle never
+        raises for in-contract inputs."""
+        from koordinator_tpu.solver import run_cycle
+
+        def never(*a, **k):
+            raise AssertionError("oversized extras reached the kernel")
+
+        self._on_tpu(monkeypatch, dense=never)
+        nodes, pods, gangs, quotas = generators.loadaware_joint(
+            seed=9, pods=24, nodes=6
+        )
+        snap = encode_snapshot(nodes, pods, gangs, quotas)
+        P = snap.pods.capacity
+        N = snap.nodes.allocatable.shape[0]
+        big = jnp.full((P, N), 2**29, jnp.int64)
+        got = run_cycle(snap, extra_scores=big)
+        assert got.path == "scan"
+        want = greedy_assign(snap, extra_scores=big)
+        np.testing.assert_array_equal(
+            np.asarray(got.assignment), np.asarray(want.assignment)
+        )
+
     def test_variant_limits(self):
         from koordinator_tpu.solver import (
             DENSE_MAX_NODES,
             DENSE_MAX_NODES_EXTRAS,
-            WIDE_MAX_NODES,
-            pallas_variant,
+            dense_kernel_fits,
         )
 
-        assert pallas_variant(2000, False, 1) == "dense"
-        assert pallas_variant(2000, False, 32) == "wide"
-        assert pallas_variant(WIDE_MAX_NODES + 1, False, 32) == "dense"
-        assert pallas_variant(DENSE_MAX_NODES, False, 1) == "dense"
-        assert pallas_variant(DENSE_MAX_NODES + 1, False, 1) is None
-        assert pallas_variant(DENSE_MAX_NODES_EXTRAS, True, 1) == "dense"
-        assert pallas_variant(DENSE_MAX_NODES_EXTRAS + 1, True, 1) is None
+        assert dense_kernel_fits(2000, False)
+        assert dense_kernel_fits(DENSE_MAX_NODES, False)
+        assert not dense_kernel_fits(DENSE_MAX_NODES + 1, False)
+        assert dense_kernel_fits(DENSE_MAX_NODES_EXTRAS, True)
+        assert not dense_kernel_fits(DENSE_MAX_NODES_EXTRAS + 1, True)
 
 
 @pytest.mark.parametrize("gangs", [False, True])

@@ -8,9 +8,10 @@ objective + headroom mask.  Covered here:
 * plain-numpy oracle parity — every term's device contribution is
   re-derived by an independent numpy implementation, cell for cell,
   on fuzzed snapshots with gang/quota interaction;
-* Assign parity across wave in {1, 8, 32} — ``run_cycle`` with a
-  term-enabled config equals ``greedy_assign`` fed the numpy oracle's
-  tensors through the extras seam, bit for bit;
+* Assign parity on every Assign engine — ``run_cycle`` with a
+  term-enabled config, the dense Pallas kernel and the round-based
+  shard path fed the device term tensors through their extras seam all
+  equal ``greedy_assign`` fed the numpy oracle's tensors, bit for bit;
 * mesh parity on {1, 8} devices — the mesh-resident servicer's warm
   term-delta stream is byte-identical to the single-chip servicer's;
 * dirty-set attribution — sensitivity deltas dirty exactly the touched
@@ -44,6 +45,7 @@ from koordinator_tpu.config import (
 )
 from koordinator_tpu.model import resources as res
 from koordinator_tpu.model.snapshot import MAX_NODE_SCORE, encode_snapshot
+from koordinator_tpu.parallel import greedy_assign_waves, make_mesh
 from koordinator_tpu.solver import (
     greedy_assign,
     masked_top_k,
@@ -51,6 +53,7 @@ from koordinator_tpu.solver import (
     score_cycle,
     score_upper_bound,
 )
+from koordinator_tpu.solver.pallas_dense import greedy_assign_dense
 from koordinator_tpu.solver.terms import (
     default_term_config,
     term_extras,
@@ -256,11 +259,29 @@ class TestNumpyOracleParity:
 
 class TestAssignWaveParity:
     @pytest.mark.parametrize("seed", range(3))
-    @pytest.mark.parametrize("wave", [1, 8, 32])
-    def test_run_cycle_terms_equal_oracle_extras(self, seed, wave):
+    @pytest.mark.parametrize("engine", ["run_cycle", "dense", "waves"])
+    def test_run_cycle_terms_equal_oracle_extras(self, seed, engine):
         snap = _term_snapshot(seed, n_nodes=10, n_pods=18)
-        cfg = dataclasses.replace(ALL_TERMS, wave=wave)
-        got = run_cycle(snap, cfg)
+        if engine == "waves":
+            # the shard path is never served a gang (no rollback)
+            snap = dataclasses.replace(snap, pods=dataclasses.replace(
+                snap.pods,
+                gang_id=np.full(snap.pods.gang_id.shape, -1, np.int32),
+            ))
+        if engine == "run_cycle":
+            got = run_cycle(snap, ALL_TERMS)
+        else:
+            t_scores, t_mask = term_extras(snap, ALL_TERMS)
+            extras = dict(extra_mask=t_mask, extra_scores=t_scores)
+            if engine == "dense":
+                got = greedy_assign_dense(
+                    snap, CycleConfig(), interpret=True, **extras
+                )
+            else:
+                got, _ = greedy_assign_waves(
+                    snap, make_mesh(jax.devices()[:2]), CycleConfig(),
+                    **extras,
+                )
         xs, xm = np_term_tensors(snap, ALL_TERMS)
         want = greedy_assign(
             snap, CycleConfig(),

@@ -1,9 +1,9 @@
 """solver/wave.py unit tests: the packed-key encode/decode and the
 in-wave certification resolver, exercised directly (the full-cycle
 bit-parity lives in tests/test_parity_fuzz.py / test_parallel.py — here
-the SHARED primitives both the shard_map and single-chip paths consume
-are pinned in isolation, so a refactor of either path cannot silently
-fork the math)."""
+the primitives the shard_map wave path consumes are pinned in
+isolation, so a refactor of that path cannot silently change the
+math)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -255,51 +255,3 @@ class TestResolveWave:
                 n_total=4,
                 prod_sensitive=False,
             )
-
-
-class TestWaveAssignKnobs:
-    def test_rejects_degenerate_knobs(self):
-        from koordinator_tpu.solver import wave_assign
-
-        with pytest.raises(ValueError, match="must be >= 1"):
-            wave_assign(None, wave=0)
-        with pytest.raises(ValueError, match="must be >= 1"):
-            wave_assign(None, top_m=0)
-
-    def test_run_cycle_falls_back_to_scan_on_oversized_extra_scores(self):
-        """run_cycle never raises for in-contract inputs: extra_scores
-        beyond the packed-key range (>= 2^31) must take the
-        bit-identical scan path instead of tripping wave_assign's
-        magnitude guard."""
-        from koordinator_tpu.harness import generators
-        from koordinator_tpu.model import encode_snapshot
-        from koordinator_tpu.solver import greedy_assign, run_cycle
-
-        n, p, g, q = generators.loadaware_joint(seed=9, pods=24, nodes=6)
-        snap = encode_snapshot(n, p, g, q)
-        P = snap.pods.capacity
-        N = snap.nodes.allocatable.shape[0]
-        big = jnp.full((P, N), 2**31, jnp.int64)
-        got = run_cycle(snap, CycleConfig(wave=8), extra_scores=big)
-        assert got.path == "scan"
-        want = greedy_assign(snap, extra_scores=big)
-        np.testing.assert_array_equal(
-            np.asarray(got.assignment), np.asarray(want.assignment)
-        )
-
-    def test_knobs_default_from_the_cycle_config(self):
-        from koordinator_tpu.harness import generators
-        from koordinator_tpu.model import encode_snapshot
-        from koordinator_tpu.solver import greedy_assign, wave_assign
-
-        n, p, g, q = generators.loadaware_joint(seed=9, pods=48, nodes=12)
-        snap = encode_snapshot(n, p, g, q)
-        cfg = CycleConfig(wave=8, top_m=2)
-        got = wave_assign(snap, cfg)  # no explicit knobs
-        want = greedy_assign(snap, cfg)
-        np.testing.assert_array_equal(
-            np.asarray(got.assignment), np.asarray(want.assignment)
-        )
-        rounds = int(np.asarray(got.rounds))
-        assert 1 <= rounds < snap.pods.capacity
-        assert got.path == "wave"
